@@ -10,8 +10,8 @@ Three reports:
   3. serving-pipeline comparison (``bench_pipeline``): seed per-tile host
      loop vs. the single-dispatch lax.map pipeline (+ERT) vs. the kernel
      paths — two-dispatch coarse/fine, the one-kernel two-pass chain
-     (``two_pass_fused``, ``two_pass_fused_ert`` with per-ray
-     compaction) and the mesh-sharded-weight variant
+     (``two_pass_fused``, ``two_pass_fused_ert`` with per-ray-block
+     skips) and the mesh-sharded-weight variant
      (``two_pass_fused_sharded``: trunk stacks layer-partitioned over
      the local device mesh, per-layer all-gather in the program; the
      ``sharding`` dict records per-device resident MB vs replicated) —
@@ -86,7 +86,7 @@ def bench_pipeline(hw: int = None, rays_per_batch: int = 1024,
                    ert_eps: float = 1e-2, iters: int = 5) -> dict:
     """Full-image serving comparison: seed tile loop vs single dispatch
     (XLA, +ERT) vs the Pallas kernel paths — the two-dispatch coarse/fine
-    chain and the one-kernel two-pass chain (+ per-ray ERT compaction).
+    chain and the one-kernel two-pass chain (+ per-ray ERT skips).
     Same scene/seed/tiling for all; R = hw*hw rays.
 
     The seed loop is timed as it serves: it rebuilds its jit wrapper per

@@ -7,7 +7,7 @@ view-dependent color branch; positional encoding L=10 (position) / L=4
 Two-pass sampling: 64 uniform + 128 importance (paper §5.1: 192 samples).
 """
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -34,24 +34,24 @@ class NerfConfig:
     rmcm_enabled: bool = True
     # render batching — PLCore analogue: rays per fused-kernel tile
     rays_per_tile: int = 128    # paper batch-computing: 128 samples weight-stationary
-    # fused-kernel VMEM budget (TPU v4/v5 ~= 16 MB/core). The one-kernel
-    # two-pass path pins BOTH networks' gathered weight stacks as the
-    # working set every grid step (2x the single-pass footprint — see
-    # kernels.ops.pick_ray_tile_two_pass) plus resample/merge scratch;
-    # the ray tile rt is sized so the remainder fits the (rt*N, P)
-    # activation slab. Mesh-sharding the weights shrinks the HBM-resident
-    # footprint, not this working set.
+    # fused-kernel scoped-VMEM budget: the limit handed to the compiler
+    # (16 MiB is its default scoped limit on TPU v5e, of 128 MiB of VMEM).
+    # The one-kernel two-pass path pins BOTH networks' gathered weight
+    # stacks as the working set every grid step (see
+    # kernels.ops.two_pass_vmem_bytes) plus one ray block's scratch; the
+    # ray tile rt is sized so the per-ray in/out blocks fit the rest.
+    # Mesh-sharding the weights shrinks the HBM-resident footprint, not
+    # this working set.
     kernel_vmem_budget_mb: float = 16.0
+    # how the Pallas kernels run: None compiles them through Mosaic when
+    # JAX's first device is a TPU and interprets them elsewhere; False
+    # always compiles (a run that must be on the chip then fails off it
+    # instead of interpreting); True always interprets.
+    kernel_interpret: Optional[bool] = None
     # early ray termination (Cicero-style): after the coarse pass, rays whose
     # remaining transmittance T < ert_eps skip the fine-pass MLP and keep the
     # coarse color. 0.0 disables (exact two-pass render).
     ert_eps: float = 0.0
-    # per-ray ERT compaction granularity inside the one-kernel two-pass
-    # path: alive rays are gathered to the tile front and the fine MLP runs
-    # in chunks of this many rays, skipping chunks past the alive count
-    # (rounded to the largest multiple of 8 dividing the ray tile; smaller
-    # chunks skip more dead work but pay more per-chunk dispatch overhead)
-    ert_chunk_rows: int = 64
     image_hw: Tuple[int, int] = (800, 800)
     dtype: str = "float32"
     # §Perf lever: MLP-engine activation dtype. The VRU always integrates

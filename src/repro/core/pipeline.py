@@ -23,7 +23,8 @@ call. This module is the weight-stationary restatement:
   pass run inside ONE Pallas kernel per ray tile
   (kernels/fused_plcore.two_pass_plcore_call), so coarse weights never
   round-trip through HBM between the passes; with ``ert_eps > 0`` the
-  kernel also compacts alive rays so mixed tiles skip fine-MLP work.
+  kernel also skips the fine pass of ray blocks whose rays all
+  terminated.
 * ``PackedPlcore.render_tile`` — the tile-stream entry point for the
   multi-tenant serving engine (repro.serving.engine): one pre-coalesced
   fixed-shape ray tile in, pixels out, same per-tile body as the image
@@ -194,7 +195,7 @@ def _tile_fn(cfg: NerfConfig, use_kernel: bool, ert_eps: float,
 
     ``adaptive`` compiles the budget-bucketed variant: the program takes
     an extra per-ray ``alive`` mask forwarded to the fused kernel's ERT
-    compaction (trunk-memo hits enter dead). Per-budget programs arise
+    skip (trunk-memo hits enter dead). Per-budget programs arise
     from the SAME cache-key mechanism as per-cell ones: the caller
     replaces ``cfg.n_fine`` with the bucket's budget, and cfg is the
     leading key element — each (budget, flags) combination is its own
@@ -274,15 +275,33 @@ class PackedPlcore:
     program re-gathers layers just-in-time (bit-identical output). Works
     with and without ``use_kernel``; the seed per-tile loop
     (plcore.render_image_tiled) does NOT understand sharded weights.
+
+    ``device``: the one device this scene lives on (a serving replica's
+    own chip). Params, quantized weights and the packed layout are
+    committed there at load, and ``commit`` puts tile buffers beside
+    them, so every render program runs on that device. None: JAX's
+    default device. Exclusive with ``shard_mesh``.
     """
 
     def __init__(self, cfg: NerfConfig, params: dict, *,
                  quant: Optional[dict] = None, use_kernel: bool = False,
                  fuse_two_pass: bool = False,
-                 ert_eps: Optional[float] = None, shard_mesh=None):
+                 ert_eps: Optional[float] = None, shard_mesh=None,
+                 device=None):
         if fuse_two_pass and not use_kernel:
             raise ValueError("fuse_two_pass routes through the Pallas "
                              "kernel — pass use_kernel=True")
+        if device is not None and shard_mesh is not None:
+            raise ValueError("device places a replicated scene on one "
+                             "device; shard_mesh spreads it over a mesh — "
+                             "pass one of them")
+        self.device = device
+        if device is not None:
+            # packing below runs on committed inputs, so the packed
+            # layout lands on the same device
+            params = jax.device_put(params, device)
+            if quant is not None:
+                quant = jax.device_put(quant, device)
         self.cfg = cfg
         self.use_kernel = use_kernel
         self.fuse_two_pass = fuse_two_pass
@@ -329,6 +348,14 @@ class PackedPlcore:
             # materialize now: packing (and any resharding) cost is paid
             # at load, not first call
             jax.block_until_ready(self.packed)
+
+    def commit(self, x) -> jax.Array:
+        """A host-side tile buffer as a fresh device array beside this
+        scene's weights (on ``device``, else the default device) — fresh,
+        because off-CPU the tile programs donate their ray buffers."""
+        if self.device is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self.device)
 
     def render_rays(self, rays_o, rays_d, key=None, *,
                     ert_eps: Optional[float] = None) -> dict:
@@ -382,6 +409,15 @@ class PackedPlcore:
         fn = _tile_fn(cfg, self.use_kernel, eps, self.fuse_two_pass,
                       self.shard_mesh, coarse_only)
         return fn(self.params, self.quant, self.packed, o_tile, d_tile)
+
+    def tile_program(self, o_tile, d_tile):
+        """The compiled program ``render_tile`` dispatches for this tile
+        shape (a ``jax.stages.Compiled``: ``as_text()`` shows what the
+        device runs, e.g. whether the Mosaic kernel is in it)."""
+        fn = _tile_fn(self.cfg, self.use_kernel, self.ert_eps,
+                      self.fuse_two_pass, self.shard_mesh)
+        return fn.lower(self.params, self.quant, self.packed, o_tile,
+                        d_tile).compile()
 
     def render_tile_oracle(self, o_tile, d_tile,
                            ert_eps: Optional[float] = None) -> jnp.ndarray:
@@ -588,8 +624,8 @@ class PackedPlcore:
 # position-only trunk half of the coarse MLP is memoized per calibration
 # voxel (core.sampling.TrunkMemo) so provably-empty, fully-memo-resident
 # rays enter the fused two-pass kernel as DEAD rows — the existing ERT
-# prefix-compaction then skips their fine pass, so the saving shows up in
-# measured tile latency, not just in counters.
+# skip then drops their fine pass, so the saving shows up in measured
+# tile latency, not just in counters.
 
 _TRUNK_JITS: dict = {}
 _RECON_JITS: dict = {}
@@ -745,8 +781,8 @@ class AdaptiveRenderer:
       coalesce rays by (scene, class) and dispatch each tile at its
       class's ``n_fine`` budget — a per-budget compiled program;
     * rays whose frustum is fully memo-resident AND provably empty enter
-      the fused kernel as DEAD rows: the kernel's ERT prefix-compaction
-      skips their fine pass, and their pixels are reconstructed from the
+      the fused kernel as DEAD rows: the kernel's ERT skip drops their
+      fine pass, and their pixels are reconstructed from the
       memoized trunk rows host-side (``_recon_fn`` — color branch + VRU
       only, no trunk matmuls);
     * a tile whose rays are ALL dead skips the kernel dispatch entirely.
@@ -761,7 +797,7 @@ class AdaptiveRenderer:
                              "weights (no shard_mesh)")
         if not (pp.use_kernel and pp.fuse_two_pass):
             raise ValueError("adaptive sampling rides the fused two-pass "
-                             "kernel's dead-row compaction — build the "
+                             "kernel's dead-row skip — build the "
                              "PackedPlcore with use_kernel=True, "
                              "fuse_two_pass=True")
         from repro.core import sampling

@@ -82,10 +82,11 @@ def render_rays(cfg: NerfConfig, params: dict, rays_o, rays_d,
     fuse_two_pass (requires use_kernel, deterministic sampling): the whole
     coarse -> importance -> fine chain runs as ONE Pallas kernel per ray
     tile — coarse weights never leave VMEM, and with ert_eps > 0 the
-    kernel compacts alive rays so mixed tiles also skip fine-MLP work.
+    kernel skips the fine pass of every ray block whose rays all
+    terminated (per ray on the chip at published widths).
     ``alive`` (fuse_two_pass only): optional (R,) float mask of
     externally-live rays — 0-rows (adaptive trunk-memo hits) enter the
-    fused kernel dead and its ERT compaction skips their fine pass.
+    fused kernel dead and the same ERT skip drops their fine pass.
     """
     R = rays_o.shape[:-1]
     k1 = k2 = None
@@ -98,7 +99,7 @@ def render_rays(cfg: NerfConfig, params: dict, rays_o, rays_d,
 
     if alive is not None and not (use_kernel and fuse_two_pass):
         raise ValueError("an external alive mask rides the fused two-pass "
-                         "kernel's compaction — pass use_kernel=True, "
+                         "kernel's ERT skip — pass use_kernel=True, "
                          "fuse_two_pass=True")
 
     if use_kernel and fuse_two_pass:

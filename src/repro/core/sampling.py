@@ -17,7 +17,8 @@ comparison-count reduction and every gather as a one-hot contraction —
 ops Mosaic can lower, bit-identical to the host path — and
 ``merge_sorted_ranks`` merges two sorted sample sets by rank arithmetic
 instead of ``jnp.sort``. Both paths share ``_weights_to_cdf``/``det_u``
-so the CDF and the u-grid cannot drift apart.
+(and, through the CDF, ``prefix_sum``) so the CDF and the u-grid cannot
+drift apart.
 """
 from __future__ import annotations
 
@@ -47,10 +48,34 @@ def stratified(near: float, far: float, n: int, shape=(),
     return near + (far - near) * s
 
 
+def prefix_sum(x):
+    """Inclusive prefix sum over the last axis, as a contraction with the
+    (N, N) upper-triangular 0/1 matrix. ``jnp.cumsum`` has no Mosaic
+    lowering; this form runs unchanged inside the fused kernel and on
+    the host. HIGHEST precision keeps every f32 term exact on the MXU
+    (0/1 are exact in each bf16 pass); a one-pass bf16 contraction would
+    round each term to 8 mantissa bits."""
+    n = x.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    tri = (row <= col).astype(x.dtype)
+    return jnp.dot(x, tri, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=x.dtype)
+
+
 def det_u(n: int):
-    """The deterministic (inference-mode) u-grid, shared verbatim by the
-    host sampler and the fused kernel's in-VMEM resampler."""
-    return jnp.linspace(0.0, 1.0 - 1e-6, n)
+    """The deterministic (inference-mode) u-grid as a (1, n) row: n evenly
+    spaced points on [0, 1 - 1e-6], shared verbatim by the host sampler
+    and the fused kernel's in-VMEM resampler. Built from a 2-D integer
+    iota: a float iota and a 1-D vector do not lower in Mosaic."""
+    k = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+    return k * ((1.0 - 1e-6) / max(n - 1, 1))
+
+
+def _u_grid(n: int, batch_shape):
+    """``det_u`` broadcast to (*batch_shape, n)."""
+    u = det_u(n).reshape((1,) * len(batch_shape) + (n,))
+    return jnp.broadcast_to(u, tuple(batch_shape) + (n,))
 
 
 def _weights_to_cdf(weights, eps: float = 1e-5):
@@ -58,7 +83,7 @@ def _weights_to_cdf(weights, eps: float = 1e-5):
     pdf over the intervals between midpoints (drop edge weights, as NeRF)."""
     w = weights[..., 1:-1] + eps
     pdf = w / jnp.sum(w, axis=-1, keepdims=True)
-    cdf = jnp.cumsum(pdf, axis=-1)
+    cdf = prefix_sum(pdf)
     return jnp.concatenate([jnp.zeros_like(cdf[..., :1]), cdf], axis=-1)
 
 
@@ -75,7 +100,7 @@ def importance(t_mid, weights, n: int, key: Optional[jax.Array] = None,
     if key is not None:
         u = jax.random.uniform(key, cdf.shape[:-1] + (n,))
     else:
-        u = jnp.broadcast_to(det_u(n), cdf.shape[:-1] + (n,))
+        u = _u_grid(n, cdf.shape[:-1])
 
     idx = jnp.clip(jnp.searchsorted(cdf, u, side="right") - 1,
                    0, cdf.shape[-1] - 2) if cdf.ndim == 1 else \
@@ -111,7 +136,7 @@ def importance_det(t_mid, weights, n: int, eps: float = 1e-5):
     """
     cdf = _weights_to_cdf(weights, eps)                       # (..., M-1)
     M1 = cdf.shape[-1]
-    u = jnp.broadcast_to(det_u(n), cdf.shape[:-1] + (n,))
+    u = _u_grid(n, cdf.shape[:-1])
     le = (cdf[..., None, :] <= u[..., :, None]).astype(jnp.int32)
     idx = jnp.clip(jnp.sum(le, axis=-1) - 1, 0, M1 - 2)       # (..., n)
     lanes = jax.lax.broadcasted_iota(jnp.int32, idx.shape + (M1,), idx.ndim)

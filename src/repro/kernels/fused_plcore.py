@@ -18,15 +18,20 @@ dequantized in-register -> VRU in closed parallel-prefix form):
   final composite. Coarse weights, sample positions and every activation
   stay in VMEM.
 
+Ray blocks. Mosaic unrolls every vector op over the vregs of its operand,
+so a body that works on a whole (rt * N, 256) activation compiles in time
+and VMEM proportional to rt (the one-pass kernel at CONFIG: 49 s at 8 rays
+per step, out of scoped VMEM at 16). Each kernel therefore walks its ray
+tile in a ``fori_loop`` over blocks of ``block`` rays, with the per-sample
+work of one block in registers and VMEM scratch: the compiled body, and
+its VMEM, depend on ``block``, not on ``rt``. Off-TPU the same kernel runs
+under the Pallas interpreter with one block per tile.
+
 Per-ray early termination (Cicero, arXiv 2404.11852) inside the two-pass
-kernel: after the coarse VRU, rays with transmittance < ert_eps are
-*compacted* — a prefix-sum rank over the alive mask builds a permutation
-(applied as a one-hot matmul) that gathers alive rays to the front of the
-tile, and the fine-pass MLP then runs chunk-by-chunk over that dense
-prefix, each chunk guarded by ``n_alive > chunk_start``. Mixed tiles —
-not just all-dead ones — skip fine-pass work proportional to their dead
-fraction, at ``cfg.ert_chunk_rows`` granularity; dead rays keep the
-coarse color/acc/depth.
+kernel: after the coarse VRU, rays with transmittance < ert_eps keep the
+coarse color/acc/depth, and a block whose rays are all dead skips the
+importance resample and the fine pass (a ``lax.cond``). Blocks are a few
+rays on the chip, so the skip is close to per-ray there.
 
 HBM traffic per ray (f32 words), N = n_coarse + n_fine samples:
 
@@ -39,32 +44,25 @@ HBM traffic per ray (f32 words), N = n_coarse + n_fine samples:
   two_pass (this kernel)    rays (6); t_c is one     rgb (3) + rgb_c (3)
                             pinned (1, Nc) row       + acc, acc_c, depth (3)
 
-VMEM budget (``ops.pick_ray_tile_two_pass``): BOTH networks' weight
-stacks occupy VMEM every grid step as the GATHERED working set (2x the
-single-pass footprint, ~7.3 MB f32 at full scale) and the per-ray
-scratch adds the fine slab (N x P), the resample one-hot
-(n_fine x (n_coarse-1)) and the rank-merge scatter one-hots (N x N); rt
-is sized so weights + scratch fit ``NerfConfig.kernel_vmem_budget_mb``
-(default 16 MB — one TPU v4/v5 core's VMEM). Both entry points take
-GATHERED (replicated) weight layouts: with mesh-sharded residency
+VMEM (``ops.two_pass_vmem_bytes``): BOTH networks' weight stacks stay
+resident every grid step as the GATHERED working set (single-buffered:
+their block never moves), plus the double-buffered per-ray in/out blocks
+and one ray block's scratch. The same model sizes rt and is handed to the
+compiler as its scoped-VMEM limit. Both entry points take GATHERED
+(replicated) weight layouts: with mesh-sharded residency
 (runtime.sharding) the pipeline all-gathers each trunk layer
 just-in-time inside the same jitted program before the kernel launches —
 sharding shrinks the per-device HBM-resident footprint
 (``ops.plcore_resident_weight_bytes``), never this working set.
-
-Off-TPU, ``two_pass_plcore_call`` runs the same tile body through a
-``lax.map`` grid emulator instead of the Pallas interpreter (identical
-semantics, parity-tested; ERT's lax.cond chunk skips stay runtime-real)
-— benchmarks/plcore_fusion.py measures the chain through it.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.nerf_icarus import NerfConfig
 from repro.core import sampling
@@ -83,8 +81,24 @@ def _pe_double_angle(x, n_freqs: int):
     return jnp.concatenate(feats, axis=-1)
 
 
+def _mm(a, b):
+    """Every contraction in the kernel body: f32 operands at HIGHEST
+    precision. Mosaic's default for f32 operands is not a contract of
+    the API, and the prefix and row sums need each f32 term exact."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _row_sum(x):
+    """Per-ray sum over the sample lanes, (G, N) -> (G, 1), as a
+    contraction with a ones column: Mosaic refuses the lane reduction
+    ``jnp.sum(w * t_all, -1)`` once t_all comes out of the rank merge
+    ("Unsupported output implicit dimension")."""
+    return _mm(x, jnp.ones((x.shape[-1], 1), jnp.float32))
+
+
 def _dq(mag, sgn_bits, scale, rows_padded):
-    m = mag.astype(jnp.float32)
+    m = mag.astype(jnp.int32).astype(jnp.float32)   # no u8 -> f32 in Mosaic
     sg = _unpack_signs(sgn_bits, rows_padded).astype(jnp.float32)
     return m * (1.0 - 2.0 * sg) * scale
 
@@ -101,42 +115,53 @@ def _weight_order(quantized: bool):
 
 
 def _net_arrays(cfg: NerfConfig, refs, quantized: bool, P: int, P2: int):
-    """Read one network's weight refs into dense f32 arrays (RMCM layers
-    dequantized in-register ONCE per kernel body, however many chunks the
-    fine pass later splits into)."""
+    """Read one network's weight refs into dense f32 values for one ray
+    block (RMCM layers dequantized in-register). The 1-D biases arrive as
+    (1, n) rows (``_kernel_weights``)."""
     W = cfg.trunk_width
+    L = cfg.trunk_layers
     if quantized:
         (tw_mag, tw_sgn, tw_scl, tb, sw, sb, fw_mag, fw_sgn, fw_scl, fb,
          cw_mag, cw_sgn, cw_scl, cb, rw, rb) = refs
-        tw = [_dq(tw_mag[i], tw_sgn[i], tw_scl[i], P)
-              for i in range(cfg.trunk_layers)]
+        tw = [_dq(tw_mag[i], tw_sgn[i], tw_scl[i], P) for i in range(L)]
         fw = _dq(fw_mag[...], fw_sgn[...], fw_scl[...], W)
         cw = _dq(cw_mag[...], cw_sgn[...], cw_scl[...], P2)
-        return (tw, tb, sw, sb, fw, fb, cw, cb, rw, rb)
-    (tw, tb, sw, sb, fw, fb, cw, cb, rw, rb) = refs
-    return ([tw[i] for i in range(cfg.trunk_layers)], tb, sw, sb,
-            fw[...], fb, cw[...], cb, rw, rb)
+    else:
+        (tw, tb, sw, sb, fw, fb, cw, cb, rw, rb) = refs
+        tw = [tw[i] for i in range(L)]
+        fw, cw = fw[...], cw[...]
+    tbs = [tb[i:i + 1] for i in range(L)]               # (1, W) rows
+    # sigma and feat both read h: ONE fused (W, W+1) matmul; feat first so
+    # both column slices start lane-aligned
+    sfw = jnp.concatenate([fw, sw[...]], axis=-1)
+    return (tw, tbs, sfw, sb[...], fb[...], cw, cb[...], rw[...], rb[...])
 
 
-def _pass_body(cfg: NerfConfig, rt: int, N: int, net, o, d, ts, deltas,
+def _kernel_weights(arrays):
+    """1-D bias vectors -> (1, n) rows: a kernel block of rank 1 must be a
+    multiple of 128 long, and the body broadcasts rows, not vectors."""
+    return [a.reshape(1, -1) if a.ndim == 1 else a for a in arrays]
+
+
+def _pass_body(cfg: NerfConfig, G: int, N: int, net, o, d, ts, deltas,
                ped=None):
-    """One full PEU -> MLP -> VRU pass over a (rt, N) sample set with
-    already-materialized rays/weights. Returns (rgb_pix (rt, 3),
-    w (rt, N), T_next (rt, N)); acc = 1 - T_next[:, -1]. ``ped``: the
-    per-ray direction encoding, precomputable once when several passes
-    share the same rays (the two-pass kernel encodes directions ONCE
-    where the host path does it per pass)."""
-    tw, tb, sw, sb, fw, fb, cw, cb, rw, rb = net
+    """One full PEU -> MLP -> VRU pass over the (G, N) sample set of a
+    block of G rays with already-materialized rays/weights. Returns
+    (rgb_pix (G, 3), w (G, N), T_next (G, N)); acc = 1 - T_next[:, N-1:].
+    ``ped``: the per-ray direction encoding, precomputable once when
+    several passes share the same rays (the two-pass kernel encodes
+    directions ONCE where the host path does it per pass)."""
+    tw, tb, sfw, sb, fb, cw, cb, rw, rb = net
     W = cfg.trunk_width
     pe_dim, de_dim = cfg.pos_enc_dim, cfg.dir_enc_dim
-    T = rt * N
+    T = G * N
 
     # ---- positions & PEU (double-angle) --------------------------------
     pts = (o[:, None, :] + ts[..., None] * d[:, None, :]).reshape(T, 3)
     pe = _pe_double_angle(pts, cfg.pos_freqs)          # (T, pe_dim)
     if ped is None:
         dn = d * jax.lax.rsqrt(jnp.sum(d * d, -1, keepdims=True))
-        ped = _pe_double_angle(dn, cfg.dir_freqs)      # (rt, de_dim)
+        ped = _pe_double_angle(dn, cfg.dir_freqs)      # (G, de_dim)
 
     # ---- MLP engine (MONB) ---------------------------------------------
     # skip layers run as SPLIT matmuls (h @ W_h + pe @ W_pe == the concat
@@ -145,54 +170,76 @@ def _pass_body(cfg: NerfConfig, rt: int, N: int, net, o, d, ts, deltas,
     h = pe
     for i in range(cfg.trunk_layers):
         if i == 0:
-            h = jax.nn.relu(
-                jnp.dot(pe, tw[i][:pe_dim],
-                        preferred_element_type=jnp.float32) + tb[i])
+            h = jax.nn.relu(_mm(pe, tw[i][:pe_dim]) + tb[i])
         elif i in cfg.skip_at:
-            h = jax.nn.relu(
-                jnp.dot(h, tw[i][:W], preferred_element_type=jnp.float32)
-                + jnp.dot(pe, tw[i][W:W + pe_dim],
-                          preferred_element_type=jnp.float32) + tb[i])
+            h = jax.nn.relu(_mm(h, tw[i][:W])
+                            + _mm(pe, tw[i][W:W + pe_dim]) + tb[i])
         else:
-            h = jax.nn.relu(
-                jnp.dot(h, tw[i][:W],
-                        preferred_element_type=jnp.float32) + tb[i])
+            h = jax.nn.relu(_mm(h, tw[i][:W]) + tb[i])
 
     # ---- heads: sigma (SONB, exact), feature, color branch -------------
-    # sigma and feat both read h: ONE fused (W, 1+W) matmul instead of a
+    # sigma and feat both read h: ONE fused (W, W+1) matmul instead of a
     # gemv + a gemm (one pass over the (T, W) activations)
-    sfw = jnp.concatenate([sw[...], fw], axis=-1)      # (W, 1+W)
-    sf = jnp.dot(h, sfw, preferred_element_type=jnp.float32)
-    sigma = sf[:, 0] + sb[...][0]
-    feat = sf[:, 1:] + fb[...]
-    # split color matmul: the direction part is PER-RAY (rt rows), not
+    sf = _mm(h, sfw)
+    feat = sf[:, :W] + fb
+    sigma = sf[:, W:W + 1] + sb                         # (T, 1)
+    # split color matmul: the direction part is PER-RAY (G rows), not
     # per-sample — N x less work than the (T, W+de) concat matmul
     C = cw.shape[-1]
-    colf = jnp.dot(feat, cw[:W], preferred_element_type=jnp.float32)
-    cold = jnp.dot(ped, cw[W:W + de_dim],
-                   preferred_element_type=jnp.float32)  # (rt, C)
+    colf = _mm(feat, cw[:W])
+    cold = _mm(ped, cw[W:W + de_dim])                   # (G, C)
     hc = jax.nn.relu(
-        (colf.reshape(rt, N, C) + cold[:, None, :]).reshape(T, C)
-        + cb[...])
-    raw = (jnp.dot(hc, rw[...], preferred_element_type=jnp.float32)
-           + rb[...])
-    rgb = jax.nn.sigmoid(raw).reshape(rt, N, 3)
+        (colf.reshape(G, N, C) + cold[:, None, :]).reshape(T, C) + cb)
+    rgb = jax.nn.sigmoid(_mm(hc, rw) + rb).reshape(G, N, 3)
 
     # ---- VRU: closed-form parallel prefix ------------------------------
-    # T_{i+1} = exp(cumsum_{j<=i} x_j); T_0 = 1; w_i = T_i - T_{i+1}.
-    # Same math as eq.(5)'s recurrence, but one vectorized cumsum
+    # T_{i+1} = exp(prefix_sum_{j<=i} x_j); T_0 = 1; w_i = T_i - T_{i+1}.
+    # Same math as eq.(5)'s recurrence, but one vectorized prefix sum
     # instead of N serial steps with a dynamic_update_slice each.
-    x = -(jnp.maximum(sigma, 0.0).reshape(rt, N)) * deltas
-    T_next = jnp.exp(jnp.cumsum(x, axis=-1))           # (rt, N): T_{i+1}
-    T_i = jnp.concatenate([jnp.ones((rt, 1), jnp.float32),
+    x = -(jnp.maximum(sigma, 0.0).reshape(G, N)) * deltas
+    T_next = jnp.exp(sampling.prefix_sum(x))            # (G, N): T_{i+1}
+    T_i = jnp.concatenate([jnp.ones((G, 1), jnp.float32),
                            T_next[:, :-1]], axis=-1)
     w = T_i - T_next
-    accum = jnp.sum(w[..., None] * rgb, axis=1)        # (rt, 3)
+    accum = jnp.sum(w[..., None] * rgb, axis=1)        # (G, 3)
     return accum, w, T_next
 
 
-def _make_kernel(cfg: NerfConfig, rt: int, N: int, P: int, P2: int,
-                 quantized: bool, ert: bool):
+def _pinned(a):
+    """Whole tensor resident every grid step (weight-stationary). Its
+    block index never changes, so ONE buffer suffices: the default second
+    pipeline buffer would double the weights' VMEM for nothing."""
+    nd = a.ndim
+    return pl.BlockSpec(a.shape, lambda i, nd=nd: (0,) * nd,
+                        pipeline_mode=pl.Buffered(1))
+
+
+def _rows(rt: int, width: int):
+    """Per-ray block of an (R, width) array: rt rows per grid step."""
+    return pl.BlockSpec((rt, width), lambda i: (i, 0))
+
+
+def _compiler_params(vmem_limit_bytes: Optional[int]):
+    if vmem_limit_bytes is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit_bytes))
+
+
+def _for_each_block(rt: int, block: int, fn):
+    """fn(rows) for every ``block``-ray slice of the tile, as a real loop
+    (one compiled body) rather than ``rt // block`` unrolled copies."""
+    assert rt % block == 0, (rt, block)
+
+    def step(b, carry):
+        fn(pl.ds(pl.multiple_of(b * block, block), block))
+        return carry
+
+    jax.lax.fori_loop(0, rt // block, step, 0)
+
+
+# ------------------------------------------------------ one-pass kernel ----
+def _make_kernel(cfg: NerfConfig, rt: int, block: int, N: int, P: int,
+                 P2: int, quantized: bool, ert: bool):
     nw = len(_weight_order(quantized))
 
     def kernel(o_ref, d_ref, t_ref, dl_ref, *refs):
@@ -201,22 +248,25 @@ def _make_kernel(cfg: NerfConfig, rt: int, N: int, P: int, P2: int,
         wrefs = refs[:nw]
         rgb_o, w_o, acc_o = refs[nw:]
 
-        def compute():
+        def ray_block(rows):
             net = _net_arrays(cfg, wrefs, quantized, P, P2)
-            o = o_ref[...].astype(jnp.float32)             # (rt, 3)
-            d = d_ref[...].astype(jnp.float32)             # (rt, 3)
-            ts = t_ref[...].astype(jnp.float32)            # (rt, N)
-            accum, w, T_next = _pass_body(cfg, rt, N, net, o, d, ts,
-                                          dl_ref[...])
-            rgb_o[...] = accum.astype(rgb_o.dtype)
-            w_o[...] = w.astype(w_o.dtype)
-            acc_o[...] = (1.0 - T_next[:, -1]).astype(acc_o.dtype)
+            o = o_ref[rows, :].astype(jnp.float32)          # (block, 3)
+            d = d_ref[rows, :].astype(jnp.float32)
+            ts = t_ref[rows, :].astype(jnp.float32)         # (block, N)
+            accum, w, T_next = _pass_body(cfg, block, N, net, o, d, ts,
+                                          dl_ref[rows, :])
+            rgb_o[rows, :] = accum.astype(rgb_o.dtype)
+            w_o[rows, :] = w.astype(w_o.dtype)
+            acc_o[rows, :] = (1.0 - T_next[:, N - 1:]).astype(acc_o.dtype)
+
+        def compute():
+            _for_each_block(rt, block, ray_block)
 
         if not ert:
             compute()
             return
         # ---- early-ray-termination fast path: skip dead tiles -----------
-        any_alive = jnp.any(alive_ref[...] > 0.0)
+        any_alive = jnp.sum((alive_ref[...] > 0.0).astype(jnp.float32)) > 0
 
         @pl.when(any_alive)
         def _():
@@ -231,227 +281,160 @@ def _make_kernel(cfg: NerfConfig, rt: int, N: int, P: int, P2: int,
     return kernel
 
 
-def _pinned(a):  # whole tensor resident every grid step (weight-stationary)
-    nd = a.ndim
-    return pl.BlockSpec(a.shape, lambda i, nd=nd: (0,) * nd)
-
-
 def fused_plcore_call(cfg: NerfConfig, weights: dict, rays_o, rays_d, t,
                       deltas, *, rt: int, quantized: bool,
-                      alive=None, interpret: bool = True):
+                      alive=None, interpret: bool = True,
+                      block: Optional[int] = None,
+                      vmem_limit_bytes: Optional[int] = None):
     """Low-level pallas_call. rays: (R, 3) with R % rt == 0; t/deltas (R, N).
 
     ``weights``: layout from ops.stack_plcore_weights (P/P2 row-padded,
     trunk stacked (L, P, W)). ``alive``: optional (R,) float mask; tiles
     whose rays are all dead (== 0) skip the MLP+VRU entirely and output
-    zeros. Returns (rgb (R,3), w (R,N), acc (R,)).
+    zeros. ``block``: rays per inner-loop step (default: the whole tile);
+    it must divide rt. Per-ray vectors cross the kernel boundary as
+    (R, 1) columns (a rank-1 block must be a multiple of 128 long).
+    ``vmem_limit_bytes``: the scoped-VMEM limit the compiler is given
+    (None: its default). Returns (rgb (R,3), w (R,N), acc (R,)).
     """
     R, N = t.shape
     assert R % rt == 0, (R, rt)
+    block = rt if block is None else block
     # row padding is derived from cfg, NOT read out of ``weights``: the
     # packed layout crosses jit boundaries as a traced pytree, and shapes
     # must stay concrete
     P = -(-(cfg.trunk_width + cfg.pos_enc_dim) // 128) * 128
     P2 = -(-(cfg.trunk_width + cfg.dir_enc_dim) // 128) * 128
-    w_arrays = [weights[k] for k in _weight_order(quantized)]
-
-    grid = (R // rt,)
-    ray_spec = pl.BlockSpec((rt, 3), lambda i: (i, 0))
-    samp_spec = pl.BlockSpec((rt, N), lambda i: (i, 0))
-    mask_spec = pl.BlockSpec((rt,), lambda i: (i,))
-
-    out_shape = [jax.ShapeDtypeStruct((R, 3), jnp.float32),
-                 jax.ShapeDtypeStruct((R, N), jnp.float32),
-                 jax.ShapeDtypeStruct((R,), jnp.float32)]
-    out_specs = [pl.BlockSpec((rt, 3), lambda i: (i, 0)),
-                 pl.BlockSpec((rt, N), lambda i: (i, 0)),
-                 pl.BlockSpec((rt,), lambda i: (i,))]
+    w_arrays = _kernel_weights(
+        [weights[k] for k in _weight_order(quantized)])
 
     ert = alive is not None
-    mask_in = [alive.astype(jnp.float32)] if ert else []
-    kernel = _make_kernel(cfg, rt, N, P, P2, quantized, ert)
+    mask_in = [alive.astype(jnp.float32).reshape(R, 1)] if ert else []
+    kernel = _make_kernel(cfg, rt, block, N, P, P2, quantized, ert)
     rgb, w, acc = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[ray_spec, ray_spec, samp_spec, samp_spec]
-                 + ([mask_spec] if ert else [])
+        grid=(R // rt,),
+        in_specs=[_rows(rt, 3), _rows(rt, 3), _rows(rt, N), _rows(rt, N)]
+                 + ([_rows(rt, 1)] if ert else [])
                  + [_pinned(a) for a in w_arrays],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[_rows(rt, 3), _rows(rt, N), _rows(rt, 1)],
+        out_shape=[jax.ShapeDtypeStruct((R, 3), jnp.float32),
+                   jax.ShapeDtypeStruct((R, N), jnp.float32),
+                   jax.ShapeDtypeStruct((R, 1), jnp.float32)],
+        compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
     )(rays_o, rays_d, t, deltas, *mask_in, *w_arrays)
-    return rgb, w, acc
+    return rgb, w, acc[:, 0]
 
 
 # --------------------------------------------------- one-kernel two-pass ----
-def _two_pass_tile(cfg: NerfConfig, rt: int, Nc: int, Nf: int,
-                   P: int, P2: int, qc: bool, qf: bool,
-                   ert_eps: float, chunk: int,
+def _two_pass_rays(cfg: NerfConfig, G: int, Nc: int, Nf: int,
+                   P: int, P2: int, qc: bool, qf: bool, ert_eps: float,
                    o, d, t_row, cw_refs, fw_refs, m=None):
-    """The two-pass tile body: coarse -> in-VMEM importance resample ->
-    (ERT-compacted) fine -> composite, for one (rt,)-ray tile. Shared
-    VERBATIM by the Pallas kernel (whose refs index like arrays) and the
-    off-TPU lax.map grid emulator — the parity test in
-    tests/test_two_pass_fused.py holds the two executors together.
-    ``m``: optional (rt,) float mask of externally-dead rows (trunk-memo
+    """The two-pass chain for one block of G rays: coarse -> in-VMEM
+    importance resample -> fine -> composite.
+    ``m``: optional (G, 1) float mask of externally-dead rows (trunk-memo
     hits in the adaptive path): rows with m == 0 join the ERT-dead set,
-    so the SAME prefix compaction that skips terminated rays skips
-    memoized ones — their fine-pass cost vanishes from tile latency
-    (their outputs are overwritten host-side from the memo).
-    Returns (rgb, rgb_coarse, acc, acc_coarse, depth)."""
+    so the SAME skip that drops terminated rays drops memoized ones —
+    their fine-pass cost vanishes from tile latency (their outputs are
+    overwritten host-side from the memo).
+
+    Every per-ray quantity is a (G, 1) column and every slice is static:
+    integer indexing of a value lowers to ``dynamic_slice``, which Mosaic
+    refuses. Returns a (G, 9) record:
+    [rgb (3) | rgb_coarse (3) | acc | acc_coarse | depth]."""
     Nt = Nc + Nf
-    o = o.astype(jnp.float32)                          # (rt, 3)
-    d = d.astype(jnp.float32)                          # (rt, 3)
+    o = o.astype(jnp.float32)                          # (G, 3)
+    d = d.astype(jnp.float32)                          # (G, 3)
     # deterministic coarse samples: one pinned (1, Nc) row, shared by
     # every ray of every tile — the only non-ray tensor crossing HBM
-    t_c = jnp.broadcast_to(t_row.astype(jnp.float32), (rt, Nc))
+    t_c = jnp.broadcast_to(t_row.astype(jnp.float32), (G, Nc))
     dl_c = sampling.deltas_from_t(t_c)
     # direction encoding is per-ray, not per-sample: encode ONCE and
     # share it between the coarse and fine passes (the host path pays
     # for it twice, once per _eval_pass)
     dn = d * jax.lax.rsqrt(jnp.sum(d * d, -1, keepdims=True))
-    ped = _pe_double_angle(dn, cfg.dir_freqs)          # (rt, de_dim)
+    ped = _pe_double_angle(dn, cfg.dir_freqs)          # (G, de_dim)
 
     # ---- pass 1: coarse, entirely in VMEM -------------------------------
     net_c = _net_arrays(cfg, cw_refs, qc, P, P2)
-    rgb_c, w_c, Tn_c = _pass_body(cfg, rt, Nc, net_c, o, d, t_c, dl_c, ped)
-    acc_c = 1.0 - Tn_c[:, -1]
-    depth_c = jnp.sum(w_c * t_c, axis=-1)
+    rgb_c, w_c, Tn_c = _pass_body(cfg, G, Nc, net_c, o, d, t_c, dl_c, ped)
+    acc_c = 1.0 - Tn_c[:, Nc - 1:]                     # (G, 1)
+    depth_c = _row_sum(w_c * t_c)
 
-    # ---- in-VMEM importance resample (w_c never leaves the chip) --------
-    t_f = sampling.importance_det(t_c, w_c, Nf)        # (rt, Nf)
-    t_all = sampling.merge_sorted_ranks(t_c, t_f)      # (rt, Nt)
-
-    net_f = _net_arrays(cfg, fw_refs, qf, P, P2)
-
-    def full_fine(_):
-        """Monolithic fine pass on the whole tile — one dense MLP."""
-        dl_all = sampling.deltas_from_t(t_all)
-        r, w, Tn = _pass_body(cfg, rt, Nt, net_f, o, d, t_all, dl_all, ped)
+    def fine(_):
+        """In-VMEM importance resample (w_c never leaves the chip), then
+        the fine pass: (rgb (3) | acc | depth), (G, 5)."""
+        t_f = sampling.importance_det(t_c, w_c, Nf)    # (G, Nf)
+        t_all = sampling.merge_sorted_ranks(t_c, t_f)  # (G, Nt)
+        net_f = _net_arrays(cfg, fw_refs, qf, P, P2)
+        r, w, Tn = _pass_body(cfg, G, Nt, net_f, o, d, t_all,
+                              sampling.deltas_from_t(t_all), ped)
         return jnp.concatenate(
-            [r, (1.0 - Tn[:, -1])[:, None],
-             jnp.sum(w * t_all, axis=-1)[:, None]], axis=-1)   # (rt, 5)
+            [r, 1.0 - Tn[:, Nt - 1:], _row_sum(w * t_all)], axis=-1)
 
     if ert_eps > 0.0 or m is not None:
+        alive = None
         if ert_eps > 0.0:
             alive = acc_c < 1.0 - ert_eps
-            if m is not None:
-                alive = jnp.logical_and(alive, m.astype(jnp.float32) > 0.0)
-        else:
-            alive = m.astype(jnp.float32) > 0.0
-        af = alive.astype(jnp.float32)
-        n_alive = jnp.sum(af).astype(jnp.int32)
-
-        # ---- per-ray ERT compaction -------------------------------------
-        # alive rays move to the tile's front (stable prefix-sum rank,
-        # applied as ONE one-hot permutation matmul over the concatenated
-        # per-ray state); the fine MLP then runs chunk-by-chunk over the
-        # dense prefix, skipping every chunk past n_alive — a mostly-dead
-        # tile saves fine-MLP work proportional to its dead fraction.
-        def compacted_fine(_):
-            front = jnp.cumsum(af) - 1.0
-            back = jnp.sum(af) + jnp.cumsum(1.0 - af) - 1.0
-            dest = jnp.where(alive, front, back).astype(jnp.int32)
-            lanes = jax.lax.broadcasted_iota(jnp.int32, (rt, rt), 1)
-            perm = (dest[:, None] == lanes).astype(jnp.float32)
-            state = jnp.concatenate([o, d, t_all, ped], axis=-1)
-            state_p = jnp.dot(perm.T, state,
-                              preferred_element_type=jnp.float32)
-            o_p, d_p = state_p[:, :3], state_p[:, 3:6]
-            t_p = state_p[:, 6:6 + Nt]
-            ped_p = state_p[:, 6 + Nt:]
-            dl_p = sampling.deltas_from_t(t_p)
-
-            outs = []
-            for g in range(rt // chunk):
-                s0 = g * chunk
-                oc, dc = o_p[s0:s0 + chunk], d_p[s0:s0 + chunk]
-                tc_, dlc = t_p[s0:s0 + chunk], dl_p[s0:s0 + chunk]
-                pedc = ped_p[s0:s0 + chunk]
-
-                def live(_, oc=oc, dc=dc, tc_=tc_, dlc=dlc, pedc=pedc):
-                    r, w, Tn = _pass_body(cfg, chunk, Nt, net_f,
-                                          oc, dc, tc_, dlc, pedc)
-                    return jnp.concatenate(
-                        [r, (1.0 - Tn[:, -1])[:, None],
-                         jnp.sum(w * tc_, axis=-1)[:, None]], axis=-1)
-
-                def dead(_):
-                    return jnp.zeros((chunk, 5), jnp.float32)
-
-                outs.append(jax.lax.cond(n_alive > s0, live, dead, None))
-            fine_p = jnp.concatenate(outs, axis=0)         # (rt, 5)
-            # un-compact (perm is a permutation matrix: applying it
-            # un-transposed inverts the compaction gather above)
-            return jnp.dot(perm, fine_p,
-                           preferred_element_type=jnp.float32)
-
-        # Compaction costs a permutation and splits the fine MLP into
-        # chunk-sized matmuls, so engage it only when it can skip at
-        # least half the tile; mostly-alive tiles run the monolithic
-        # pass with zero ERT overhead (their dead rays still keep the
-        # coarse color via the select below).
-        fine = jax.lax.cond(n_alive > rt // 2, full_fine,
-                            compacted_fine, None)
-        rgb = jnp.where(alive[:, None], fine[:, :3], rgb_c)
-        acc = jnp.where(alive, fine[:, 3], acc_c)
-        depth = jnp.where(alive, fine[:, 4], depth_c)
+        if m is not None:
+            live = m.astype(jnp.float32) > 0.0
+            alive = live if alive is None else jnp.logical_and(alive, live)
+        coarse = jnp.concatenate([rgb_c, acc_c, depth_c], axis=-1)
+        any_alive = jnp.sum(alive.astype(jnp.float32)) > 0.0
+        rec = jax.lax.cond(any_alive, fine, lambda _: coarse, None)
+        rec = jnp.where(alive, rec, coarse)
     else:
-        fine = full_fine(None)
-        rgb, acc, depth = fine[:, :3], fine[:, 3], fine[:, 4]
-    return rgb, rgb_c, acc, acc_c, depth
+        rec = fine(None)
+    return jnp.concatenate(
+        [rec[:, 0:3], rgb_c, rec[:, 3:4], acc_c, rec[:, 4:5]], axis=-1)
 
 
-def _make_two_pass_kernel(cfg: NerfConfig, rt: int, Nc: int, Nf: int,
-                          P: int, P2: int, qc: bool, qf: bool,
-                          ert_eps: float, chunk: int,
-                          has_mask: bool = False):
+def _make_two_pass_kernel(cfg: NerfConfig, rt: int, block: int, Nc: int,
+                          Nf: int, P: int, P2: int, qc: bool, qf: bool,
+                          ert_eps: float, has_mask: bool = False):
     nwc = len(_weight_order(qc))
     nwf = len(_weight_order(qf))
 
     def kernel(o_ref, d_ref, tc_ref, *refs):
-        m = None
+        m_ref = None
         if has_mask:
             m_ref, refs = refs[0], refs[1:]
-            m = m_ref[...]
         cw_refs = refs[:nwc]
         fw_refs = refs[nwc:nwc + nwf]
-        rgb_o, rgbc_o, acc_o, accc_o, depth_o = refs[nwc + nwf:]
-        rgb, rgb_c, acc, acc_c, depth = _two_pass_tile(
-            cfg, rt, Nc, Nf, P, P2, qc, qf, ert_eps, chunk,
-            o_ref[...], d_ref[...], tc_ref[...], cw_refs, fw_refs, m)
-        rgb_o[...] = rgb.astype(rgb_o.dtype)
-        rgbc_o[...] = rgb_c.astype(rgbc_o.dtype)
-        acc_o[...] = acc.astype(acc_o.dtype)
-        accc_o[...] = acc_c.astype(accc_o.dtype)
-        depth_o[...] = depth.astype(depth_o.dtype)
+        (out_o,) = refs[nwc + nwf:]
+
+        def ray_block(rows):
+            m = None if m_ref is None else m_ref[rows, :]
+            out_o[rows, :] = _two_pass_rays(
+                cfg, block, Nc, Nf, P, P2, qc, qf, ert_eps,
+                o_ref[rows, :], d_ref[rows, :], tc_ref[...], cw_refs,
+                fw_refs, m).astype(out_o.dtype)
+
+        _for_each_block(rt, block, ray_block)
 
     return kernel
 
 
 def two_pass_plcore_call(cfg: NerfConfig, packed_c: dict, packed_f: dict,
                          rays_o, rays_d, t_row, *, rt: int, ert_eps: float,
-                         chunk: int, interpret: bool = True,
-                         emulate_grid: Optional[bool] = None,
-                         alive=None):
+                         interpret: bool = True, block: Optional[int] = None,
+                         alive=None, vmem_limit_bytes: Optional[int] = None):
     """ONE pallas_call per ray tile for the complete coarse -> importance
     -> fine chain. rays: (R, 3) with R % rt == 0; t_row: (1, n_coarse)
     deterministic coarse sample positions (identical for every ray —
     inference mode). ``packed_c``/``packed_f``: stack_plcore_weights
     layouts for the two networks, both pinned in VMEM simultaneously.
 
-    Off-TPU (``interpret=True``) the ray-tile grid runs by default
-    through a ``lax.map`` emulator over the SAME tile body instead of the
-    Pallas interpreter: identical semantics (held to fp32 tolerance by
-    the parity test — XLA compiles the shared jaxpr with different gemm
-    blocking in the two surroundings), without the interpreter's per-step
-    block machinery, and ERT's ``lax.cond`` chunk skips stay
-    runtime-real. Force the Pallas interpreter with
-    ``emulate_grid=False``.
+    ``block``: rays per inner-loop step (default: the whole tile); it
+    must divide rt. Off-TPU (``interpret=True``) the same kernel runs
+    under the Pallas interpreter; ERT's ``lax.cond`` block skips stay
+    runtime-real there.
 
     ``alive``: optional (R,) float mask of externally-live rows (0 = the
     adaptive path already has this ray's pixel memoized): dead rows join
-    the ERT compaction and skip the fine MLP.
+    the ERT-dead set and skip the fine MLP. ``vmem_limit_bytes``: the
+    scoped-VMEM limit the compiler is given (None: its default).
 
     Returns (rgb (R,3), rgb_coarse (R,3), acc (R,), acc_coarse (R,),
     depth (R,)); the caller composites white background.
@@ -459,61 +442,27 @@ def two_pass_plcore_call(cfg: NerfConfig, packed_c: dict, packed_f: dict,
     R = rays_o.shape[0]
     Nc = t_row.shape[-1]
     assert R % rt == 0, (R, rt)
-    assert (ert_eps == 0.0 and alive is None) or rt % chunk == 0, (rt, chunk)
+    block = rt if block is None else block
     P = -(-(cfg.trunk_width + cfg.pos_enc_dim) // 128) * 128
     P2 = -(-(cfg.trunk_width + cfg.dir_enc_dim) // 128) * 128
     qc = "trunk_mag" in packed_c
     qf = "trunk_mag" in packed_f
-    wc = [packed_c[k] for k in _weight_order(qc)]
-    wf = [packed_f[k] for k in _weight_order(qf)]
-
-    if emulate_grid is None:
-        emulate_grid = interpret
-    if emulate_grid:
-        def tile(od):
-            o_t, d_t, m_t = od
-            return _two_pass_tile(cfg, rt, Nc, cfg.n_fine, P, P2, qc, qf,
-                                  float(ert_eps), chunk,
-                                  o_t, d_t, t_row, wc, wf, m_t)
-        m_full = (None if alive is None
-                  else alive.astype(jnp.float32).reshape(-1, rt))
-        if R == rt:            # single-tile grid: no scan wrapper at all
-            return tile((rays_o, rays_d,
-                         None if m_full is None else m_full[0]))
-        if alive is None:
-            def tile(od, _tile=tile):
-                o_t, d_t = od
-                return _tile((o_t, d_t, None))
-            outs = jax.lax.map(tile, (rays_o.reshape(-1, rt, 3),
-                                      rays_d.reshape(-1, rt, 3)))
-        else:
-            outs = jax.lax.map(tile, (rays_o.reshape(-1, rt, 3),
-                                      rays_d.reshape(-1, rt, 3), m_full))
-        return tuple(x.reshape((R,) + x.shape[2:]) for x in outs)
-
-    grid = (R // rt,)
-    ray_spec = pl.BlockSpec((rt, 3), lambda i: (i, 0))
-    pix_spec = pl.BlockSpec((rt, 3), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((rt,), lambda i: (i,))
-    mask_spec = pl.BlockSpec((rt,), lambda i: (i,))
-    out_shape = [jax.ShapeDtypeStruct((R, 3), jnp.float32),
-                 jax.ShapeDtypeStruct((R, 3), jnp.float32),
-                 jax.ShapeDtypeStruct((R,), jnp.float32),
-                 jax.ShapeDtypeStruct((R,), jnp.float32),
-                 jax.ShapeDtypeStruct((R,), jnp.float32)]
-    out_specs = [pix_spec, pix_spec, vec_spec, vec_spec, vec_spec]
-
+    wc = _kernel_weights([packed_c[k] for k in _weight_order(qc)])
+    wf = _kernel_weights([packed_f[k] for k in _weight_order(qf)])
     has_mask = alive is not None
-    mask_in = [alive.astype(jnp.float32)] if has_mask else []
-    kernel = _make_two_pass_kernel(cfg, rt, Nc, cfg.n_fine, P, P2, qc, qf,
-                                   float(ert_eps), chunk, has_mask)
-    return pl.pallas_call(
+    mask_in = [alive.astype(jnp.float32).reshape(R, 1)] if has_mask else []
+
+    kernel = _make_two_pass_kernel(cfg, rt, block, Nc, cfg.n_fine, P, P2,
+                                   qc, qf, float(ert_eps), has_mask)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[ray_spec, ray_spec, _pinned(t_row)]
-                 + ([mask_spec] if has_mask else [])
+        grid=(R // rt,),
+        in_specs=[_rows(rt, 3), _rows(rt, 3), _pinned(t_row)]
+                 + ([_rows(rt, 1)] if has_mask else [])
                  + [_pinned(a) for a in wc] + [_pinned(a) for a in wf],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=_rows(rt, 9),
+        out_shape=jax.ShapeDtypeStruct((R, 9), jnp.float32),
+        compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
     )(rays_o, rays_d, t_row, *mask_in, *wc, *wf)
+    return out[:, 0:3], out[:, 3:6], out[:, 6], out[:, 7], out[:, 8]

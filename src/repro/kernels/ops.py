@@ -24,6 +24,16 @@ def interpret_default() -> bool:
     return jax.devices()[0].platform != "tpu"
 
 
+def _interpret(cfg: NerfConfig, interpret: Optional[bool]) -> bool:
+    """The call's own ``interpret``, else ``cfg.kernel_interpret``, else
+    the platform default."""
+    if interpret is not None:
+        return interpret
+    if cfg.kernel_interpret is not None:
+        return cfg.kernel_interpret
+    return interpret_default()
+
+
 def _rup(v: int, m: int) -> int:
     return -(-v // m) * m
 
@@ -188,29 +198,15 @@ def unstack_trunk_params(cfg: NerfConfig, packed: dict):
 
 
 # ------------------------------------------------------------ fused render --
-def plcore_weight_vmem_bytes(cfg: NerfConfig) -> int:
-    """f32 footprint of one network's GATHERED stacked weight layout — the
-    working set the kernel pins in VMEM every grid step (conservative for
-    the smaller RMCM-packed layout). With mesh-sharded weights this is
-    unchanged: the per-layer all-gather re-materializes full layers
-    just-in-time for compute; what sharding shrinks is the HBM-RESIDENT
-    footprint, ``plcore_resident_weight_bytes``."""
-    W, C, L = cfg.trunk_width, cfg.color_width, cfg.trunk_layers
-    P = _rup(W + cfg.pos_enc_dim, 128)
-    P2 = _rup(W + cfg.dir_enc_dim, 128)
-    n = L * P * W + W * W + P2 * C + W * 1 + C * 3      # matrices
-    n += L * W + W + C + 1 + 3                          # biases
-    return 4 * n
-
-
 def plcore_resident_weight_bytes(cfg: NerfConfig, n_shards: int = 1) -> int:
     """Per-device HBM bytes of one network's f32 packed layout when the
     trunk stacks are layer-sharded ``n_shards`` ways (heads stay
     replicated — every mesh cell reads them every pass). n_shards=1 is
-    exactly ``plcore_weight_vmem_bytes``: the replicated residency. This
+    exactly the f32 packed layout's bytes: the replicated residency. This
     is the quantity the serving SceneCache budgets against — resident
     bytes scale ~1/n_shards with the mesh while the VMEM working set
-    (gathered just-in-time) stays a constant."""
+    (gathered just-in-time, ``kernel_weight_vmem_bytes``) stays a
+    constant."""
     W, C, L = cfg.trunk_width, cfg.color_width, cfg.trunk_layers
     P = _rup(W + cfg.pos_enc_dim, 128)
     P2 = _rup(W + cfg.dir_enc_dim, 128)
@@ -219,18 +215,157 @@ def plcore_resident_weight_bytes(cfg: NerfConfig, n_shards: int = 1) -> int:
     return 4 * (trunk // max(1, int(n_shards)) + heads)
 
 
-def pick_ray_tile(cfg: NerfConfig, n_samples: int,
-                  vmem_budget_bytes: Optional[int] = None) -> int:
-    """rt so resident weights + the (rt * N, P) fp32 activation slab fit
-    the VMEM budget (``cfg.kernel_vmem_budget_mb`` unless overridden)."""
+# VMEM model shared by the tile pickers and the compiler's scoped-VMEM
+# limit (the kernels are compiled with ``vmem_limit_bytes`` = this model,
+# so a model that under-counts fails to compile — tests/test_tpu_compile
+# holds it to that at CONFIG). Mosaic lays every block and value out in
+# (sublane, 128-lane) tiles, so a row of any width costs whole lanes.
+def _row_bytes(width: int, itemsize: int = 4) -> int:
+    return itemsize * _rup(width, 128)
+
+
+def _tile_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of one array in (8 * 4 / itemsize, 128) tiles."""
+    shape = tuple(shape)
+    if len(shape) == 1:
+        shape = (1,) + shape
+    lead = int(np.prod(shape[:-2])) if len(shape) > 2 else 1
+    return lead * _rup(shape[-2], 32 // itemsize) * _row_bytes(shape[-1],
+                                                                itemsize)
+
+
+def kernel_weight_shapes(cfg: NerfConfig, quantized: bool) -> list:
+    """(shape, itemsize) of each array of one network's packed layout as
+    the kernels receive it (``stack_plcore_weights``; 1-D biases as
+    (1, n) rows) — in ``fused_plcore._weight_order`` order."""
+    W, C, L = cfg.trunk_width, cfg.color_width, cfg.trunk_layers
+    P = _rup(W + cfg.pos_enc_dim, 128)
+    P2 = _rup(W + cfg.dir_enc_dim, 128)
+    heads = {"trunk_b": ((L, W), 4), "sigma_w": ((W, 1), 4),
+             "sigma_b": ((1, 1), 4), "feat_b": ((1, W), 4),
+             "color0_b": ((1, C), 4), "rgb_w": ((C, 3), 4),
+             "rgb_b": ((1, 3), 4)}
+    if quantized:
+        Wf = _rup(W, 8)
+        mats = {"trunk_mag": ((L, P, W), 1), "trunk_sgn": ((L, P // 8, W), 1),
+                "trunk_scl": ((L, 1, W), 4), "feat_mag": ((Wf, W), 1),
+                "feat_sgn": ((Wf // 8, W), 1), "feat_scl": ((1, W), 4),
+                "color0_mag": ((P2, C), 1), "color0_sgn": ((P2 // 8, C), 1),
+                "color0_scl": ((1, C), 4)}
+    else:
+        mats = {"trunk_w": ((L, P, W), 4), "feat_w": ((W, W), 4),
+                "color0_w": ((P2, C), 4)}
+    shapes = {**heads, **mats}
+    return [shapes[k] for k in _fp._weight_order(quantized)]
+
+
+def kernel_weight_vmem_bytes(cfg: NerfConfig, quantized: bool) -> int:
+    """VMEM one network's packed layout occupies (single-buffered)."""
+    return sum(_tile_bytes(s, i) for s, i in
+               kernel_weight_shapes(cfg, quantized))
+
+
+def _act_row_bytes(cfg: NerfConfig) -> int:
+    """VMEM per sample row of one pass's live values: the PE, three
+    trunk-width activations, the fused sigma|feat head, the colour
+    branch before and after its ReLU, and five narrow per-sample columns
+    (position, t, delta, RGB logits, RGB)."""
+    W, C = cfg.trunk_width, cfg.color_width
+    return (_row_bytes(cfg.pos_enc_dim) + 3 * _row_bytes(W)
+            + _row_bytes(W + 1) + 2 * _row_bytes(C) + 5 * _row_bytes(3))
+
+
+def _net_scratch_bytes(cfg: NerfConfig, n_samples: int) -> int:
+    """Per network pass: the (W, W+1) sigma|feat head matrix the body
+    builds, and the pass's (N, N) prefix-sum triangle."""
+    W = cfg.trunk_width
+    return (_rup(W, 8) * _row_bytes(W + 1)
+            + _rup(n_samples, 8) * _row_bytes(n_samples))
+
+
+# Sample rows one inner-loop step of a kernel works on: the compiled body
+# (and its VMEM scratch) grows with it, the MXU's row utilization too.
+_BLOCK_SAMPLE_ROWS = 512
+
+
+def pick_ray_block(n_samples: int) -> int:
+    """Rays per inner-loop step on the chip: the most whose samples fit
+    ``_BLOCK_SAMPLE_ROWS`` rows — a power of two from 8 up, else ONE ray:
+    Mosaic loads a block of 2 or 4 rows at a dynamic offset only from
+    arrays at most 128 lanes wide (the (rt, N) sample blocks are wider)."""
+    g = _BLOCK_SAMPLE_ROWS // max(1, n_samples)
+    if g < 8:
+        return 1
+    p = 8
+    while 2 * p <= g:
+        p *= 2
+    return p
+
+
+def _ray_block(rt: int, want: int) -> int:
+    """The block the kernel's loop steps by: ``want`` (a power of two)
+    halved until it divides rt, and 1 below 8 (see ``pick_ray_block``)."""
+    g = min(want, rt)
+    while g > 1 and rt % g:
+        g //= 2
+    return g if g >= 8 or g == rt else 1
+
+
+def fused_vmem_bytes(cfg: NerfConfig, n_samples: int, rt: int, block: int,
+                     quantized: bool = False) -> int:
+    """Scoped VMEM of the one-pass kernel at ray tile ``rt``: one network
+    single-buffered, the double-buffered per-ray blocks (rays, t, deltas,
+    mask in; rgb, w, acc out), one block's pass."""
+    io = 2 * rt * (5 * _row_bytes(3) + 3 * _row_bytes(n_samples))
+    return (kernel_weight_vmem_bytes(cfg, quantized) + io
+            + block * n_samples * _act_row_bytes(cfg)
+            + _net_scratch_bytes(cfg, n_samples))
+
+
+def two_pass_vmem_bytes(cfg: NerfConfig, rt: int, block: int,
+                        quantized: bool = False) -> int:
+    """Scoped VMEM of the two-pass kernel at ray tile ``rt``: BOTH
+    networks single-buffered (their block never moves), the
+    double-buffered per-ray blocks (o, d, mask in; the (rt, 9) record
+    out), and one block's two passes — coarse and fine values both
+    live — with the resample's (n_fine, n_coarse - 1) one-hots and the
+    rank merge's (n, n_coarse + n_fine) ones per ray."""
+    Nc, Nf = cfg.n_coarse, cfg.n_fine
+    Nt = Nc + Nf
+    io = 2 * rt * 4 * _row_bytes(9)
+    resample = block * (3 * Nf * _row_bytes(Nc - 1)
+                        + 2 * Nt * _row_bytes(Nt))
+    return (2 * kernel_weight_vmem_bytes(cfg, quantized) + io
+            + block * (Nc + Nt) * _act_row_bytes(cfg) + resample
+            + _net_scratch_bytes(cfg, Nc) + _net_scratch_bytes(cfg, Nt))
+
+
+def _budget(cfg: NerfConfig, vmem_budget_bytes: Optional[int]) -> int:
     if vmem_budget_bytes is None:
-        vmem_budget_bytes = int(cfg.kernel_vmem_budget_mb * (1 << 20))
-    # weights stay pinned across all grid steps; the slab gets the rest
-    slab = max(vmem_budget_bytes - plcore_weight_vmem_bytes(cfg), 1 << 18)
-    P = _rup(cfg.trunk_width + cfg.pos_enc_dim, 128)
-    rows = slab // (P * 4)
-    rt = max(8, (rows // n_samples) // 8 * 8)
-    return min(rt, 128)
+        return int(cfg.kernel_vmem_budget_mb * (1 << 20))
+    return int(vmem_budget_bytes)
+
+
+def _largest_tile(fits, cap: int) -> int:
+    """Largest power-of-two rt in [8, cap] with fits(rt); 8 if none does
+    (the kernel is then compiled with a limit above the budget)."""
+    rt = cap
+    while rt > 8 and not fits(rt):
+        rt //= 2
+    return rt
+
+
+def pick_ray_tile(cfg: NerfConfig, n_samples: int,
+                  vmem_budget_bytes: Optional[int] = None,
+                  quantized: bool = False) -> int:
+    """rt so the one-pass kernel's ``fused_vmem_bytes`` fits the VMEM
+    budget (``cfg.kernel_vmem_budget_mb`` unless overridden)."""
+    budget = _budget(cfg, vmem_budget_bytes)
+    block = pick_ray_block(n_samples)
+    return _largest_tile(
+        lambda rt: fused_vmem_bytes(cfg, n_samples, rt,
+                                    _ray_block(rt, block),
+                                    quantized) <= budget, 128)
 
 
 def fused_render(cfg: NerfConfig, params: Optional[dict], rays_o, rays_d, t,
@@ -248,10 +383,18 @@ def fused_render(cfg: NerfConfig, params: Optional[dict], rays_o, rays_d, t,
     kernel tiles skip MLP+VRU work.
     """
     _DISPATCHES.inc()
-    it = interpret_default() if interpret is None else interpret
+    it = _interpret(cfg, interpret)
     R, N = t.shape
-    rt = rt or pick_ray_tile(cfg, N, vmem_budget_bytes)
+    if packed is None:
+        packed = stack_plcore_weights(cfg, params, quant)
+        quantized = quant is not None
+    else:
+        quantized = "trunk_mag" in packed
+    rt = rt or pick_ray_tile(cfg, N, vmem_budget_bytes, quantized)
     rt = min(rt, _rup(R, 8))
+    # the interpreter runs the tile as one block; on the chip the block
+    # bounds the compiled body
+    block = rt if it else _ray_block(rt, pick_ray_block(N))
     Rp = _rup(R, rt)
     if Rp != R:
         padn = Rp - R
@@ -262,65 +405,38 @@ def fused_render(cfg: NerfConfig, params: Optional[dict], rays_o, rays_d, t,
         if alive is not None:   # padded rays are dead
             alive = jnp.concatenate(
                 [alive, jnp.zeros((padn,), alive.dtype)])
-    if packed is None:
-        packed = stack_plcore_weights(cfg, params, quant)
-        quantized = quant is not None
-    else:
-        quantized = "trunk_mag" in packed
+    vmem = None if it else fused_vmem_bytes(cfg, N, rt, block, quantized)
     rgb, w, acc = _fp.fused_plcore_call(
         cfg, packed, rays_o, rays_d, t, deltas,
-        rt=rt, quantized=quantized, alive=alive, interpret=it)
+        rt=rt, quantized=quantized, alive=alive, interpret=it, block=block,
+        vmem_limit_bytes=vmem)
     return rgb[:R], {"weights": w[:R], "acc": acc[:R]}
 
 
 # ------------------------------------------------ one-kernel two-pass render --
 def pick_ray_tile_two_pass(cfg: NerfConfig,
-                           vmem_budget_bytes: Optional[int] = None) -> int:
-    """rt for the single-dispatch two-pass kernel, sized on the
-    sharded-resident + gathered-working-set model: BOTH networks' weight
-    stacks occupy VMEM every grid step as the GATHERED working set (2x
-    the one-pass ``plcore_weight_vmem_bytes`` — with mesh-sharded
-    weights the per-layer all-gather re-materializes full layers before
-    the kernel launches, so the VMEM term does not shrink; only the
-    HBM-resident footprint does, ``plcore_resident_weight_bytes``), and
-    the per-ray scratch adds the fine-pass activation slab ((Nc+Nf) x P)
-    plus the resample one-hot (Nf x (Nc-1)), the rank-merge scatter
-    one-hots ((Nc+Nf)^2) and the O(rt) compaction permutation."""
-    if vmem_budget_bytes is None:
-        vmem_budget_bytes = int(cfg.kernel_vmem_budget_mb * (1 << 20))
-    weights = 2 * plcore_weight_vmem_bytes(cfg)
-    slab = max(vmem_budget_bytes - weights, 1 << 18)
-    P = _rup(cfg.trunk_width + cfg.pos_enc_dim, 128)
-    Nt = cfg.n_coarse + cfg.n_fine
-    per_ray = 4 * (Nt * P                            # fine activation slab
-                   + cfg.n_fine * (cfg.n_coarse - 1)  # resample one-hot
-                   + Nt * Nt                         # rank-merge scatter
-                   + 512)                            # compaction row (rt<=512)
-    rt = max(8, (slab // per_ray) // 8 * 8)
-    # cap above the one-pass kernel's 128: the two-pass kernel amortizes
-    # its per-grid-step cost (both weight sets re-pinned, resample
-    # scratch) over the whole chain, so bigger tiles win when they fit.
-    # Powers of two only, so any pow2 ray batch is tiled without padding.
-    cap = 512
-    while cap > 8 and cap > rt:
-        cap //= 2
-    return cap
-
-
-def _ert_chunk(rt: int, want_rows: int) -> int:
-    """Largest multiple of 8 that divides rt and is <= want_rows — the
-    fixed-capacity granularity of the per-ray ERT compaction."""
-    c = max(8, (min(want_rows, rt) // 8) * 8)
-    while rt % c:
-        c -= 8
-    return max(c, 8)
+                           vmem_budget_bytes: Optional[int] = None,
+                           quantized: bool = False) -> int:
+    """rt for the single-dispatch two-pass kernel: the largest power of
+    two (at most 512) whose ``two_pass_vmem_bytes`` fits the budget.
+    BOTH networks' weight stacks occupy VMEM every grid step as the
+    GATHERED working set (with mesh-sharded weights the per-layer
+    all-gather re-materializes full layers before the kernel launches, so
+    the VMEM term does not shrink; only the HBM-resident footprint does,
+    ``plcore_resident_weight_bytes``). Powers of two only, so any pow2
+    ray batch is tiled without padding."""
+    budget = _budget(cfg, vmem_budget_bytes)
+    block = pick_ray_block(cfg.n_coarse + cfg.n_fine)
+    return _largest_tile(
+        lambda rt: two_pass_vmem_bytes(cfg, rt, _ray_block(rt, block),
+                                       quantized) <= budget, 512)
 
 
 def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
                           ert_eps: float = 0.0, rt: Optional[int] = None,
+                          block: Optional[int] = None,
                           vmem_budget_bytes: Optional[int] = None,
                           interpret: Optional[bool] = None,
-                          emulate_grid: Optional[bool] = None,
                           alive=None) -> dict:
     """The complete coarse -> importance -> fine render as ONE pallas_call
     per ray tile (deterministic/inference sampling; coarse weights never
@@ -329,39 +445,42 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
     trunk layers first via runtime.sharding.gather_plcore_packed (the
     pipeline does this inside the same jitted program, so the gathers
     overlap the preceding compute). ``ert_eps`` > 0 enables per-ray
-    early-termination compaction inside the kernel. ``alive``: optional
-    (R,) float mask — rows with 0 (adaptive trunk-memo hits) enter the
-    kernel dead and the ERT compaction skips their fine pass. Returns
+    early termination inside the kernel. ``alive``: optional (R,) float
+    mask — rows with 0 (adaptive trunk-memo hits) enter the kernel dead
+    and skip their fine pass. ``rt``/``block``: ray tile per grid step
+    and rays per inner-loop step (defaults: off the chip the whole batch,
+    up to 2048 rays, as one block — the interpreter has no VMEM; on the
+    chip the VMEM model's tile and ``pick_ray_block``). Returns
     {rgb, rgb_coarse, acc, acc_coarse, depth}, each trimmed to R rays;
     white background is the caller's composite.
     """
     _DISPATCHES.inc()
-    it = interpret_default() if interpret is None else interpret
+    it = _interpret(cfg, interpret)
     from repro.core import sampling
     R = rays_o.shape[0]
+    quantized = "trunk_mag" in packed["coarse"]
     if rt is None:
-        if it and emulate_grid is not False:
-            # the off-TPU lax.map emulator has no VMEM: the natural tile
-            # is the whole host batch (capped so activations stay sane)
-            rt = min(_rup(R, 8), 2048)
-        else:
-            rt = pick_ray_tile_two_pass(cfg, vmem_budget_bytes)
+        rt = (min(_rup(R, 8), 2048) if it else
+              pick_ray_tile_two_pass(cfg, vmem_budget_bytes, quantized))
     rt = min(rt, _rup(R, 8))
+    if block is None:
+        block = rt if it else pick_ray_block(cfg.n_coarse + cfg.n_fine)
+    block = _ray_block(rt, block)
     Rp = _rup(R, rt)
     if Rp != R:
         padn = Rp - R
         rays_o = jnp.concatenate([rays_o, rays_o[-1:].repeat(padn, 0)])
         rays_d = jnp.concatenate([rays_d, rays_d[-1:].repeat(padn, 0)])
         if alive is not None:
-            # padded rows enter dead: the compaction skips them for free
+            # padded rows enter dead: their blocks skip the fine pass
             alive = jnp.concatenate(
                 [alive, jnp.zeros((padn,), alive.dtype)])
     # deterministic coarse samples are ray-independent: ship ONE row
     t_row = sampling.stratified(cfg.near, cfg.far, cfg.n_coarse, (1,), None)
-    chunk = _ert_chunk(rt, cfg.ert_chunk_rows)
+    vmem = None if it else two_pass_vmem_bytes(cfg, rt, block, quantized)
     rgb, rgb_c, acc, acc_c, depth = _fp.two_pass_plcore_call(
         cfg, packed["coarse"], packed["fine"], rays_o, rays_d, t_row,
-        rt=rt, ert_eps=float(ert_eps), chunk=chunk, interpret=it,
-        emulate_grid=emulate_grid, alive=alive)
+        rt=rt, ert_eps=float(ert_eps), interpret=it, block=block,
+        alive=alive, vmem_limit_bytes=vmem)
     return {"rgb": rgb[:R], "rgb_coarse": rgb_c[:R], "acc": acc[:R],
             "acc_coarse": acc_c[:R], "depth": depth[:R]}

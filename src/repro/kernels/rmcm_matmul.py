@@ -24,9 +24,12 @@ from jax.experimental import pallas as pl
 
 
 def _unpack_signs(bits, bk: int):
-    """(bk//8, bn) uint8 -> (bk, bn) {0,1} int8. Bit j of byte i = row 8i+j."""
-    shifts = jnp.arange(8, dtype=jnp.uint8).reshape(1, 8, 1)
-    expanded = (bits[:, None, :] >> shifts) & jnp.uint8(1)
+    """(bk//8, bn) uint8 -> (bk, bn) {0,1} int32. Bit j of byte i = row
+    8i+j. Unpacks in int32 from an in-register iota: Mosaic has no 8-bit
+    vector shifts, and a kernel may not capture an array constant."""
+    b = bits.astype(jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 8, b.shape[-1]), 1)
+    expanded = (b[:, None, :] >> shifts) & 1
     return expanded.reshape(bk, bits.shape[-1])
 
 

@@ -17,8 +17,8 @@ Two services:
   coarse weights never leave VMEM;
   ``--ert EPS`` enables Cicero-style early ray termination (rays whose
   transmittance after the coarse pass is < EPS skip the fine-pass MLP;
-  under ``--fuse-two-pass`` the kernel compacts alive rays so mixed ray
-  tiles also skip work);
+  under ``--fuse-two-pass`` the kernel skips the fine pass of every ray
+  block whose rays all terminated);
   ``--shard-weights`` shards the packed trunk weight stacks layer-wise
   over the local device mesh (``--shard-devices`` caps how many devices
   the mesh uses; the mesh size must divide the trunk layer count for
@@ -28,7 +28,7 @@ Two services:
   the replicated path;
   ``--vmem-budget-mb`` sizes the fused kernel's VMEM budget — under
   ``--fuse-two-pass`` BOTH networks' gathered weight stacks stay pinned
-  as the working set and the activation slab gets the remainder;
+  as the working set and the per-ray blocks get the remainder;
   ``--tiled`` falls back to the seed per-tile host loop (the benchmark
   baseline — see benchmarks/plcore_fusion.py for the measured gap).
 
@@ -281,6 +281,48 @@ def _parse_host_events(args):
             + [parse(s, "slow") for s in args.host_slow])
 
 
+def make_scene_loader(cfg, scene_ids, *, seed: int = 0,
+                      scene_bias: float = 0.0, rmcm_weights: bool = False,
+                      use_kernel: bool = False, fuse_two_pass: bool = False,
+                      mesh=None, device=None):
+    """The engine's scene loader: scene id -> ``PackedPlcore`` with one
+    synthetic model per id (a distinct param draw from ``seed`` + the
+    id's index stands in for a distinct trained checkpoint). ``mesh``
+    shards each scene's trunk stacks over a (sub-)mesh; ``device`` puts
+    a replicated scene on one device (a replica's own chip)."""
+    scene_ids = list(scene_ids)
+
+    def load_scene(scene_id: str) -> PackedPlcore:
+        idx = scene_ids.index(scene_id)
+        params = init_params(plcore_decls(cfg),
+                             jax.random.PRNGKey(seed + idx), "float32")
+        if scene_bias:
+            # shift the sigma-head bias: negative values carve real
+            # empty space into the synthetic scenes (the canonical
+            # mixed scene for the adaptive-sampling gates is -0.5)
+            for net in params:
+                params[net]["sigma"]["b"] = (
+                    params[net]["sigma"]["b"] + scene_bias)
+        quant = None
+        if rmcm_weights:
+            quant = {"coarse": rmcm.quantize_tree(params["coarse"]),
+                     "fine": rmcm.quantize_tree(params["fine"])}
+        return PackedPlcore(cfg, params, quant=quant, use_kernel=use_kernel,
+                            fuse_two_pass=fuse_two_pass, shard_mesh=mesh,
+                            device=device)
+    return load_scene
+
+
+def host_devices(device_groups, shard_mesh) -> list:
+    """The device each host's replicated scenes live on: its group's
+    first device. None for every host when weights are mesh-sharded (the
+    per-host sub-meshes place them) or for a single host (JAX's default
+    device)."""
+    if shard_mesh is not None or len(device_groups) < 2:
+        return [None] * len(device_groups)
+    return [g[0] for g in device_groups]
+
+
 def serve_engine(args) -> dict:
     """Multi-tenant serving: N scenes behind an LRU weight cache, a
     Poisson request trace through the coalescing RenderEngine — or,
@@ -311,7 +353,7 @@ def serve_engine(args) -> dict:
         # the bit-identity gates need one engine's deterministic memo walk
         if not (args.kernel and args.fuse_two_pass):
             raise SystemExit("--adaptive-sampling rides the fused "
-                             "two-pass kernel's dead-row compaction; it "
+                             "two-pass kernel's dead-row skip; it "
                              "requires --kernel --fuse-two-pass")
         for flag, name in ((args.shard_weights, "--shard-weights"),
                            (args.route_by_shard, "--route-by-shard"),
@@ -345,33 +387,17 @@ def serve_engine(args) -> dict:
                        for g in device_groups]
     else:
         host_meshes = [shard_mesh] * args.hosts
+    # without sharding each host is a replica on its own device: its
+    # scenes and tiles live there, not all on the default device
+    devices = host_devices(device_groups, shard_mesh)
 
     scene_ids = [f"scene{i}" for i in range(args.scenes)]
 
-    def make_loader(mesh):
-        def load_scene(scene_id: str) -> PackedPlcore:
-            # one synthetic model per scene id: a distinct param draw
-            # stands in for a distinct trained checkpoint
-            idx = scene_ids.index(scene_id)
-            params = init_params(plcore_decls(cfg),
-                                 jax.random.PRNGKey(args.seed + idx),
-                                 "float32")
-            if args.scene_bias:
-                # shift the sigma-head bias: negative values carve real
-                # empty space into the synthetic scenes (the canonical
-                # mixed scene for the adaptive-sampling gates is -0.5)
-                for net in params:
-                    params[net]["sigma"]["b"] = (
-                        params[net]["sigma"]["b"] + args.scene_bias)
-            quant = None
-            if args.rmcm:
-                quant = {"coarse": rmcm.quantize_tree(params["coarse"]),
-                         "fine": rmcm.quantize_tree(params["fine"])}
-            return PackedPlcore(cfg, params, quant=quant,
-                                use_kernel=args.kernel,
-                                fuse_two_pass=args.fuse_two_pass,
-                                shard_mesh=mesh)
-        return load_scene
+    def make_loader(mesh, device=None):
+        return make_scene_loader(
+            cfg, scene_ids, seed=args.seed, scene_bias=args.scene_bias,
+            rmcm_weights=args.rmcm, use_kernel=args.kernel,
+            fuse_two_pass=args.fuse_two_pass, mesh=mesh, device=device)
 
     load_scene = make_loader(shard_mesh)
     plan = (FaultPlan(FaultConfig.cluster_chaos(args.fault_seed)
@@ -415,10 +441,10 @@ def serve_engine(args) -> dict:
                       budget_classes=budget_classes,
                       memo_mb=args.memo_mb)
         if chaos and args.hosts > 1:
-            caches = [SceneCache(plan.wrap_loader(make_loader(m))
-                                 if plan else make_loader(m),
+            caches = [SceneCache(plan.wrap_loader(make_loader(m, dev))
+                                 if plan else make_loader(m, dev),
                                  capacity_mb=args.cache_mb)
-                      for m in host_meshes]
+                      for m, dev in zip(host_meshes, devices)]
             return ClusterEngine(caches, meshes=host_meshes,
                                  device_groups=device_groups, **kw)
         if use_cache is None:
@@ -758,8 +784,8 @@ def build_parser():
     ap.add_argument("--fuse-two-pass", action="store_true",
                     help="run the whole coarse->importance->fine chain as "
                          "ONE Pallas kernel per ray tile (requires "
-                         "--kernel; with --ert, compacts alive rays so "
-                         "mixed tiles skip fine-MLP work)")
+                         "--kernel; with --ert, ray blocks whose rays "
+                         "all terminated skip the fine MLP)")
     ap.add_argument("--tiled", action="store_true",
                     help="seed per-tile host loop instead of the "
                          "single-dispatch pipeline")
@@ -921,7 +947,13 @@ def build_parser():
     return ap
 
 
-if __name__ == "__main__":
+def main():
     args = build_parser().parse_args()
-    {"nerf": serve_nerf, "engine": serve_engine,
-     "lm": serve_lm}[args.mode](args)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    return {"nerf": serve_nerf, "engine": serve_engine,
+            "lm": serve_lm}[args.mode](args)
+
+
+if __name__ == "__main__":
+    main()

@@ -57,7 +57,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.metrics import CLUSTER_STATS_SCHEMA, extend_stats_view
@@ -739,7 +738,7 @@ class ClusterEngine(RenderEngine):
         home = sched.route_for(tile.scene_id, pp, host)
         try:
             rgb, cost = pp.dispatch_tile(
-                jnp.asarray(tile.rays_o), jnp.asarray(tile.rays_d),
+                pp.commit(tile.rays_o), pp.commit(tile.rays_d),
                 home_cell=home, coarse_only=tile.degraded)
             arr = np.asarray(rgb)
         except Exception:

@@ -85,7 +85,6 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data import rays as R
@@ -771,7 +770,7 @@ class TileExecutor:
                      else 0.0)
             return rgb, cost, extra
         rgb, cost = tile.pp.dispatch_tile(
-            jnp.asarray(tile.rays_o), jnp.asarray(tile.rays_d),
+            tile.pp.commit(tile.rays_o), tile.pp.commit(tile.rays_d),
             home_cell=tile.home_cell, coarse_only=tile.degraded,
             percell=self.percell,
             tracer=tr if tr.enabled else None,
@@ -844,8 +843,8 @@ class TileExecutor:
         for a, _, _ in tile.spans:
             if not a.terminal:
                 a.fallbacks += 1
-        o = jnp.asarray(tile.rays_o)
-        d = jnp.asarray(tile.rays_d)
+        o = tile.pp.commit(tile.rays_o)
+        d = tile.pp.commit(tile.rays_d)
         arr = np.asarray(
             tile.pp.render_tile(o, d, coarse_only=True) if tile.degraded
             else tile.pp.render_tile_oracle(o, d))

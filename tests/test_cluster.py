@@ -375,3 +375,57 @@ def test_fuzz_cluster_interleaving_always_terminates(setup):
         assert res.status in STATUSES
         if res.delivered:
             assert np.isfinite(res.image).all()
+
+
+# ------------------------------------------ one replica per device --------
+_REPLICA_PLACEMENT = r'''
+import jax
+from repro.configs.nerf_icarus import tiny
+from repro.core.pipeline import PackedPlcore
+from repro.launch.serve import host_devices, make_scene_loader
+from repro.serving import ClusterEngine, RenderRequest, SceneCache, split_devices
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+groups = split_devices(4)
+devices = host_devices(groups, None)
+assert devices == devs, devices
+
+# every dispatched tile: the devices its rays and pixels sit on, keyed by
+# the device its scene was loaded onto
+seen = {}
+dispatch = PackedPlcore.dispatch_tile
+def spy(self, o, d, **kw):
+    rgb, cost = dispatch(self, o, d, **kw)
+    seen.setdefault(self.device, set()).update(
+        o.devices() | d.devices() | rgb.devices())
+    return rgb, cost
+PackedPlcore.dispatch_tile = spy
+
+cfg = tiny()
+scene_ids = [f"scene{i}" for i in range(4)]
+caches = [SceneCache(make_scene_loader(cfg, scene_ids, device=dev),
+                     capacity_mb=64.0) for dev in devices]
+for cache, sid in zip(caches, scene_ids):
+    cache.get(sid)                    # one scene per replica
+eng = ClusterEngine(caches, device_groups=groups, tile_rays=64)
+rids = [eng.submit(RenderRequest(sid, hw=8, theta=40.0 * i))
+        for i, sid in enumerate(scene_ids * 2)]
+eng.drain()
+assert all(eng.completed[r].status == "ok" for r in rids)
+for host, dev in zip(eng.pool.hosts, devs):
+    assert host.dispatches > 0, host.id
+    for sid in host.cache.resident_scenes:
+        pp = host.cache.get(sid)
+        for a in jax.tree.leaves((pp.params, pp.quant, pp.packed)):
+            assert a.devices() == {dev}, (host.id, sid, a.devices())
+    assert seen[dev] == {dev}, (host.id, seen[dev])
+print("ALL OK")
+'''
+
+
+def test_replicas_keep_weights_and_tiles_on_own_device(fake_devices):
+    """Four hosts on four devices, weights replicated (no mesh): each
+    host's resident scene weights and every tile it dispatches — rays in,
+    pixels out — sit on that host's own device, not on device 0."""
+    fake_devices(_REPLICA_PLACEMENT, n_devices=4)

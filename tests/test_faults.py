@@ -176,6 +176,9 @@ class _NaNPlcore:
         self.params, self.quant, self.packed = pp.params, pp.quant, pp.packed
         self.shard_mesh = None
 
+    def commit(self, x):
+        return self._pp.commit(x)
+
     def dispatch_tile(self, o, d, home_cell=None, coarse_only=False):
         rgb, cost = self._pp.dispatch_tile(o, d, home_cell=home_cell,
                                            coarse_only=coarse_only)

@@ -132,8 +132,8 @@ def test_sticky_scene_grouping(setup):
     {"use_kernel": True, "fuse_two_pass": True},
 ])
 def test_kernel_ert_coalescing_matches_per_request(setup, flags):
-    """Kernel paths under ERT: per-kernel-tile skip and alive-ray
-    compaction decisions depend on WHICH rays share a tile — exactly what
+    """Kernel paths under ERT: per-kernel-tile and per-ray-block skip
+    decisions depend on WHICH rays share a tile — exactly what
     cross-request coalescing changes — so the engine output must still
     match the per-request render through the same PackedPlcore."""
     cfg, param_sets = setup

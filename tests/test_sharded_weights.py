@@ -68,9 +68,10 @@ def test_unstack_is_exact_inverse_rmcm():
 
 def test_resident_bytes_model():
     cfg = tiny()
-    # n_shards=1 is exactly the replicated (VMEM working set) footprint
+    # n_shards=1 is exactly the replicated f32 packed layout
+    packed = kops.stack_plcore_weights(cfg, _params(cfg)["coarse"])
     assert (kops.plcore_resident_weight_bytes(cfg, 1)
-            == kops.plcore_weight_vmem_bytes(cfg))
+            == sum(a.nbytes for a in packed.values()))
     full = kops.plcore_resident_weight_bytes(cfg, 1)
     W, L = cfg.trunk_width, cfg.trunk_layers
     P = -(-(W + cfg.pos_enc_dim) // 128) * 128

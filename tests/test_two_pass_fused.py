@@ -5,8 +5,8 @@ sampler (the kernel-shareable forms in core.sampling restate searchsorted
 / gather / sort as comparison counts and one-hot contractions — exact
 arithmetic, not approximations); the fused chain must be one pallas_call
 (kernels.ops.dispatch_count) and match the two-dispatch kernel path; ERT
-compaction must be invisible for all-alive tiles, keep the coarse color
-for all-dead tiles, and match the reference renderer on mixed tiles.
+must be invisible for all-alive tiles, keep the coarse color for all-dead
+tiles, and match the reference renderer on mixed tiles.
 """
 import jax
 import jax.numpy as jnp
@@ -75,6 +75,19 @@ def test_two_pass_is_one_dispatch(setup):
     assert kops.dispatch_count() - n1 == 1
 
 
+def test_kernel_interpret_false_never_falls_back_to_the_interpreter(setup):
+    """A config that asks for the compiled kernel fails off the TPU
+    instead of quietly running under the interpreter."""
+    import dataclasses
+    cfg, params, ro, rd = setup
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    o, d = ro.reshape(-1, 3)[:8], rd.reshape(-1, 3)[:8]
+    with pytest.raises(Exception, match="(?i)interpret"):
+        render_rays(dataclasses.replace(cfg, kernel_interpret=False),
+                    params, o, d, use_kernel=True, fuse_two_pass=True)
+
+
 def test_two_pass_matches_two_dispatch(setup):
     """Same math, one dispatch: the in-VMEM resample chain must track the
     two-dispatch kernel path within fp32 tolerance. The paths run the
@@ -95,26 +108,27 @@ def test_two_pass_matches_two_dispatch(setup):
 
 
 def test_grid_emulator_matches_pallas_interpret(setup):
-    """Off-TPU the two-pass grid runs through a lax.map emulator over the
-    same tile body; it must reproduce the Pallas interpreter within fp32
-    tolerance (same jaxpr compiled inside different surrounding programs,
-    so XLA's gemm blocking reorders fp32 sums; the resampler amplifies
-    those last-ulp diffs), with and without ERT compaction."""
+    """Off-TPU the interpreter runs the whole batch as ONE tile stepped as
+    one block; the chip's shape — a grid of tiles, each walked in small
+    ray blocks, down to the one-ray block CONFIG compiles with — must
+    reproduce it within fp32 tolerance (the same jaxpr at other matmul
+    shapes, so XLA's gemm blocking reorders fp32 sums; the resampler
+    amplifies those last-ulp diffs), with and without ERT."""
     cfg, params, ro, rd = setup
     o, d = ro.reshape(-1, 3), rd.reshape(-1, 3)
     packed = {n: kops.stack_plcore_weights(cfg, params[n], None)
               for n in ("coarse", "fine")}
     for eps in (0.0, 0.05):
-        a = kops.fused_render_two_pass(cfg, packed, o, d, ert_eps=eps,
-                                       emulate_grid=True)
-        b = kops.fused_render_two_pass(cfg, packed, o, d, ert_eps=eps,
-                                       emulate_grid=False)
-        for key in ("rgb", "rgb_coarse", "acc", "acc_coarse"):
-            np.testing.assert_allclose(np.asarray(a[key]),
-                                       np.asarray(b[key]), atol=1e-3,
-                                       err_msg=key)
-        np.testing.assert_allclose(np.asarray(a["depth"]),
-                                   np.asarray(b["depth"]), atol=1e-2)
+        a = kops.fused_render_two_pass(cfg, packed, o, d, ert_eps=eps)
+        for rt, block in ((64, 8), (32, 1)):
+            b = kops.fused_render_two_pass(cfg, packed, o, d, ert_eps=eps,
+                                           rt=rt, block=block)
+            for key in ("rgb", "rgb_coarse", "acc", "acc_coarse"):
+                np.testing.assert_allclose(np.asarray(a[key]),
+                                           np.asarray(b[key]), atol=1e-3,
+                                           err_msg=key)
+            np.testing.assert_allclose(np.asarray(a["depth"]),
+                                       np.asarray(b["depth"]), atol=1e-2)
 
 
 def test_two_pass_rejects_sampling_key(setup):
@@ -160,31 +174,29 @@ def test_fuse_two_pass_requires_kernel(setup):
         PackedPlcore(cfg, params, fuse_two_pass=True)
 
 
-# ----------------------------------------------- per-ray ERT compaction ----
+# -------------------------------------------------- per-ray ERT skips ----
 def test_ert_all_alive_tile_matches_uncompacted(setup):
-    """When no ray terminates, ERT compaction must be invisible: any
-    compaction granularity renders bit-for-bit the same (every all-alive
-    tile takes the monolithic fine path), and the result matches the
-    ERT-off render to the last-ulp wobble of the lax.cond compilation
-    boundary."""
-    from dataclasses import replace
+    """When no ray terminates, ERT must be invisible: the fine pass runs
+    for every block, so the render matches the ERT-off render to the
+    last-ulp wobble of the lax.cond compilation boundary — at the
+    interpreter's one-block tile and at the chip's small blocks."""
     cfg, params, ro, rd = setup
     o, d = ro.reshape(-1, 3), rd.reshape(-1, 3)
     # empty the scene: sigma bias way down -> acc ~ 0 -> every ray alive
     thin = jax.tree.map(lambda x: x, params)
     thin["coarse"]["sigma"]["b"] = thin["coarse"]["sigma"]["b"] - 1e3
-    base = render_rays(cfg, thin, o, d, use_kernel=True, fuse_two_pass=True)
-    a = render_rays(cfg, thin, o, d, use_kernel=True, fuse_two_pass=True,
-                    ert_eps=1e-6)
-    # compaction granularity must be bit-for-bit invisible when all alive
-    cfg1 = replace(cfg, ert_chunk_rows=1024)
-    b = render_rays(cfg1, thin, o, d, use_kernel=True, fuse_two_pass=True,
-                    ert_eps=1e-6)
-    np.testing.assert_array_equal(np.asarray(a["rgb"]), np.asarray(b["rgb"]))
-    # vs ERT off: identical math, but the fine pass sits behind a lax.cond
-    # whose body XLA compiles separately -> last-ulp gemm-blocking wobble
-    np.testing.assert_allclose(np.asarray(base["rgb"]),
-                               np.asarray(a["rgb"]), atol=1e-5)
+    packed = {n: kops.stack_plcore_weights(cfg, thin[n], None)
+              for n in ("coarse", "fine")}
+    for rt, block in ((None, None), (64, 8)):
+        base = kops.fused_render_two_pass(cfg, packed, o, d, rt=rt,
+                                          block=block)
+        a = kops.fused_render_two_pass(cfg, packed, o, d, ert_eps=1e-6,
+                                       rt=rt, block=block)
+        assert bool(jnp.all(a["acc_coarse"] < 1.0 - 1e-6))
+        # identical math, but the fine pass sits behind a lax.cond whose
+        # body XLA compiles separately -> last-ulp gemm-blocking wobble
+        np.testing.assert_allclose(np.asarray(base["rgb"]),
+                                   np.asarray(a["rgb"]), atol=1e-5)
 
 
 def test_ert_all_dead_tile_keeps_coarse(setup):
@@ -203,7 +215,7 @@ def test_ert_all_dead_tile_keeps_coarse(setup):
 
 
 def test_ert_mixed_tile_matches_reference(setup):
-    """Mixed alive/dead tiles: compaction must reproduce the reference
+    """Mixed alive/dead tiles: the block skips must reproduce the reference
     renderer (two-dispatch kernel ERT) — alive rays get the full fine
     render, dead rays keep coarse."""
     cfg, params, ro, rd = setup
@@ -242,8 +254,19 @@ def test_two_pass_ray_tile_accounts_for_both_nets():
             == kops.pick_ray_tile_two_pass(cfg, vmem_budget_bytes=1 << 20))
 
 
-def test_ert_chunk_divides_tile():
-    assert kops._ert_chunk(128, 16) == 16
-    assert kops._ert_chunk(120, 16) == 8
-    assert kops._ert_chunk(8, 64) == 8
-    assert kops._ert_chunk(64, 1024) == 64
+def test_ray_block_divides_tile():
+    """The kernels' inner loop steps by a block that divides the tile and
+    is one ray or a multiple of 8 (Mosaic loads 2 or 4 rows at a dynamic
+    offset only from arrays at most 128 lanes wide)."""
+    assert kops.pick_ray_block(192) == 1          # CONFIG: 64 + 128
+    assert kops.pick_ray_block(64) == 8
+    assert kops.pick_ray_block(32) == 16          # tiny(): 16 + 16
+    assert kops._ray_block(128, 16) == 16
+    assert kops._ray_block(120, 16) == 8
+    assert kops._ray_block(8, 64) == 8
+    assert kops._ray_block(64, 1024) == 64
+    assert kops._ray_block(24, 4) == 1
+    for rt in (8, 24, 120, 512):
+        for want in (1, 8, 16, 64):
+            g = kops._ray_block(rt, want)
+            assert rt % g == 0 and (g == 1 or g % 8 == 0)
