@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.configs.nerf_icarus import NerfConfig
 from repro.core import plcore
+from repro.obs.trace import NULL_TRACER
 
 # Compiled-program caches, keyed on (cfg, flags): cfg is a frozen dataclass
 # (hashable); params/quant/packed enter as traced args so a cache entry
@@ -73,6 +74,9 @@ from repro.core import plcore
 _IMAGE_JITS: dict = {}
 _RAY_JITS: dict = {}
 _TILE_JITS: dict = {}
+# The tile programs' name scope: the HLO (and a device trace) name their
+# operations under it.
+TILE_SCOPE = "plcore.tile"
 
 
 def _donating_jit(fn, donate_names=()):
@@ -208,33 +212,39 @@ def _tile_fn(cfg: NerfConfig, use_kernel: bool, ert_eps: float,
             from repro.core import sampling, volume
 
             def run(params, quant, packed, o_tile, d_tile):
-                params, quant, packed = _materialize(
-                    cfg, params, quant, packed, shard_mesh, use_kernel)
-                t_c = sampling.stratified(cfg.near, cfg.far, cfg.n_coarse,
-                                          o_tile.shape[:-1], None)
-                rgb_c, aux_c = plcore._eval_pass(
-                    cfg, params["coarse"], (quant or {}).get("coarse"),
-                    o_tile, d_tile, t_c, use_kernel,
-                    (packed or {}).get("coarse"))
-                return volume.white_background(rgb_c, aux_c["acc"])
+                with jax.named_scope(TILE_SCOPE):
+                    params, quant, packed = _materialize(
+                        cfg, params, quant, packed, shard_mesh, use_kernel)
+                    t_c = sampling.stratified(cfg.near, cfg.far,
+                                              cfg.n_coarse,
+                                              o_tile.shape[:-1], None)
+                    rgb_c, aux_c = plcore._eval_pass(
+                        cfg, params["coarse"], (quant or {}).get("coarse"),
+                        o_tile, d_tile, t_c, use_kernel,
+                        (packed or {}).get("coarse"))
+                    return volume.white_background(rgb_c, aux_c["acc"])
         elif adaptive:
             def run(params, quant, packed, o_tile, d_tile, alive):
-                params, quant, packed = _materialize(
-                    cfg, params, quant, packed, shard_mesh, use_kernel)
-                out = plcore.render_rays(
-                    cfg, params, o_tile, d_tile, quant=quant, packed=packed,
-                    use_kernel=use_kernel, fuse_two_pass=fuse_two_pass,
-                    ert_eps=ert_eps, white_bkgd=True, alive=alive)
-                return out["rgb"]
+                with jax.named_scope(TILE_SCOPE):
+                    params, quant, packed = _materialize(
+                        cfg, params, quant, packed, shard_mesh, use_kernel)
+                    out = plcore.render_rays(
+                        cfg, params, o_tile, d_tile, quant=quant,
+                        packed=packed, use_kernel=use_kernel,
+                        fuse_two_pass=fuse_two_pass, ert_eps=ert_eps,
+                        white_bkgd=True, alive=alive)
+                    return out["rgb"]
         else:
             def run(params, quant, packed, o_tile, d_tile):
-                params, quant, packed = _materialize(
-                    cfg, params, quant, packed, shard_mesh, use_kernel)
-                out = plcore.render_rays(
-                    cfg, params, o_tile, d_tile, quant=quant, packed=packed,
-                    use_kernel=use_kernel, fuse_two_pass=fuse_two_pass,
-                    ert_eps=ert_eps, white_bkgd=True)
-                return out["rgb"]
+                with jax.named_scope(TILE_SCOPE):
+                    params, quant, packed = _materialize(
+                        cfg, params, quant, packed, shard_mesh, use_kernel)
+                    out = plcore.render_rays(
+                        cfg, params, o_tile, d_tile, quant=quant,
+                        packed=packed, use_kernel=use_kernel,
+                        fuse_two_pass=fuse_two_pass, ert_eps=ert_eps,
+                        white_bkgd=True)
+                    return out["rgb"]
 
         fn = _donating_jit(run, ("o_tile", "d_tile"))
         _TILE_JITS[key] = fn
@@ -496,11 +506,19 @@ class PackedPlcore:
         view = self._cell_views.get(int(cell))
         if view is not None:
             return view
-        if tracer is not None:
-            t0 = tracer.clock()
+        tr = tracer if tracer is not None else NULL_TRACER
+        with tr.span("plcore.stage", cat="plcore") as sp:
+            view = self._stage_cell(int(cell))
+            if sp is not None:
+                cost = self.cell_stage_cost(cell)
+                sp.attrs.update(cell=int(cell), stage_layers=cost["layers"],
+                                stage_bytes=cost["bytes"])
+        return view
+
+    def _stage_cell(self, cell: int) -> dict:
+        """Build, materialize and cache ``cell_view``'s view."""
         from repro.kernels import ops as kops
         from repro.runtime import sharding as rsh
-        cell = int(cell)
         dev = list(self.shard_mesh.devices.flat)[cell]
         staged = {net: rsh.stage_plcore_packed_to_cell(
             p, self.shard_mesh, cell) for net, p in self.packed.items()}
@@ -525,11 +543,6 @@ class PackedPlcore:
         view = {"params": params, "quant": quant, "packed": packed}
         jax.block_until_ready(view)
         self._cell_views[cell] = view
-        if tracer is not None:
-            cost = self.cell_stage_cost(cell)
-            tracer.complete("plcore.stage", t0, cat="plcore", cell=cell,
-                            stage_layers=cost["layers"],
-                            stage_bytes=cost["bytes"])
         return view
 
     def render_tile_cell(self, o_tile, d_tile, cell: int,
@@ -587,32 +600,32 @@ class PackedPlcore:
         if use_percell and (budget is not None or alive is not None):
             raise ValueError("adaptive budgets/masks are a replicated "
                              "single-cell feature — not with percell")
-        if tracer is not None:
-            t0 = tracer.clock()
-        if use_percell:
-            staged_now = int(home_cell) not in self._cell_views
-            rgb = self.render_tile_cell(o_tile, d_tile, home_cell,
-                                        ert_eps=ert_eps,
-                                        coarse_only=coarse_only,
-                                        tracer=tracer)
-            stage = self.cell_stage_cost(home_cell)
-            cost = {"layers": 0, "bytes": 0, "cell": int(home_cell),
-                    "stage_layers": stage["layers"] if staged_now else 0,
-                    "stage_bytes": stage["bytes"] if staged_now else 0}
-        else:
-            rgb = self.render_tile(o_tile, d_tile, ert_eps=ert_eps,
-                                   coarse_only=coarse_only,
-                                   budget=budget, alive=alive)
-            cost = self.tile_gather_cost(home_cell)
-        if tracer is not None:
-            tracer.complete("plcore.dispatch", t0, cat="plcore",
-                            rays=int(o_tile.shape[0]),
-                            coarse_only=bool(coarse_only),
-                            percell=bool(use_percell),
-                            cell=(int(home_cell) if use_percell else -1),
-                            gather_layers=cost["layers"],
-                            gather_bytes=cost["bytes"],
-                            **(trace_attrs or {}))
+        tr = tracer if tracer is not None else NULL_TRACER
+        with tr.span("plcore.dispatch", cat="plcore") as sp:
+            if use_percell:
+                staged_now = int(home_cell) not in self._cell_views
+                rgb = self.render_tile_cell(o_tile, d_tile, home_cell,
+                                            ert_eps=ert_eps,
+                                            coarse_only=coarse_only,
+                                            tracer=tracer)
+                stage = self.cell_stage_cost(home_cell)
+                cost = {"layers": 0, "bytes": 0, "cell": int(home_cell),
+                        "stage_layers": (stage["layers"] if staged_now
+                                         else 0),
+                        "stage_bytes": stage["bytes"] if staged_now else 0}
+            else:
+                rgb = self.render_tile(o_tile, d_tile, ert_eps=ert_eps,
+                                       coarse_only=coarse_only,
+                                       budget=budget, alive=alive)
+                cost = self.tile_gather_cost(home_cell)
+            if sp is not None:
+                sp.attrs.update(rays=int(o_tile.shape[0]),
+                                coarse_only=bool(coarse_only),
+                                percell=bool(use_percell),
+                                cell=(int(home_cell) if use_percell else -1),
+                                gather_layers=cost["layers"],
+                                gather_bytes=cost["bytes"],
+                                **(trace_attrs or {}))
         return rgb, cost
 
 

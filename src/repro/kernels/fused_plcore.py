@@ -323,6 +323,7 @@ def fused_plcore_call(cfg: NerfConfig, weights: dict, rays_o, rays_d, t,
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
+        name="plcore_pass",
     )(rays_o, rays_d, t, deltas, *mask_in, *w_arrays)
     return rgb, w, acc[:, 0]
 
@@ -464,5 +465,6 @@ def two_pass_plcore_call(cfg: NerfConfig, packed_c: dict, packed_f: dict,
         out_shape=jax.ShapeDtypeStruct((R, 9), jnp.float32),
         compiler_params=_compiler_params(vmem_limit_bytes),
         interpret=interpret,
+        name="plcore_two_pass",
     )(rays_o, rays_d, t_row, *mask_in, *wc, *wf)
     return out[:, 0:3], out[:, 3:6], out[:, 6], out[:, 7], out[:, 8]

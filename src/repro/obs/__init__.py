@@ -4,8 +4,10 @@ The measurement substrate under the serving fabric (and the signal
 source for every adaptive ROADMAP item):
 
 * ``trace``   — bounded ring-buffer ``SpanTracer`` with deterministic
-                ids and an injectable clock; ``NULL_TRACER`` is the
-                tracing-off fast path.
+                ids and an injectable clock; ``span`` mirrors a span
+                into any active JAX profile; ``NULL_TRACER`` is the
+                tracing-off fast path; ``watch_compiles`` counts the
+                process's XLA compiles.
 * ``metrics`` — typed ``MetricsRegistry`` (counters / gauges /
                 log-bucket histograms, optional labels); the engine's
                 ``stats`` dict is a registry-backed ``StatsView`` built
@@ -28,9 +30,11 @@ from repro.obs.metrics import (CLUSTER_STATS_SCHEMA, ENGINE_STATS_SCHEMA,
                                StatsView, engine_stats_view,
                                extend_stats_view, global_registry,
                                log_buckets)
-from repro.obs.trace import NULL_TRACER, NullTracer, Span, SpanTracer
+from repro.obs.trace import (NULL_TRACER, NullTracer, Span, SpanTracer,
+                             watch_compiles)
 
 __all__ = ["SpanTracer", "NullTracer", "NULL_TRACER", "Span",
+           "watch_compiles",
            "MetricsRegistry", "StatsView", "EngineMetrics", "Histogram",
            "engine_stats_view", "extend_stats_view", "global_registry",
            "log_buckets", "ENGINE_STATS_SCHEMA", "CLUSTER_STATS_SCHEMA",

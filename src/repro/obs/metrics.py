@@ -419,9 +419,6 @@ class EngineMetrics:
         self.coalesce_seconds = registry.histogram(
             f"{prefix}_coalesce_seconds",
             "scene resolve + ray coalescing per tile", unit="s")
-        self.inflight_seconds = registry.histogram(
-            f"{prefix}_tile_inflight_seconds",
-            "dispatch enqueue -> drain materialization per tile", unit="s")
         self.service_seconds = registry.histogram(
             f"{prefix}_tile_service_seconds",
             "per-tile service time feeding the admission EWMA", unit="s")

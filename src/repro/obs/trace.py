@@ -18,20 +18,31 @@ INSTANT events in one bounded ring. Design constraints, in order:
   code tests ``tracer.enabled`` only where it would otherwise do real
   work (building attribute dicts). The tracing-off overhead is gated
   < 3% by the ``serving.observability`` benchmark block.
+* **On the profiler's clock too.** ``span`` (a context manager) opens
+  and closes a ring span like ``begin``/``end`` and, while the tracer
+  is enabled, wraps it in a ``jax.profiler.TraceAnnotation`` of the
+  same bare name, so an active JAX profile holds the host's spans beside
+  the device's operations. The ring's stamps are read inside the
+  annotation, which keeps its cost out of the span durations.
 
-Span taxonomy (docs/observability.md): ``request.*`` lifecycle,
-``tile.*`` per-dispatch chain (coalesce -> dispatch -> device_compute ->
-drain -> scatter, with retry / fallback / redispatch / requeue /
-abandon / drop branches), ``cache.*`` residency, ``host.*`` cluster
-events, ``plcore.dispatch`` device-side enqueue.
+Span taxonomy (docs/observability.md): ``engine.step`` / ``.submit``
+around the engine's two entry points, ``request.*`` lifecycle,
+``tile.*`` per-dispatch chain (coalesce -> commit -> dispatch ->
+device_compute -> wait -> fetch -> drain -> scatter, with retry /
+fallback / redispatch / requeue / abandon / drop branches), ``cache.*``
+residency, ``host.*`` cluster events, ``plcore.dispatch`` device-side
+enqueue, ``jax.compile`` backend compiles (``watch_compiles``).
 """
 from __future__ import annotations
 
 import time
+import weakref
 from collections import deque
+from contextlib import nullcontext
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanTracer", "NullTracer", "NULL_TRACER"]
+__all__ = ["Span", "SpanTracer", "NullTracer", "NULL_TRACER",
+           "watch_compiles"]
 
 
 class Span:
@@ -80,6 +91,12 @@ class NullTracer:
     def complete(self, name, t0, cat="engine", **attrs):
         return None
 
+    def span(self, name, cat="engine", **attrs):
+        return _NULL_SCOPE
+
+    def discard(self, span):
+        pass
+
     def sampled_request(self, rid: int) -> bool:
         return False
 
@@ -91,6 +108,35 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+_NULL_SCOPE = nullcontext()
+
+
+class _Scope:
+    """``SpanTracer.span``'s context: a ring span inside a profiler
+    annotation of the same bare name (attributes stay in the ring: a
+    ``#k=v#`` suffix would split the profile's grouping by name)."""
+    __slots__ = ("tracer", "name", "cat", "attrs", "span", "annotation")
+
+    def __init__(self, tracer: "SpanTracer", name: str, cat: str,
+                 attrs: dict):
+        self.tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.attrs = attrs
+
+    def __enter__(self) -> Span:
+        from jax import profiler
+        self.annotation = profiler.TraceAnnotation(self.name)
+        self.annotation.__enter__()
+        self.span = self.tracer.begin(self.name, self.cat, **self.attrs)
+        return self.span
+
+    def __exit__(self, *exc) -> None:
+        try:
+            if self.span.sid in self.tracer._open:     # not discarded
+                self.tracer.end(self.span)
+        finally:
+            self.annotation.__exit__(*exc)
 
 
 class SpanTracer:
@@ -114,6 +160,8 @@ class SpanTracer:
         self._open: Dict[int, Span] = {}
         self._sid = 0
         self.dropped = 0
+        watch_compiles()
+        _LIVE_TRACERS.add(self)
 
     # ------------------------------------------------------------ emit ----
     def _next_sid(self) -> int:
@@ -161,6 +209,20 @@ class SpanTracer:
         self._commit(span)
         return span
 
+    def span(self, name: str, cat: str = "engine", **attrs) -> _Scope:
+        """``with tracer.span(name, cat, **attrs) as sp:`` — the ring
+        span ``begin``/``end`` would write, mirrored into any active JAX
+        profile. ``sp`` is the open ``Span`` (``None`` from
+        ``NULL_TRACER``): set final attributes on ``sp.attrs``, or drop
+        it with ``discard``."""
+        return _Scope(self, name, cat, attrs)
+
+    def discard(self, span: Optional[Span]) -> None:
+        """Forget an open span without committing it (work that turned
+        out to be nothing, such as a coalesce that found no rays)."""
+        if span is not None:
+            self._open.pop(span.sid, None)
+
     # ------------------------------------------------------------ read ----
     def sampled_request(self, rid: int) -> bool:
         return self.sample_every <= 1 or rid % self.sample_every == 0
@@ -187,3 +249,48 @@ class SpanTracer:
             "capacity": self.capacity,
             "sample_every": self.sample_every,
         }
+
+
+# ---------------------------------------------------------------------------
+# Backend compiles, counted inside the program: one process-wide
+# jax.monitoring listener (jax keeps listeners for the life of the
+# process, so it is registered once) feeds global_registry() and writes a
+# back-dated ``jax.compile`` span into every live enabled tracer that
+# runs on the host clock the duration is measured on.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_LIVE_TRACERS: "weakref.WeakSet[SpanTracer]" = weakref.WeakSet()
+_WATCHING = False
+
+
+def watch_compiles() -> None:
+    """Register the compile listener (idempotent): ``jax_compiles_total``
+    and ``jax_compile_seconds`` in ``global_registry()``, and a
+    ``jax.compile`` span ending now in each live ``SpanTracer`` whose
+    clock is ``time.perf_counter`` (a tracer on another clock, such as a
+    test's fake one, cannot place a host-clock interval)."""
+    global _WATCHING
+    if _WATCHING:
+        return
+    from jax import monitoring
+
+    from repro.obs.metrics import global_registry
+    reg = global_registry()
+    count = reg.counter("jax_compiles_total",
+                        "XLA backend compiles in this process")
+    seconds = reg.histogram("jax_compile_seconds",
+                            "duration of each XLA backend compile",
+                            unit="s")
+
+    def on_duration(event: str, duration: float, **kwargs) -> None:
+        if event != COMPILE_EVENT:
+            return
+        count.inc()
+        seconds.observe(duration)
+        now = time.perf_counter()
+        for tr in list(_LIVE_TRACERS):
+            if tr.clock is time.perf_counter:
+                tr._commit(Span(tr._next_sid(), "jax.compile", "jax", "X",
+                                now - duration, now, {}))
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _WATCHING = True

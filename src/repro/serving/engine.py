@@ -85,11 +85,12 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.data import rays as R
 from repro.obs.metrics import (MetricsRegistry, engine_stats_view)
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, watch_compiles
 from repro.serving.faults import FaultPlan, InjectedDispatchError
 from repro.serving.scene_cache import SceneCache, SceneLoadError
 
@@ -383,9 +384,17 @@ class TileScheduler:
                              f"hw={req.hw}")
         rid = self._seq
         self._seq += 1
-        a = _Active(req, rid, rid, self._clock())
-        a.dispatches_at_submit = self.stats["dispatches"]
+        with self.tracer.span("engine.submit", request=rid,
+                              scene=req.scene_id):
+            return self._admit(req, rid)
+
+    def _admit(self, req: RenderRequest, rid: int) -> int:
+        """The body of ``submit``: build the queue entry (its camera
+        rays), then admit or reject it."""
         tr = self.tracer
+        with tr.span("request.rays", cat="request", hw=req.hw):
+            a = _Active(req, rid, rid, self._clock())
+        a.dispatches_at_submit = self.stats["dispatches"]
         if tr.enabled and tr.sampled_request(rid):
             a.trace_span = tr.begin("request", cat="request", request=rid,
                                     scene=req.scene_id, hw=req.hw,
@@ -556,8 +565,28 @@ class TileScheduler:
     def next_tile(self) -> Optional[_Tile]:
         """Coalesce ONE tile from the best loadable scene's pending
         requests in queue order (scene + residency resolution in
-        ``_resolve_scene``); ``None`` when nothing is schedulable."""
-        t_coalesce0 = self._clock()
+        ``_resolve_scene``); ``None`` when nothing is schedulable. A
+        produced tile is traced as ``tile.coalesce``."""
+        tr = self.tracer
+        with tr.span("tile.coalesce", cat="tile") as sp:
+            t0 = self._clock() if sp is None else None
+            tile = self._coalesce()
+            if tile is None:
+                tr.discard(sp)
+                return None
+            if sp is not None:
+                sp.attrs.update(
+                    tile=tile.tid, scene=tile.scene_id, rays=tile.n_real,
+                    pad=len(tile.rays_o) - tile.n_real,
+                    requests=len(tile.spans), host=tile.host_id,
+                    degraded=tile.degraded, budget_class=tile.budget)
+        m = getattr(self.stats, "m", None)
+        if m is not None:
+            m.coalesce_seconds.observe(self._clock() - t0 if sp is None
+                                       else sp.t1 - sp.t0)
+        return tile
+
+    def _coalesce(self) -> Optional[_Tile]:
         resolved = self._resolve_scene()
         if resolved is None:
             return None
@@ -660,15 +689,6 @@ class TileScheduler:
                      dead_bucket=(bucket is not None
                                   and bucket >= len(ar.budgets)),
                      host_id=host_id, tid=tid)
-        tr = self.tracer
-        if tr.enabled:
-            tr.complete("tile.coalesce", t_coalesce0, cat="tile", tile=tid,
-                        scene=scene, rays=n, pad=pad, requests=len(spans),
-                        host=host_id, degraded=degraded,
-                        budget_class=budget)
-        m = getattr(self.stats, "m", None)
-        if m is not None:
-            m.coalesce_seconds.observe(self._clock() - t_coalesce0)
         return tile
 
 
@@ -769,9 +789,11 @@ class TileExecutor:
                      if fault is not None and fault["kind"] == "straggle"
                      else 0.0)
             return rgb, cost, extra
+        with tr.span("tile.commit", cat="tile", tile=tile.tid):
+            o = tile.pp.commit(tile.rays_o)
+            d = tile.pp.commit(tile.rays_d)
         rgb, cost = tile.pp.dispatch_tile(
-            tile.pp.commit(tile.rays_o), tile.pp.commit(tile.rays_d),
-            home_cell=tile.home_cell, coarse_only=tile.degraded,
+            o, d, home_cell=tile.home_cell, coarse_only=tile.degraded,
             percell=self.percell,
             tracer=tr if tr.enabled else None,
             trace_attrs={"tile": tile.tid, "host": tile.host_id,
@@ -980,9 +1002,17 @@ class TileExecutor:
     def _finish_slot(self, tile, rgb, t0, extra, sp) -> None:
         """The drain body shared by ``drain_one`` (oldest overall) and
         ``drain_cell_one`` (oldest of one cell stream): materialize,
-        recover if corrupt/straggled, scatter, unpin."""
-        arr = np.asarray(rgb)
+        recover if corrupt/straggled, scatter, unpin. Traced, the
+        materialization is split into the wait for the device and the
+        copy to the host."""
         tr = self.tracer
+        if tr.enabled:
+            with tr.span("tile.wait", cat="tile", tile=tile.tid):
+                jax.block_until_ready(rgb)
+            with tr.span("tile.fetch", cat="tile", tile=tile.tid):
+                arr = np.asarray(rgb)
+        else:
+            arr = np.asarray(rgb)
         tr.end(sp)
         if tr.enabled:
             tr.event("tile.drain", cat="tile", tile=tile.tid,
@@ -1022,7 +1052,6 @@ class TileExecutor:
         dt = self._clock() - t0
         m = getattr(self.stats, "m", None)
         if m is not None:
-            m.inflight_seconds.observe(dt)
             m.in_flight_tiles.set(len(self._slots))
         self._update_service_ewma(dt)
         self.completion.scatter(tile, arr)
@@ -1072,7 +1101,21 @@ class CompletionSink:
         self.completion_order: List[int] = []
 
     def scatter(self, tile: _Tile, rgb: np.ndarray) -> None:
-        t0 = self._clock()
+        with self.tracer.span("tile.scatter", cat="tile", tile=tile.tid,
+                              scene=tile.scene_id,
+                              host=tile.host_id) as sp:
+            t0 = self._clock() if sp is None else None
+            late = self._write(tile, rgb)
+            if sp is not None:
+                sp.attrs["late"] = late
+        m = getattr(self.stats, "m", None)
+        if m is not None:
+            m.scatter_seconds.observe(self._clock() - t0 if sp is None
+                                      else sp.t1 - sp.t0)
+
+    def _write(self, tile: _Tile, rgb: np.ndarray) -> int:
+        """``scatter``'s body: the tile's pixels into each contributing
+        request's framebuffer; returns the rays that landed too late."""
         off = 0
         late = 0
         for a, start, take in tile.spans:
@@ -1093,13 +1136,7 @@ class CompletionSink:
             off += take
             if a.n_done == a.n_rays:
                 self._complete(a)
-        tr = self.tracer
-        if tr.enabled:
-            tr.complete("tile.scatter", t0, cat="tile", tile=tile.tid,
-                        scene=tile.scene_id, host=tile.host_id, late=late)
-        m = getattr(self.stats, "m", None)
-        if m is not None:
-            m.scatter_seconds.observe(self._clock() - t0)
+        return late
 
     def _finish(self, a: _Active, status: str,
                 error: Optional[str] = None) -> None:
@@ -1241,6 +1278,7 @@ class RenderEngine:
             else MetricsRegistry()
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = engine_stats_view(self.registry)
+        watch_compiles()
         if percell_dispatch:
             # extension block, bound ONLY when per-cell dispatch is on so
             # the default serialized stats stay byte-identical
@@ -1339,15 +1377,16 @@ class RenderEngine:
         coalesce -> dispatch -> block -> scatter of the pre-pipelined
         engine. Never raises for handled fault classes (dispatch
         failures, corrupt tiles, loader errors, stragglers)."""
-        self.scheduler.expire(self._clock())
-        tile = self.scheduler.next_tile()
-        if tile is not None:
-            self.executor.dispatch(tile)
-            return True
-        if self.executor.in_flight:
-            self.executor.drain_one()
-            return True
-        return False
+        with self.tracer.span("engine.step"):
+            self.scheduler.expire(self._clock())
+            tile = self.scheduler.next_tile()
+            if tile is not None:
+                self.executor.dispatch(tile)
+                return True
+            if self.executor.in_flight:
+                self.executor.drain_one()
+                return True
+            return False
 
     def take(self, request_id: int) -> RenderResult:
         """Pop a completed result, releasing its framebuffer. Long-running

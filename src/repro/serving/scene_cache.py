@@ -292,25 +292,27 @@ class SceneCache:
                 f"({fail[0]} consecutive failures; retry in {fail[1] + 1} "
                 f"more attempts)", fail_fast=True)
         self.misses += 1
-        sp = tr.begin("cache.load", cat="cache", scene=scene_id,
-                      host=self.trace_host) if tr.enabled else None
-        try:
-            pp = self._loader(scene_id)
-            nbytes = plcore_nbytes(pp)
-        except Exception as e:
-            # failure cleanup: nothing was inserted (the entry only lands
-            # below, after the loader AND the size accounting succeed),
-            # so cache state/pins are untouched — count it and arm the
-            # fail-fast window
-            self.load_failures += 1
-            n_fail = (fail[0] if fail else 0) + 1
-            self._failed[scene_id] = [
-                n_fail, min(self.fail_backoff * (2 ** (n_fail - 1)),
-                            self.max_fail_backoff)]
-            tr.end(sp, ok=False, error=str(e)[:120])
-            raise SceneLoadError(
-                f"loader failed for scene {scene_id!r}: {e}") from e
-        tr.end(sp, ok=True, bytes=nbytes)
+        with tr.span("cache.load", cat="cache", scene=scene_id,
+                     host=self.trace_host) as sp:
+            try:
+                pp = self._loader(scene_id)
+                nbytes = plcore_nbytes(pp)
+            except Exception as e:
+                # failure cleanup: nothing was inserted (the entry only
+                # lands below, after the loader AND the size accounting
+                # succeed), so cache state/pins are untouched — count it
+                # and arm the fail-fast window
+                self.load_failures += 1
+                n_fail = (fail[0] if fail else 0) + 1
+                self._failed[scene_id] = [
+                    n_fail, min(self.fail_backoff * (2 ** (n_fail - 1)),
+                                self.max_fail_backoff)]
+                if sp is not None:
+                    sp.attrs.update(ok=False, error=str(e)[:120])
+                raise SceneLoadError(
+                    f"loader failed for scene {scene_id!r}: {e}") from e
+            if sp is not None:
+                sp.attrs.update(ok=True, bytes=nbytes)
         self._failed.pop(scene_id, None)
         self._entries[scene_id] = (pp, nbytes)
         self._evict_over_capacity(keep=scene_id)

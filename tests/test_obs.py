@@ -12,20 +12,25 @@ cluster host kill — walks a complete lifecycle to a terminal span
 (``validate_trace``); (6) the Chrome trace export round-trips through
 ``validate_chrome_trace`` and the Prometheus text parses; (7) the
 validator actually catches broken chains (orphan dispatch, double
-serve, dangling request).
+serve, dangling request); (8) ``span`` writes the ring record
+``begin``/``end`` write, and with tracing off no profiler annotation is
+ever built; (9) the process's compile counter sees each new program
+shape once.
 """
 import json
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs.nerf_icarus import tiny
 from repro.core.pipeline import PackedPlcore
 from repro.core.plcore import plcore_decls
 from repro.models.params import init_params
-from repro.obs import (CLUSTER_STATS_SCHEMA, ENGINE_STATS_SCHEMA, Histogram,
-                       MetricsRegistry, Span, SpanTracer, chrome_trace,
-                       engine_stats_view, extend_stats_view, log_buckets,
+from repro.obs import (CLUSTER_STATS_SCHEMA, ENGINE_STATS_SCHEMA,
+                       NULL_TRACER, Histogram, MetricsRegistry, Span,
+                       SpanTracer, chrome_trace, engine_stats_view,
+                       extend_stats_view, global_registry, log_buckets,
                        prometheus_text, snapshot, validate_chrome_trace,
                        validate_trace)
 from repro.serving import (ClusterEngine, FaultConfig, FaultPlan, HostEvent,
@@ -196,6 +201,103 @@ def test_tracer_sampling_and_validation():
         SpanTracer(capacity=0)
     with pytest.raises(ValueError):
         SpanTracer(sample_every=0)
+
+
+def _counting_annotations(monkeypatch) -> list:
+    """Stands in for ``jax.profiler.TraceAnnotation``; returns the list
+    of the names it was built with."""
+    made = []
+
+    class Annotation:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return made
+
+
+def test_span_writes_the_ring_record_of_begin_end(monkeypatch):
+    made = _counting_annotations(monkeypatch)
+    clk_a, clk_b = _FakeClock(), _FakeClock()
+    a, b = SpanTracer(clock=clk_a), SpanTracer(clock=clk_b)
+    sp = a.begin("tile.scatter", cat="tile", tile=3, scene="s")
+    clk_a.advance(2.0)
+    a.end(sp, late=0)
+    with b.span("tile.scatter", cat="tile", tile=3, scene="s") as sp:
+        clk_b.advance(2.0)
+        sp.attrs["late"] = 0
+    assert [s.key() for s in b.spans()] == [s.key() for s in a.spans()]
+    assert made == ["tile.scatter"]        # the bare name, no attributes
+    # a discarded span leaves no record and nothing open
+    with b.span("tile.coalesce", cat="tile") as sp:
+        b.discard(sp)
+    assert len(b.spans()) == 1 and not b.open_spans()
+    # an exception closes the span and propagates
+    with pytest.raises(KeyError):
+        with b.span("cache.load", cat="cache"):
+            raise KeyError("loader")
+    assert b.spans()[-1].name == "cache.load" and not b.open_spans()
+
+
+def test_null_tracer_span_builds_no_annotation(setup, monkeypatch):
+    made = _counting_annotations(monkeypatch)
+    assert NULL_TRACER.span("engine.step") is \
+        NULL_TRACER.span("tile.wait", cat="tile", tile=1)
+    with NULL_TRACER.span("tile.coalesce", cat="tile") as sp:
+        NULL_TRACER.discard(sp)
+    assert sp is None
+    # a whole untraced engine run builds none either
+    cfg, param_sets = setup
+    eng = RenderEngine(SceneCache(_loader(cfg, param_sets)),
+                       tile_rays=TILE)
+    rids = [eng.submit(r) for r in _requests(2)]
+    eng.drain()
+    assert all(eng.take(r).status == "ok" for r in rids)
+    assert made == []
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A program read back from JAX's persistent cache is not compiled:
+    keep the cache off around a test that counts compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_compile_counter_sees_each_new_tile_shape_once(setup,
+                                                        no_persistent_cache):
+    cfg, param_sets = setup
+    pp = PackedPlcore(cfg, param_sets["scene2"])
+    tr = SpanTracer()                      # registers the listener
+    compiles = global_registry().get("jax_compiles_total")
+    seconds = global_registry().get("jax_compile_seconds")
+    tile = np.zeros((TILE + 3, 3), np.float32)    # a shape no test uses
+    tile[:, 2] = 1.0
+    n0, s0 = compiles.value, seconds.default.count
+    jax.block_until_ready(pp.render_tile(tile, tile))
+    assert compiles.value == n0 + 1
+    assert seconds.default.count == s0 + 1
+    jax.block_until_ready(pp.render_tile(tile, tile))
+    assert compiles.value == n0 + 1        # the same shape: no compile
+    spans = [s for s in tr.spans() if s.name == "jax.compile"]
+    assert len(spans) == 1 and spans[0].t1 > spans[0].t0
+    # a tracer on a fake clock cannot place a host-clock compile
+    fake = SpanTracer(clock=_FakeClock())
+    tile2 = np.zeros((TILE + 5, 3), np.float32)
+    jax.block_until_ready(pp.render_tile(tile2, tile2))
+    assert compiles.value == n0 + 2
+    assert not fake.spans()
 
 
 def _traced_run(cfg, param_sets, *, faults=None):
