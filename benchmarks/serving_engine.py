@@ -131,7 +131,7 @@ def run() -> dict:
     reps, reps_pl, reps_sh, reps_sh_rt, seq_walls = [], [], [], [], []
     reps_pc = []
     for _ in range(2):
-        engine = RenderEngine(cache, tile_rays=tile_rays)
+        engine = RenderEngine(cache, tile_rays=tile_rays, pipeline_depth=1)
         reps.append(loadgen.run_trace(engine, trace, mode="closed",
                                       concurrency=4))
         # sequential request-at-a-time baseline over the same trace
@@ -148,7 +148,8 @@ def run() -> dict:
                                  pipeline_depth=depth)
         reps_pl.append(loadgen.run_trace(engine_pl, trace, mode="closed",
                                          concurrency=4))
-        engine_sh = RenderEngine(cache_sh, tile_rays=tile_rays)
+        engine_sh = RenderEngine(cache_sh, tile_rays=tile_rays,
+                                 pipeline_depth=1)
         reps_sh.append(loadgen.run_trace(engine_sh, trace, mode="closed",
                                          concurrency=4))
         # sharded + owner-map routing (and the pipelined executor):

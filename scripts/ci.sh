@@ -35,9 +35,11 @@ echo "== plcore pipeline benchmark (tiny smoke; two_pass_fused gate) =="
 BENCH_PLCORE_HW=16 BENCH_PLCORE_ENFORCE=1 python -m benchmarks.run fusion
 
 echo "== serving engine smoke (3 scenes, deterministic trace) =="
-# fixed-seed closed-loop trace through the multi-tenant engine; --check
-# fails the run unless every request completed, the scene-cache hit rate
-# is > 0, and coalescing issued no more dispatches than per-request
+# fixed-seed closed-loop trace through the multi-tenant engine at its
+# default pipeline depth (2); --check fails the run unless every request
+# completed, the scene-cache hit rate is > 0, coalescing issued no more
+# dispatches than per-request, >= 2 tiles were in flight, and the
+# framebuffers are BIT-IDENTICAL to a synchronous depth=1 rerun
 python -m repro.launch.serve --mode engine --scenes 3 --requests 9 \
     --hw-mix 12,16 --tile-rays 128 --loop closed --seed 0 --check
 

@@ -46,8 +46,9 @@ Two services:
   (deterministic — the CI mode). Reports split request latency into
   queueing delay vs service time (p50/p95/p99 each).
   ``--pipeline-depth N`` gives the executor N in-flight tile slots
-  (double-buffered async dispatch: host scatter of tile k-1 overlaps
-  device compute of tile k; depth 1 is the synchronous baseline);
+  (default: the engine's, 2 on one host — double-buffered async
+  dispatch, the host work of tile k-1 overlaps device compute of tile
+  k; 1 under ``--hosts > 1``; depth 1 is the synchronous baseline);
   ``--route-by-shard`` (with ``--shard-weights``) routes each scene's
   tiles to the mesh cell owning most of its trunk layers so the modeled
   per-dispatch weight gathers shrink with locality. ``--check`` exits
@@ -332,6 +333,7 @@ def serve_engine(args) -> dict:
     from repro.serving import (ClusterEngine, FaultConfig, FaultPlan,
                                RenderEngine, SceneCache, split_devices)
     from repro.serving import loadgen
+    from repro.serving.engine import DEFAULT_PIPELINE_DEPTH
 
     cfg = NERF_FULL if args.full else nerf_tiny()
     if args.ert > 0.0:
@@ -371,6 +373,10 @@ def serve_engine(args) -> dict:
                 int(b) for b in args.budget_classes.split(","))
     if args.hosts < 1:
         raise SystemExit(f"--hosts must be >= 1, got {args.hosts}")
+    if args.pipeline_depth is None:
+        # each engine's own default: the RenderEngine pipelines, the
+        # ClusterEngine (--hosts > 1) keeps its synchronous default
+        args.pipeline_depth = 1 if args.hosts > 1 else DEFAULT_PIPELINE_DEPTH
     host_events = _parse_host_events(args)
     if host_events and args.hosts < 2:
         raise SystemExit("--host-kill/--host-slow need --hosts >= 2 "
@@ -820,12 +826,13 @@ def build_parser():
     ap.add_argument("--loop", choices=["open", "closed"], default="open")
     ap.add_argument("--concurrency", type=int, default=4,
                     help="closed-loop in-flight request count")
-    ap.add_argument("--pipeline-depth", type=int, default=1,
-                    help="executor in-flight tile slots: 1 = synchronous "
-                         "dispatch->block->scatter (the bit-identity "
-                         "baseline), >= 2 overlaps host coalescing/"
+    ap.add_argument("--pipeline-depth", type=int, default=None,
+                    help="executor in-flight tile slots (default: the "
+                         "engine's, 2 on one host and 1 with --hosts > 1): "
+                         ">= 2 overlaps host commit/dispatch/copy-back/"
                          "scatter with device compute via jax async "
-                         "dispatch")
+                         "dispatch, 1 = synchronous dispatch->block->"
+                         "scatter (the bit-identity baseline)")
     ap.add_argument("--route-by-shard", action="store_true",
                     help="owner-map tile routing (with --shard-weights): "
                          "pin each scene's tiles to a mesh cell owning "

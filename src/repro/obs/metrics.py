@@ -413,6 +413,11 @@ class EngineMetrics:
             f"{prefix}_queue_depth", "queued (non-terminal) requests")
         self.in_flight_tiles = registry.gauge(
             f"{prefix}_in_flight_tiles", "occupied executor slots")
+        # kept out of ENGINE_STATS_SCHEMA so the serialized stats stay
+        # byte-identical; read from the registry
+        self.overlapped_dispatches = registry.counter(
+            f"{prefix}_overlapped_dispatches_total",
+            "tiles dispatched while another slot was still occupied")
         self.queue_depth_hist = registry.histogram(
             f"{prefix}_queue_depth_requests",
             "queue depth sampled at each submit", buckets=DEPTH_BUCKETS)

@@ -21,9 +21,12 @@ lever has one home:
   device array (jax async dispatch), so the executor dispatches tile k+1
   and drains tile k−(depth−1) while the device computes the tiles in
   between — host coalescing/scatter overlaps device compute instead of
-  alternating with it. ``pipeline_depth=1`` flushes every dispatch
-  immediately and reduces EXACTLY to the synchronous
-  dispatch→block→scatter loop (the bit-identity anchor CI pins). The
+  alternating with it. The served default is ``DEFAULT_PIPELINE_DEPTH``
+  (2): the next tile is queued on the device before the host blocks on
+  the last one, so the device never waits for the host's per-tile work.
+  ``pipeline_depth=1`` flushes every dispatch immediately and reduces
+  EXACTLY to the synchronous dispatch→block→scatter loop (the
+  bit-identity anchor CI pins). The
   executor pins each tile's scene in the ``SceneCache`` for the life of
   the slot, so eviction can never drop weights under an in-flight
   dispatch, and accounts every dispatch's owner-map gather cost into
@@ -96,6 +99,11 @@ from repro.serving.scene_cache import SceneCache, SceneLoadError
 
 #: Terminal request statuses (see module docstring).
 STATUSES = ("ok", "degraded", "partial", "expired", "rejected")
+
+#: In-flight tile slots of a ``RenderEngine`` built without
+#: ``pipeline_depth``: tile k+1 is coalesced, committed and enqueued while
+#: tile k runs, so the host's per-tile work hides behind the kernel.
+DEFAULT_PIPELINE_DEPTH = 2
 
 
 @dataclass(frozen=True)
@@ -566,7 +574,11 @@ class TileScheduler:
         """Coalesce ONE tile from the best loadable scene's pending
         requests in queue order (scene + residency resolution in
         ``_resolve_scene``); ``None`` when nothing is schedulable. A
-        produced tile is traced as ``tile.coalesce``."""
+        produced tile is traced as ``tile.coalesce``: a step that finds
+        every ray handed out (at depth >= 2 it goes on to drain) opens
+        no span, so the profile's annotations match the ring's spans."""
+        if not any(a.remaining > 0 for a in self.queue):
+            return None
         tr = self.tracer
         with tr.span("tile.coalesce", cat="tile") as sp:
             t0 = self._clock() if sp is None else None
@@ -755,6 +767,10 @@ class TileExecutor:
         self._clock = clock
         self._sleep = sleep             # injectable alongside the clock
         self._slots: deque = deque()    # (tile, rgb, t0, extra_s, span)
+        # per stream, when its last drained tile was materialized: at
+        # depth >= 2 the next tile was enqueued behind it and only starts
+        # then, so its service is measured from there, not from dispatch
+        self._last_drain: Dict[Optional[int], float] = {}
 
     @property
     def in_flight(self) -> int:
@@ -972,6 +988,7 @@ class TileExecutor:
         sp = (tr.begin("tile.device_compute", cat="tile", tile=tile.tid,
                        host=tile.host_id, slot=len(self._slots))
               if tr.enabled else None)
+        overlapped = bool(self._slots)
         self._slots.append((tile, rgb, self._clock(), extra, sp))
         self._account(tile, cost)
         self._note_cell_dispatch(tile)
@@ -980,6 +997,8 @@ class TileExecutor:
         m = getattr(self.stats, "m", None)
         if m is not None:
             m.in_flight_tiles.set(len(self._slots))
+            if overlapped:
+                m.overlapped_dispatches.inc()
         if self.percell:
             # the depth budget is PER CELL: this tile's stream drains
             # when ITS cell is full, other cells' tiles stay in flight
@@ -1004,8 +1023,13 @@ class TileExecutor:
         ``drain_cell_one`` (oldest of one cell stream): materialize,
         recover if corrupt/straggled, scatter, unpin. Traced, the
         materialization is split into the wait for the device and the
-        copy to the host."""
+        copy to the host. The tile's service runs from the later of its
+        dispatch and its stream's previous drain: a tile enqueued behind
+        another waits for it on the device, and that wait is the older
+        tile's service, not this one's."""
         tr = self.tracer
+        cell = self._cell_of(tile)
+        start = max(t0, self._last_drain.get(cell, t0))
         if tr.enabled:
             with tr.span("tile.wait", cat="tile", tile=tile.tid):
                 jax.block_until_ready(rgb)
@@ -1029,7 +1053,7 @@ class TileExecutor:
             # this lands on a different cell; here it models cutting the
             # loss instead of stalling the drain point)
             verdict = self.straggler.record_step(
-                self._clock() - t0 + extra)
+                self._clock() - start + extra)
             if verdict["deadline_exceeded"]:
                 self.stats["straggler_redispatches"] += 1
                 if tr.enabled:
@@ -1049,13 +1073,14 @@ class TileExecutor:
                 tr.event("tile.corrupt", cat="tile", tile=tile.tid,
                          host=tile.host_id)
             arr, _ = self._resolve_sync(tile)
-        dt = self._clock() - t0
+        now = self._clock()
+        self._last_drain[cell] = now
         m = getattr(self.stats, "m", None)
         if m is not None:
             m.in_flight_tiles.set(len(self._slots))
-        self._update_service_ewma(dt)
+        self._update_service_ewma(now - start)
         self.completion.scatter(tile, arr)
-        self.cache.unpin(tile.scene_id, cell=self._cell_of(tile))
+        self.cache.unpin(tile.scene_id, cell=cell)
 
     def drain_all(self) -> None:
         while self.drain_one():
@@ -1203,10 +1228,11 @@ class RenderEngine:
     ``tile_rays`` is the fixed dispatch shape — every tile that reaches
     the device has exactly this many rays (the compiled tile program is
     reused forever), and only a tail tile carries padding.
-    ``pipeline_depth`` bounds the executor's in-flight slots (1 =
-    synchronous, bit-identical baseline; >= 2 overlaps host scatter with
-    device compute); ``route_by_shard`` turns on owner-map tile routing
-    for mesh-sharded residents.
+    ``pipeline_depth`` bounds the executor's in-flight slots (default
+    ``DEFAULT_PIPELINE_DEPTH`` = 2, which overlaps the host's commit,
+    dispatch, copy back and scatter with device compute; 1 =
+    synchronous, the bit-identical baseline); ``route_by_shard`` turns
+    on owner-map tile routing for mesh-sharded residents.
 
     Fault-tolerance knobs (all default to the pre-fault behavior):
     ``max_queue`` bounds the request queue (admission rejects beyond);
@@ -1227,7 +1253,8 @@ class RenderEngine:
 
     def __init__(self, cache: SceneCache, *, tile_rays: int = 512,
                  max_sticky_tiles: int = 64, clock=time.perf_counter,
-                 pipeline_depth: int = 1, route_by_shard: bool = False,
+                 pipeline_depth: int = DEFAULT_PIPELINE_DEPTH,
+                 route_by_shard: bool = False,
                  percell_dispatch: bool = False,
                  max_queue: Optional[int] = None,
                  aging_tiles: Optional[int] = None,

@@ -10,7 +10,11 @@ differences invisible) while actually holding ``depth`` tiles in flight;
 until the last slot drains; (3) owner-map routing strictly shrinks the
 engine's per-dispatch gather accounting (``plcore_gather_count/_bytes``)
 vs unrouted on the same trace, with identical pixels; (4) request latency
-splits exactly into queueing delay + service time. Subprocess legs
+splits exactly into queueing delay + service time; (5) an engine built
+without ``pipeline_depth`` pipelines two tiles deep, counts each
+dispatch that overlapped an occupied slot, and reads each tile's service
+from the later of its dispatch and the previous drain, so admission
+control's estimate does not double at depth 2. Subprocess legs
 (the conftest ``fake_devices`` fixture) re-assert (1)+(3) on a REAL
 4-way layer shard over 8 fake CPU devices, and hold per-cell dispatch
 (``percell_dispatch=True``) to the ISSUE acceptance bar there: tiles
@@ -58,34 +62,142 @@ MIXED = [RenderRequest("scene0", hw=10, theta=10.0),
 
 
 # ------------------------------------------------ pipelined bit-identity ----
-def test_pipeline_depths_bit_identical(setup):
-    """Depths 1/2/3 over the same submitted-upfront trace: identical
-    scheduler decisions (dispatch/pad counts equal), identical images,
-    and the deep engines really pipeline (peak in-flight == depth)."""
+def _run_mixed(cfg, param_sets, **kw):
+    eng = _engine(cfg, param_sets, **kw)
+    rids = [eng.submit(r) for r in MIXED]
+    eng.drain()
+    assert eng.in_flight_tiles == 0
+    assert eng.stats["requests_completed"] == len(MIXED)
+    return eng, rids
+
+
+@pytest.fixture(scope="module")
+def sync_mixed(setup):
+    """The synchronous depth=1 engine over ``MIXED``: the anchor."""
+    eng, rids = _run_mixed(*setup, pipeline_depth=1)
+    assert eng.pipeline_depth == 1
+    assert eng.stats["max_in_flight"] == 1
+    return eng, rids
+
+
+@pytest.mark.parametrize("kw, depth", [({"pipeline_depth": 2}, 2),
+                                       ({"pipeline_depth": 3}, 3),
+                                       ({}, 2)],
+                         ids=["depth2", "depth3", "default"])
+def test_pipeline_depths_bit_identical(setup, sync_mixed, kw, depth):
+    """Depths 2/3, and an engine built without ``pipeline_depth`` (the
+    served default, 2), over the same submitted-upfront trace as the
+    synchronous loop: identical scheduler decisions (dispatch/pad counts
+    equal), identical images, and the deep engines really pipeline
+    (peak in-flight == depth)."""
     cfg, param_sets = setup
-    runs = {}
-    for depth in (1, 2, 3):
-        eng = _engine(cfg, param_sets, pipeline_depth=depth)
-        rids = [eng.submit(r) for r in MIXED]
+    base, base_rids = sync_mixed
+    eng, rids = _run_mixed(cfg, param_sets, **kw)
+    assert eng.pipeline_depth == depth
+    # all requests queued before the first step -> the scheduler walks
+    # the same policy path at any depth
+    assert eng.stats["dispatches"] == base.stats["dispatches"]
+    assert eng.stats["padded_rays"] == base.stats["padded_rays"]
+    assert eng.stats["scene_switches"] == base.stats["scene_switches"]
+    assert eng.stats["max_in_flight"] == depth
+    for rid, brid in zip(rids, base_rids):
+        img = eng.completed[rid].image
+        assert np.isfinite(img).all()       # NaN fb: no gap, no leak
+        np.testing.assert_array_equal(img, base.completed[brid].image)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_overlapped_dispatch_counter(setup, depth):
+    """``engine_overlapped_dispatches_total`` counts tiles dispatched
+    while another slot was occupied: every dispatch but the first on a
+    back-to-back trace at depth 2, none at depth 1 — and it stays out of
+    the serialized stats."""
+    eng, _ = _run_mixed(*setup, pipeline_depth=depth)
+    overlapped = eng.registry.get("engine_overlapped_dispatches_total")
+    d = eng.stats["dispatches"]
+    assert d > 1
+    assert overlapped.value == (d - 1 if depth > 1 else 0)
+    assert not any("overlapped" in k for k in eng.stats)
+
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+class _FakeDevice:
+    """A chip on a fake clock: tiles run one after another, each
+    ``service_s`` long, starting when both enqueued and the chip is
+    free; the host's own work takes no time."""
+
+    def __init__(self, clock: _FakeClock, service_s: float):
+        self.clock = clock
+        self.service_s = service_s
+        self.free_at = 0.0
+
+    def enqueue(self) -> float:
+        self.free_at = max(self.clock.t, self.free_at) + self.service_s
+        return self.free_at
+
+
+class _PendingTile:
+    """A dispatched tile's result: materializing it waits (on the fake
+    clock) until the fake chip has finished the tile."""
+
+    def __init__(self, rgb, clock: _FakeClock, done_at: float):
+        self.rgb, self.clock, self.done_at = rgb, clock, done_at
+
+    def __array__(self, dtype=None, copy=None):
+        self.clock.t = max(self.clock.t, self.done_at)
+        return np.asarray(self.rgb, dtype)
+
+
+class _TimedPlcore:
+    """A resident whose dispatches run on a ``_FakeDevice``."""
+
+    def __init__(self, pp, device: _FakeDevice):
+        self._pp, self._device = pp, device
+
+    def __getattr__(self, name):
+        return getattr(self._pp, name)
+
+    def dispatch_tile(self, o, d, **kw):
+        rgb, cost = self._pp.dispatch_tile(o, d, **kw)
+        return (_PendingTile(rgb, self._device.clock,
+                             self._device.enqueue()), cost)
+
+
+def test_service_estimate_is_per_tile_at_any_depth(setup):
+    """On a fake chip of 10 ms a tile, the service EWMA and histogram
+    read 10 ms at depth 1 and at depth 2 — not the ~20 ms from dispatch
+    to drain that a tile queued behind another spends at depth 2 — so
+    admission control predicts the same queueing at both depths."""
+    cfg, param_sets = setup
+    service_s = 0.01
+    readings = {}
+    for depth in (1, 2):
+        clk = _FakeClock()
+        dev = _FakeDevice(clk, service_s)
+        cache = SceneCache(lambda sid: _TimedPlcore(
+            PackedPlcore(cfg, param_sets[sid]), dev), capacity_mb=256.0)
+        eng = RenderEngine(cache, tile_rays=TILE, clock=clk,
+                           pipeline_depth=depth)
+        for r in MIXED:
+            eng.submit(r)
         eng.drain()
-        assert eng.in_flight_tiles == 0
-        assert eng.stats["requests_completed"] == len(MIXED)
-        runs[depth] = (eng, rids)
-    base, base_rids = runs[1]
-    assert base.stats["max_in_flight"] == 1
-    for depth in (2, 3):
-        eng, rids = runs[depth]
-        # all requests queued before the first step -> the scheduler walks
-        # the same policy path at any depth
-        assert eng.stats["dispatches"] == base.stats["dispatches"]
-        assert eng.stats["padded_rays"] == base.stats["padded_rays"]
-        assert eng.stats["scene_switches"] == base.stats["scene_switches"]
-        assert eng.stats["max_in_flight"] == depth
-        for rid, brid in zip(rids, base_rids):
-            img = eng.completed[rid].image
-            assert np.isfinite(img).all()       # NaN fb: no gap, no leak
-            np.testing.assert_array_equal(img,
-                                          base.completed[brid].image)
+        d = eng.stats["dispatches"]
+        assert clk.t == pytest.approx(d * service_s)    # chip never idle
+        hist = eng.registry.get("engine_tile_service_seconds").default
+        assert hist.count == d
+        assert hist.sum / d == pytest.approx(service_s)
+        assert eng.stats["tile_service_s_ewma"] == pytest.approx(service_s)
+        eng.submit(RenderRequest("scene0", hw=16))      # 4 tiles queued
+        readings[depth] = eng.scheduler._estimated_queueing_s()
+    assert readings[1] == pytest.approx(4 * service_s)
+    assert readings[2] == pytest.approx(readings[1])
 
 
 def test_step_makes_progress_while_in_flight(setup):

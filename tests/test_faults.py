@@ -297,7 +297,7 @@ def test_deadline_expiry_statuses(setup):
     cfg, param_sets = setup
     clk = _FakeClock()
     eng = RenderEngine(SceneCache(_loader(cfg, param_sets)),
-                       tile_rays=TILE, clock=clk)
+                       tile_rays=TILE, clock=clk, pipeline_depth=1)
     # expired: deadline passes before any ray is tiled
     rid_e = eng.submit(RenderRequest(scene_id="scene0", hw=8,
                                      deadline_s=1.0))
