@@ -137,3 +137,74 @@ class PEU:
         if self.include_input:
             enc = jnp.concatenate([x, enc], axis=-1)
         return enc
+
+
+# ------------------------------------------- Mip-NeRF: cones and the IPE ----
+def conical_frustum_to_gaussian(d, t0, t1, radius):
+    """Mean and diagonal covariance of the conical frustum between
+    distances t0 and t1 along each ray (Mip-NeRF eq. 7-8, the stable
+    form of ``internal/mip.py``), as a Gaussian.
+
+    d: (..., 3) directions; t0, t1: (..., N); radius: (..., 1), the
+    cone's radius per unit of t. Returns (t_mean (..., N), cov_diag
+    (..., N, 3)): the sample's mean is ``o + t_mean * d``."""
+    mu = 0.5 * (t0 + t1)
+    hw = 0.5 * (t1 - t0)
+    mu2, hw2 = mu * mu, hw * hw
+    den = 3.0 * mu2 + hw2
+    t_mean = mu + 2.0 * mu * hw2 / den
+    t_var = hw2 / 3.0 - (4.0 / 15.0) * (hw2 * hw2 * (12.0 * mu2 - hw2)
+                                         / (den * den))
+    r_var = (radius * radius) * (mu2 / 4.0 + (5.0 / 12.0) * hw2
+                                 - (4.0 / 15.0) * hw2 * hw2 / den)
+    dd = d * d
+    null = 1.0 - dd / jnp.maximum(1e-10, jnp.sum(dd, -1, keepdims=True))
+    cov = (t_var[..., None] * dd[..., None, :]
+           + r_var[..., None] * null[..., None, :])
+    return t_mean, cov
+
+
+def _octaves(x, lo: int, hi: int, base: float):
+    """[base^lo x, ..., base^(hi-1) x] along the last axis, octave-major."""
+    return jnp.concatenate([(base ** l) * x for l in range(lo, hi)], axis=-1)
+
+
+def integrated_pos_enc(mean, var, n_freqs: int):
+    """Mip-NeRF's IPE of a diagonal Gaussian (``integrated_pos_enc``):
+    with y = [2^0 mean, ..., 2^(L-1) mean] and v = [4^0 var, ...,
+    4^(L-1) var], [sin(y) exp(-v/2), cos(y) exp(-v/2)] — Mip-NeRF's own
+    layout, all sines then all cosines, no identity: (..., 3) ->
+    (..., 6L)."""
+    y = _octaves(mean, 0, n_freqs, 2.0)
+    a = jnp.exp(-0.5 * _octaves(var, 0, n_freqs, 4.0))
+    return jnp.concatenate([jnp.sin(y) * a, jnp.cos(y) * a], axis=-1)
+
+
+def integrated_pos_enc_recurrence(mean, var, n_freqs: int,
+                                  block: int = 4):
+    """``integrated_pos_enc`` the PEU's way, the form the fused kernel
+    runs. Octaves go in blocks of ``block``, each one lane-contiguous
+    array, so a step works on every octave of the block at once: the
+    first block's sin/cos directly, each next block's from the last by
+    ``block`` double-angle steps (sin 2x = 2 sin x cos x, cos 2x =
+    1 - 2 sin^2 x), which keeps every argument small. The attenuation
+    takes one exp per block, exp(-4^l var / 2) from the block's exactly
+    scaled variances: the per-octave recurrence a_{l+1} = a_l^4 from
+    a_0 = exp(-var / 2) would carry var only to the f32 spacing of
+    numbers near 1 (~1.2e-7), which is most of a pixel footprint's
+    variance."""
+    blk = min(block, n_freqs)
+    y = _octaves(mean, 0, blk, 2.0)
+    s, c = jnp.sin(y), jnp.cos(y)
+    v = _octaves(var, 0, blk, 4.0)
+    sins, coss = [], []
+    for k in range(-(-n_freqs // blk)):
+        a = jnp.exp((-0.5 * 4.0 ** (k * blk)) * v)
+        sins.append(s * a)
+        coss.append(c * a)
+        for _ in range(blk):
+            s, c = 2.0 * s * c, 1.0 - 2.0 * s * s
+    n = 3 * n_freqs
+    return jnp.concatenate([jnp.concatenate(sins, axis=-1)[..., :n],
+                            jnp.concatenate(coss, axis=-1)[..., :n]],
+                           axis=-1)

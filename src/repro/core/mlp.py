@@ -133,6 +133,15 @@ def nerf_mlp_apply(cfg: NerfConfig, params: dict, pe_pos, pe_dir,
     return sigma, nerf_color_apply(cfg, params, feat, pe_dir, quant)
 
 
+def cone_heads(cfg: NerfConfig, sigma_raw, rgb):
+    """The cone path's output activations (Mip-NeRF's): density
+    softplus(raw + density_bias), colour padded to
+    sigmoid * (1 + 2 p) - p, from the raw density and the sigmoid colour
+    that ``nerf_mlp_apply`` (and the kernel's MLP) produce."""
+    sigma = jax.nn.softplus(sigma_raw + cfg.density_bias)
+    return sigma, rgb * (1.0 + 2.0 * cfg.rgb_padding) - cfg.rgb_padding
+
+
 # ----------------------------------------------------- generic coordinate MLP
 def mlp_decls(in_dim: int, widths: Sequence[int], out_dim: int) -> dict:
     dims = [in_dim, *widths, out_dim]

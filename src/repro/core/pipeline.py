@@ -207,6 +207,19 @@ def _tile_fn(cfg: NerfConfig, use_kernel: bool, ert_eps: float,
     key = (cfg, use_kernel, float(ert_eps), fuse_two_pass, shard_mesh,
            coarse_only, cell, adaptive)
     fn = _TILE_JITS.get(key)
+    if fn is None and cfg.cone:
+        # a cone tile carries its rays' (n, 1) pixel radii; the modes that
+        # would reach here otherwise refuse it in PackedPlcore
+        def run(params, quant, packed, o_tile, d_tile, r_tile):
+            with jax.named_scope(TILE_SCOPE):
+                out = plcore.render_rays(
+                    cfg, params, o_tile, d_tile, quant=quant, packed=packed,
+                    use_kernel=use_kernel, fuse_two_pass=fuse_two_pass,
+                    white_bkgd=True, radii=r_tile)
+                return out["rgb"]
+
+        fn = _TILE_JITS[key] = _donating_jit(run, ("o_tile", "d_tile",
+                                                   "r_tile"))
     if fn is None:
         if coarse_only:
             from repro.core import sampling, volume
@@ -291,6 +304,12 @@ class PackedPlcore:
     committed there at load, and ``commit`` puts tile buffers beside
     them, so every render program runs on that device. None: JAX's
     default device. Exclusive with ``shard_mesh``.
+
+    A cone config (Mip-NeRF) renders tiles with their rays' pixel radii
+    (``render_tile(..., radii=)``) and packs ONE network when the config
+    shares it between the passes (``params["coarse"]``; a "fine" entry is
+    dropped). Sharded residency refuses it, and so do ``render_tile``'s
+    coarse-only, budget and alive modes and the per-cell path.
     """
 
     def __init__(self, cfg: NerfConfig, params: dict, *,
@@ -305,6 +324,13 @@ class PackedPlcore:
             raise ValueError("device places a replicated scene on one "
                              "device; shard_mesh spreads it over a mesh — "
                              "pass one of them")
+        if cfg.cone and shard_mesh is not None:
+            raise ValueError("sharded residency does not serve cone "
+                             "(Mip-NeRF) scenes")
+        nets = tuple(plcore.plcore_decls(cfg))
+        params = {net: params[net] for net in nets}
+        if quant is not None:
+            quant = {net: quant[net] for net in nets}
         self.device = device
         if device is not None:
             # packing below runs on committed inputs, so the packed
@@ -325,7 +351,7 @@ class PackedPlcore:
             q = quant or {}
             self.packed = {
                 net: kops.stack_plcore_weights(cfg, params[net], q.get(net))
-                for net in ("coarse", "fine")}
+                for net in nets}
         if shard_mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from repro.runtime import sharding as rsh
@@ -347,11 +373,11 @@ class PackedPlcore:
             repl = NamedSharding(shard_mesh, PartitionSpec())
             params = {net: jax.device_put(
                 {k: v for k, v in params[net].items() if k != "trunk"},
-                repl) for net in ("coarse", "fine")}
+                repl) for net in nets}
             if quant is not None:
                 quant = {net: jax.device_put(
                     {k: v for k, v in quant[net].items() if k != "trunk"},
-                    repl) for net in ("coarse", "fine")}
+                    repl) for net in nets}
         self.params = params
         self.quant = quant
         if self.packed is not None:
@@ -387,11 +413,19 @@ class PackedPlcore:
             ert_eps=self.ert_eps if ert_eps is None else ert_eps,
             shard_mesh=self.shard_mesh)
 
+    def _cone_radii(self, o_tile, radii):
+        """A cone tile's (n, 1) radii beside its rays: zeros, a
+        zero-radius cone per ray, when none are given (the same program
+        compiles either way)."""
+        if radii is None:
+            return self.commit(np.zeros((o_tile.shape[0], 1), np.float32))
+        return radii
+
     def render_tile(self, o_tile, d_tile,
                     ert_eps: Optional[float] = None,
                     coarse_only: bool = False,
                     budget: Optional[int] = None,
-                    alive=None) -> jnp.ndarray:
+                    alive=None, radii=None) -> jnp.ndarray:
         """Render ONE pre-coalesced ray tile -> rgb (n, 3). The serving
         engine's dispatch path: fixed tile shapes hit the same compiled
         program every call (no per-request retrace), and the tile body is
@@ -406,9 +440,21 @@ class PackedPlcore:
         replaced cfg keys its own compiled program, so each budget class
         is a distinct fixed-shape artifact reused across tiles of that
         class. ``alive`` is the optional per-ray dead-row mask (trunk-memo
-        hits enter dead; requires the fused-kernel path)."""
+        hits enter dead; requires the fused-kernel path).
+
+        ``radii`` (cone configs): the tile's (n, 1) per-ray pixel radii,
+        a device array like the rays (donated too)."""
         eps = self.ert_eps if ert_eps is None else float(ert_eps)
         cfg = self.cfg
+        if cfg.cone:
+            if coarse_only or budget is not None or alive is not None:
+                mode = ("coarse_only degradation" if coarse_only
+                        else "adaptive sampling")
+                raise ValueError(f"{mode} does not render cone (Mip-NeRF) "
+                                 f"scenes")
+            fn = _tile_fn(cfg, self.use_kernel, eps, self.fuse_two_pass)
+            return fn(self.params, self.quant, self.packed, o_tile, d_tile,
+                      self._cone_radii(o_tile, radii))
         if budget is not None and int(budget) != cfg.n_fine:
             cfg = dataclasses.replace(cfg, n_fine=int(budget))
         if alive is not None:
@@ -426,11 +472,13 @@ class PackedPlcore:
         device runs, e.g. whether the Mosaic kernel is in it)."""
         fn = _tile_fn(self.cfg, self.use_kernel, self.ert_eps,
                       self.fuse_two_pass, self.shard_mesh)
+        extra = (self._cone_radii(o_tile, None),) if self.cfg.cone else ()
         return fn.lower(self.params, self.quant, self.packed, o_tile,
-                        d_tile).compile()
+                        d_tile, *extra).compile()
 
     def render_tile_oracle(self, o_tile, d_tile,
-                           ert_eps: Optional[float] = None) -> jnp.ndarray:
+                           ert_eps: Optional[float] = None,
+                           radii=None) -> jnp.ndarray:
         """The retry ladder's LAST rung: render one tile through the
         bit-exact oracle program. For a ``fuse_two_pass`` instance that
         is the two-dispatch kernel path (coarse and fine as separate
@@ -441,8 +489,14 @@ class PackedPlcore:
         pixels equal the healthy primary path's bit-for-bit — recovery
         through the oracle is invisible in delivered framebuffers. The
         fault-injection plan never wraps this path: it is the trusted
-        floor the ladder stands on."""
+        floor the ladder stands on. A cone scene's oracle is the XLA
+        program of the same math (``plcore.render_rays_cone``): close to
+        the kernel's pixels, not bit-identical."""
         eps = self.ert_eps if ert_eps is None else float(ert_eps)
+        if self.cfg.cone:
+            fn = _tile_fn(self.cfg, False, eps, False)
+            return fn(self.params, self.quant, None, o_tile, d_tile,
+                      self._cone_radii(o_tile, radii))
         fn = _tile_fn(self.cfg, self.use_kernel, eps, False,
                       self.shard_mesh)
         return fn(self.params, self.quant, self.packed, o_tile, d_tile)
@@ -571,7 +625,7 @@ class PackedPlcore:
                       coarse_only: bool = False,
                       percell: bool = False,
                       budget: Optional[int] = None,
-                      alive=None,
+                      alive=None, radii=None,
                       tracer=None, trace_attrs=None):
         """The pipelined executor's entry point: dispatch ONE coalesced
         ray tile and return ``(rgb, gather_cost)`` — ``rgb`` an
@@ -616,7 +670,8 @@ class PackedPlcore:
             else:
                 rgb = self.render_tile(o_tile, d_tile, ert_eps=ert_eps,
                                        coarse_only=coarse_only,
-                                       budget=budget, alive=alive)
+                                       budget=budget, alive=alive,
+                                       radii=radii)
                 cost = self.tile_gather_cost(home_cell)
             if sp is not None:
                 sp.attrs.update(rays=int(o_tile.shape[0]),
@@ -747,6 +802,9 @@ def build_scene_aux(pp: "PackedPlcore", *, grid_res: int = 48,
         raise ValueError("adaptive sampling needs the replicated raw "
                          "trunk params — a mesh-sharded PackedPlcore "
                          "drops them at load")
+    if pp.cfg.cone:
+        raise ValueError("adaptive sampling does not render cone "
+                         "(Mip-NeRF) scenes")
     from repro.core import sampling
     from repro.data import rays as drays
     cfg = pp.cfg

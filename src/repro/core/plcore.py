@@ -23,14 +23,18 @@ import jax.numpy as jnp
 
 from repro.configs.nerf_icarus import NerfConfig
 from repro.core import rmcm, sampling, volume
-from repro.core.encoding import nerf_encoding
-from repro.core.mlp import nerf_mlp_apply, nerf_mlp_decls
+from repro.core.encoding import (conical_frustum_to_gaussian,
+                                 integrated_pos_enc, nerf_encoding)
+from repro.core.mlp import cone_heads, nerf_mlp_apply, nerf_mlp_decls
 from repro.models.params import Decl
 
 
 # ------------------------------------------------------------------ decls ---
 def plcore_decls(cfg: NerfConfig) -> dict:
-    """Coarse + fine networks (original NeRF trains both)."""
+    """Coarse + fine networks (original NeRF trains both); one, under
+    "coarse", when the config shares it between the passes (Mip-NeRF)."""
+    if cfg.shared_net:
+        return {"coarse": nerf_mlp_decls(cfg)}
     return {"coarse": nerf_mlp_decls(cfg), "fine": nerf_mlp_decls(cfg)}
 
 
@@ -62,12 +66,90 @@ def _eval_pass(cfg: NerfConfig, params, quant, rays_o, rays_d, t,
                                   rgb.astype(jnp.float32), deltas)
 
 
+# ----------------------------------------------- Mip-NeRF: cone rays ------
+def _cone_pass(cfg: NerfConfig, params, quant, rays_o, rays_d, radii, t0,
+               t1, pe_dir):
+    """One pass over the (R, N) intervals [t0, t1): frustum Gaussians ->
+    IPE -> MLP -> the volume integral over the finite intervals."""
+    t_mean, cov = conical_frustum_to_gaussian(rays_d, t0, t1, radii)
+    mean = rays_o[..., None, :] + t_mean[..., None] * rays_d[..., None, :]
+    pe = integrated_pos_enc(mean, cov, cfg.pos_freqs)
+    sigma, rgb = cone_heads(cfg, *nerf_mlp_apply(cfg, params, pe, pe_dir,
+                                                 quant=quant))
+    deltas = (t1 - t0) * jnp.linalg.norm(rays_d, axis=-1, keepdims=True)
+    return volume.render_parallel(sigma, rgb, deltas)
+
+
+def render_rays_cone(cfg: NerfConfig, params: dict, rays_o, rays_d, radii,
+                     *, quant: Optional[dict] = None,
+                     white_bkgd: bool = True) -> dict:
+    """Mip-NeRF's deterministic two-level render in XLA ops, the math of
+    the fused cone kernel: ``n_coarse`` intervals between evenly spaced
+    edges, the blurred-weight resample to ``n_fine`` new intervals, and
+    the fine pass on those alone (no merge), both levels through the one
+    network under "coarse". radii: (R, 1) cone radius per unit of t.
+    Returns {rgb, rgb_coarse, depth, acc}."""
+    net, q = params["coarse"], (quant or {}).get("coarse")
+    R = rays_o.shape[:-1]
+    t0, t1 = sampling.cone_intervals(cfg.near, cfg.far, cfg.n_coarse)
+    t0 = jnp.broadcast_to(t0, R + t0.shape[-1:])
+    t1 = jnp.broadcast_to(t1, R + t1.shape[-1:])
+    dirs = rays_d / jnp.linalg.norm(rays_d, axis=-1, keepdims=True)
+    pe_dir = nerf_encoding(dirs, cfg.dir_freqs)[..., None, :]
+    rgb_c, aux_c = _cone_pass(cfg, net, q, rays_o, rays_d, radii, t0, t1,
+                              pe_dir)
+    t0, t1 = sampling.mip_resample(t0, t1, aux_c["weights"], cfg.n_fine,
+                                   cfg.resample_padding)
+    rgb_f, aux_f = _cone_pass(cfg, net, q, rays_o, rays_d, radii, t0, t1,
+                              pe_dir)
+    depth = volume.composite_depth(aux_f["weights"], 0.5 * (t0 + t1))
+    if white_bkgd:
+        rgb_f = volume.white_background(rgb_f, aux_f["acc"])
+        rgb_c = volume.white_background(rgb_c, aux_c["acc"])
+    return {"rgb": rgb_f, "rgb_coarse": rgb_c, "depth": depth,
+            "acc": aux_f["acc"]}
+
+
+def _render_cone(cfg, params, rays_o, rays_d, radii, key, quant, use_kernel,
+                 fuse_two_pass, packed, ert_eps, white_bkgd, alive):
+    """``render_rays`` for a cone config: the fused cone kernel or the XLA
+    path; the modes the cone path lacks refuse it by name."""
+    if key is not None:
+        raise ValueError("cone rays render with deterministic sampling "
+                         "only: no sampling key")
+    if ert_eps > 0.0 or alive is not None:
+        raise ValueError("early ray termination and alive masks do not "
+                         "render cone (Mip-NeRF) rays")
+    if radii is None:   # a ray without a footprint: a zero-radius cone
+        radii = jnp.zeros(rays_o.shape[:-1] + (1,), jnp.float32)
+    radii = radii.reshape(rays_o.shape[:-1] + (1,))
+    if not use_kernel:
+        return render_rays_cone(cfg, params, rays_o, rays_d, radii,
+                                quant=quant, white_bkgd=white_bkgd)
+    if not fuse_two_pass:
+        raise ValueError("cone rays have no two-dispatch kernel path: use "
+                         "the fused two-pass kernel or the XLA path")
+    from repro.kernels import ops as kops
+    if packed is None:
+        packed = {net: kops.stack_plcore_weights(
+                      cfg, params[net], (quant or {}).get(net))
+                  for net in plcore_decls(cfg)}
+    out = kops.fused_render_two_pass(cfg, packed, rays_o, rays_d,
+                                     radii=radii)
+    rgb_f, rgb_c = out["rgb"], out["rgb_coarse"]
+    if white_bkgd:
+        rgb_f = volume.white_background(rgb_f, out["acc"])
+        rgb_c = volume.white_background(rgb_c, out["acc_coarse"])
+    return {"rgb": rgb_f, "rgb_coarse": rgb_c, "depth": out["depth"],
+            "acc": out["acc"]}
+
+
 def render_rays(cfg: NerfConfig, params: dict, rays_o, rays_d,
                 key: Optional[jax.Array] = None, *,
                 quant: Optional[dict] = None, use_kernel: bool = False,
                 fuse_two_pass: bool = False,
                 packed: Optional[dict] = None, ert_eps: float = 0.0,
-                white_bkgd: bool = True, alive=None) -> dict:
+                white_bkgd: bool = True, alive=None, radii=None) -> dict:
     """Two-pass render (paper §5.1): n_coarse stratified + n_fine importance.
 
     rays_o/rays_d: (R, 3). Returns {rgb, rgb_coarse, depth, acc}.
@@ -87,7 +169,13 @@ def render_rays(cfg: NerfConfig, params: dict, rays_o, rays_d,
     ``alive`` (fuse_two_pass only): optional (R,) float mask of
     externally-live rays — 0-rows (adaptive trunk-memo hits) enter the
     fused kernel dead and the same ERT skip drops their fine pass.
+    ``radii`` (cone configs, Mip-NeRF): (R,) or (R, 1) per-ray pixel
+    radius per unit of t; None renders each ray as a zero-radius cone.
     """
+    if cfg.cone:
+        return _render_cone(cfg, params, rays_o, rays_d, radii, key, quant,
+                            use_kernel, fuse_two_pass, packed, ert_eps,
+                            white_bkgd, alive)
     R = rays_o.shape[:-1]
     k1 = k2 = None
     if key is not None:

@@ -194,6 +194,73 @@ def deltas_from_t(t, far_cap: float = 1e10):
     return jnp.concatenate([d, last], axis=-1)
 
 
+# ------------------------------------------------- Mip-NeRF intervals ----
+def cone_intervals(near: float, far: float, n: int):
+    """The coarse pass's n intervals between n + 1 evenly spaced edges of
+    [near, far] (Mip-NeRF's deterministic ``sample_along_rays``) as
+    (t0, t1) rows, each (1, n): n + 1 edges fit no lane multiple, so the
+    interval ends travel as two n-wide rows. From an integer iota, as
+    ``det_u``, so the same code runs inside a kernel."""
+    k = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+    s0, s1 = k / n, (k + 1.0) / n
+    return near * (1.0 - s0) + far * s0, near * (1.0 - s1) + far * s1
+
+
+def mip_resample(t0, t1, weights, n: int, padding: float,
+                 eps: float = 1e-5):
+    """Mip-NeRF's deterministic ``resample_along_rays``: blur the coarse
+    weights (2-tap max, then 2-tap mean), add ``padding``, and draw n + 1
+    new edges from the piecewise-constant PDF over the coarse intervals
+    at u = linspace(0, 1 - eps_f32, n + 1), in the comparison-mask form
+    of ``sorted_piecewise_constant_pdf``: a max / min over ``u >= cdf``,
+    with no searchsorted and no gather, so it runs inside a kernel.
+
+    t0, t1: (..., M) interval ends (rows broadcast); weights (..., M).
+    Returns the new intervals' (t0, t1), each (..., n)."""
+    w = weights
+    w_prev = jnp.concatenate([w[..., :1], w[..., :-1]], axis=-1)
+    w_next = jnp.concatenate([w[..., 1:], w[..., -1:]], axis=-1)
+    w = 0.5 * (jnp.maximum(w_prev, w) + jnp.maximum(w, w_next)) + padding
+    M = w.shape[-1]
+    wsum = jnp.sum(w, axis=-1, keepdims=True)
+    pad = jnp.maximum(0.0, eps - wsum)
+    w = w + pad / M
+    cdf = jnp.minimum(1.0, prefix_sum(w / (wsum + pad)))
+    # the M + 1 CDF values [0, cdf_0 .. cdf_{M-2}, 1] as the lower and
+    # upper ends of the M bins, beside the bins' edges t0 and t1
+    c_lo = jnp.concatenate([jnp.zeros_like(cdf[..., :1]), cdf[..., :-1]],
+                           axis=-1)
+    c_hi = jnp.concatenate([cdf[..., :-1], jnp.ones_like(cdf[..., :1])],
+                           axis=-1)
+    k = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+    step = (1.0 - float(np.finfo(np.float32).eps)) / n
+
+    def row(x):
+        """(..., M) -> (..., 1, M): bins along the lanes."""
+        return jnp.expand_dims(x, -2)
+
+    def edges(u):
+        """The new edge at each u: queries along axis -2, bins along -1.
+        Bin 0 (cdf 0) always starts at or below u and the last bin (cdf
+        1) always ends above it, so the max / min over the selected bins
+        equal the reference's, whose unselected bins read as the first /
+        last edge and cdf value."""
+        q = jnp.expand_dims(u, -1)
+        below = q >= row(c_lo)                   # bins whose start <= u
+        above = q < row(c_hi)                    # bins whose end > u
+        lo = jnp.full(below.shape, -jnp.inf, jnp.float32)
+        hi = jnp.full(below.shape, jnp.inf, jnp.float32)
+        x0 = jnp.max(jnp.where(below, row(t0), lo), axis=-1)
+        x1 = jnp.min(jnp.where(above, row(t1), hi), axis=-1)
+        c0 = jnp.max(jnp.where(below, row(c_lo), lo), axis=-1)
+        c1 = jnp.min(jnp.where(above, row(c_hi), hi), axis=-1)
+        den = c1 - c0
+        f = jnp.clip(jnp.where(den > 0.0, (u - c0) / den, 0.0), 0.0, 1.0)
+        return x0 + f * (x1 - x0)
+
+    return edges(k * step), edges((k + 1.0) * step)
+
+
 # ===================================================================== ASDR =
 # Adaptive per-ray sample budgets + cross-ray trunk memoization. A cheap
 # coarse-only probe at scene load calibrates a quantized-voxel density

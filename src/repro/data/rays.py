@@ -12,6 +12,7 @@ rays returned unnormalized-origin + unit directions.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Callable, Iterator, Tuple
@@ -50,6 +51,30 @@ def camera_rays(c2w, H: int, W: int, focal: float):
     rays_d = rays_d / jnp.linalg.norm(rays_d, axis=-1, keepdims=True)
     rays_o = jnp.broadcast_to(c2w[:3, 3], rays_d.shape)
     return rays_o, rays_d
+
+
+@functools.lru_cache(maxsize=16)
+def pixel_radii(H: int, W: int, focal: float) -> np.ndarray:
+    """Mip-NeRF's per-pixel cone radius (``generate_rays``) for the
+    unit directions of ``camera_rays``: the distance from each pixel's
+    direction to that of the pixel one row below (one past the image for
+    the last row), times 2 / sqrt(12), halfway between the cones
+    inscribed in and circumscribed about the pixel. A rotation keeps the
+    distance, so it is computed in camera space, in float64, and depends
+    on the frame's size alone: one read-only array per (H, W, focal),
+    kept for the next frame of that size. Returns (H * W,) float32 in
+    row-major pixel order: the cone's radius per unit of distance along
+    the ray."""
+    x = ((np.arange(W) + 0.5 - W / 2) / focal)[None, :]
+    y = (-(np.arange(H) + 0.5 - H / 2) / focal)[:, None]
+    y_next = y - 1.0 / focal
+    n0 = np.sqrt(x * x + y * y + 1.0)
+    n1 = np.sqrt(x * x + y_next * y_next + 1.0)
+    dx = np.sqrt((x / n0 - x / n1) ** 2 + (y / n0 - y_next / n1) ** 2
+                 + (1.0 / n0 - 1.0 / n1) ** 2)
+    r = (dx * (2.0 / math.sqrt(12.0))).astype(np.float32).reshape(-1)
+    r.flags.writeable = False
+    return r
 
 
 # ------------------------------------------------------ analytic scenes -----

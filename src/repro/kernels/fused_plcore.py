@@ -17,6 +17,12 @@ dequantized in-register -> VRU in closed parallel-prefix form):
   same code the host path tests against), then the fine MLP+VRU and the
   final composite. Coarse weights, sample positions and every activation
   stay in VMEM.
+* ``cone_two_pass_call`` — the same economy for Mip-NeRF's cone rays
+  (``plcore_two_pass_cone``): a per-ray radius column in, each interval
+  cast to a frustum Gaussian and encoded by its IPE in the PEU, one
+  pinned network read by both levels, the blurred-weight resample in
+  mask form, the fine level on the resampled intervals alone, the VRU
+  over finite intervals.
 
 Ray blocks. Mosaic unrolls every vector op over the vregs of its operand,
 so a body that works on a whole (rt * N, 256) activation compiles in time
@@ -65,7 +71,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.nerf_icarus import NerfConfig
-from repro.core import sampling
+from repro.core import encoding, sampling
+from repro.core.mlp import cone_heads
 from repro.kernels.rmcm_matmul import _unpack_signs
 
 
@@ -151,9 +158,6 @@ def _pass_body(cfg: NerfConfig, G: int, N: int, net, o, d, ts, deltas,
     ``ped``: the per-ray direction encoding, precomputable once when
     several passes share the same rays (the two-pass kernel encodes
     directions ONCE where the host path does it per pass)."""
-    tw, tb, sfw, sb, fb, cw, cb, rw, rb = net
-    W = cfg.trunk_width
-    pe_dim, de_dim = cfg.pos_enc_dim, cfg.dir_enc_dim
     T = G * N
 
     # ---- positions & PEU (double-angle) --------------------------------
@@ -162,6 +166,19 @@ def _pass_body(cfg: NerfConfig, G: int, N: int, net, o, d, ts, deltas,
     if ped is None:
         dn = d * jax.lax.rsqrt(jnp.sum(d * d, -1, keepdims=True))
         ped = _pe_double_angle(dn, cfg.dir_freqs)      # (G, de_dim)
+
+    sigma, rgb = _mlp(cfg, G, N, net, pe, ped)
+    return _vru(G, N, sigma, rgb, deltas)
+
+
+def _mlp(cfg: NerfConfig, G: int, N: int, net, pe, ped):
+    """The MLP engine over a block's (G * N, pe_dim) sample encodings and
+    its (G, de_dim) per-ray direction encodings: (raw density (T, 1),
+    sigmoid colour (G, N, 3))."""
+    tw, tb, sfw, sb, fb, cw, cb, rw, rb = net
+    W = cfg.trunk_width
+    pe_dim, de_dim = cfg.pos_enc_dim, cfg.dir_enc_dim
+    T = G * N
 
     # ---- MLP engine (MONB) ---------------------------------------------
     # skip layers run as SPLIT matmuls (h @ W_h + pe @ W_pe == the concat
@@ -191,8 +208,12 @@ def _pass_body(cfg: NerfConfig, G: int, N: int, net, o, d, ts, deltas,
     hc = jax.nn.relu(
         (colf.reshape(G, N, C) + cold[:, None, :]).reshape(T, C) + cb)
     rgb = jax.nn.sigmoid(_mm(hc, rw) + rb).reshape(G, N, 3)
+    return sigma, rgb
 
-    # ---- VRU: closed-form parallel prefix ------------------------------
+
+def _vru(G: int, N: int, sigma, rgb, deltas):
+    """VRU: closed-form parallel prefix over a block's (G, N) samples.
+    Returns (rgb_pix (G, 3), w (G, N), T_next (G, N))."""
     # T_{i+1} = exp(prefix_sum_{j<=i} x_j); T_0 = 1; w_i = T_i - T_{i+1}.
     # Same math as eq.(5)'s recurrence, but one vectorized prefix sum
     # instead of N serial steps with a dynamic_update_slice each.
@@ -468,3 +489,116 @@ def two_pass_plcore_call(cfg: NerfConfig, packed_c: dict, packed_f: dict,
         name="plcore_two_pass",
     )(rays_o, rays_d, t_row, *mask_in, *wc, *wf)
     return out[:, 0:3], out[:, 3:6], out[:, 6], out[:, 7], out[:, 8]
+
+
+# ------------------------------------------- Mip-NeRF: the cone two-pass ----
+def _cone_pass_body(cfg: NerfConfig, G: int, N: int, net, o, d, r, t0, t1,
+                    ped, dnorm):
+    """One pass over the (G, N) intervals [t0, t1) of a block of G cone
+    rays: each frustum cast to a Gaussian, its IPE in the PEU
+    (``encoding.integrated_pos_enc_recurrence``), the MLP, Mip-NeRF's
+    heads, the VRU over the finite intervals. Returns
+    (rgb_pix (G, 3), w (G, N), T_next (G, N))."""
+    T = G * N
+    t_mean, cov = encoding.conical_frustum_to_gaussian(d, t0, t1, r)
+    mean = (o[:, None, :] + t_mean[..., None] * d[:, None, :]).reshape(T, 3)
+    pe = encoding.integrated_pos_enc_recurrence(mean, cov.reshape(T, 3),
+                                                cfg.pos_freqs)
+    sigma, rgb = cone_heads(cfg, *_mlp(cfg, G, N, net, pe, ped))
+    return _vru(G, N, sigma, rgb, (t1 - t0) * dnorm)
+
+
+def _cone_two_pass_rays(cfg: NerfConfig, G: int, P: int, P2: int,
+                        q: bool, o, d, r, t0_row, t1_row, w_refs):
+    """Mip-NeRF's two levels for one block of G rays, in VMEM: the coarse
+    pass over the pinned (1, n) interval rows, the blurred-weight
+    resample to n new intervals (``sampling.mip_resample``, mask form),
+    the fine pass over those alone. Returns the (G, 9) record
+    [rgb (3) | rgb_coarse (3) | acc | acc_coarse | depth].
+
+    Both levels read the one pinned network as two trips of one loop
+    over the same pass body, so its weights are loaded once per level:
+    written out twice, the compiler merges the second level's loads into
+    the first's and keeps a copy of every matrix live across both
+    (scoped VMEM at MIPNERF, compiled for a v5e: 14.2 MiB written out,
+    4.5-5.0 MiB as the loop)."""
+    n = cfg.n_coarse
+    o = o.astype(jnp.float32)
+    d = d.astype(jnp.float32)
+    r = r.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.sum(d * d, -1, keepdims=True))
+    ped = _pe_double_angle(d * inv, cfg.dir_freqs)     # (G, de_dim)
+    dnorm = 1.0 / inv
+
+    def level(i, carry):
+        """One level: the pass over the carried intervals, then (first
+        level only) the resample to the next level's."""
+        t0, t1, _, cur = carry
+        net = _net_arrays(cfg, w_refs, q, P, P2)
+        rgb, w, Tn = _cone_pass_body(cfg, G, n, net, o, d, r, t0, t1, ped,
+                                     dnorm)
+        nxt = jnp.concatenate([rgb, 1.0 - Tn[:, n - 1:],
+                               _row_sum(w * (0.5 * (t0 + t1)))], axis=-1)
+
+        def resample():
+            return sampling.mip_resample(t0, t1, w, n, cfg.resample_padding)
+
+        t0, t1 = jax.lax.cond(i == 0, resample, lambda: (t0, t1))
+        return t0, t1, cur, nxt
+
+    # the carry's first records from data: a constant's replicated layout
+    # is one Mosaic cannot carry through the loop
+    zero = 0.0 * jnp.concatenate([o, d[:, :2]], axis=-1)
+    carry = (jnp.broadcast_to(t0_row.astype(jnp.float32), (G, n)),
+             jnp.broadcast_to(t1_row.astype(jnp.float32), (G, n)),
+             zero, zero)
+    _, _, coarse, fine = jax.lax.fori_loop(0, 2, level, carry)
+    return jnp.concatenate([fine[:, 0:3], coarse[:, 0:3], fine[:, 3:4],
+                            coarse[:, 3:4], fine[:, 4:5]], axis=-1)
+
+
+def _make_cone_kernel(cfg: NerfConfig, rt: int, block: int, P: int, P2: int,
+                      q: bool):
+    def kernel(o_ref, d_ref, r_ref, t0_ref, t1_ref, *refs):
+        w_refs, out_o = refs[:-1], refs[-1]
+
+        def ray_block(rows):
+            out_o[rows, :] = _cone_two_pass_rays(
+                cfg, block, P, P2, q, o_ref[rows, :], d_ref[rows, :],
+                r_ref[rows, :], t0_ref[...], t1_ref[...],
+                w_refs).astype(out_o.dtype)
+
+        _for_each_block(rt, block, ray_block)
+
+    return kernel
+
+
+def cone_two_pass_call(cfg: NerfConfig, packed: dict, rays_o, rays_d,
+                       radii, t0_row, t1_row, *, rt: int,
+                       interpret: bool = True,
+                       block: Optional[int] = None,
+                       vmem_limit_bytes: Optional[int] = None):
+    """ONE pallas_call per ray tile for Mip-NeRF's coarse -> resample ->
+    fine chain (``plcore_two_pass_cone`` in HLO and device traces).
+    rays: (R, 3) and radii (R, 1) with R % rt == 0; t0_row / t1_row:
+    (1, n_coarse) coarse interval ends, the same for every ray.
+    ``packed``: the config's one network, pinned once and read by both
+    passes. Returns the (R, 9) record of ``two_pass_plcore_call``."""
+    R = rays_o.shape[0]
+    assert R % rt == 0, (R, rt)
+    block = rt if block is None else block
+    P = -(-(cfg.trunk_width + cfg.pos_enc_dim) // 128) * 128
+    P2 = -(-(cfg.trunk_width + cfg.dir_enc_dim) // 128) * 128
+    q = "trunk_mag" in packed
+    w = _kernel_weights([packed[k] for k in _weight_order(q)])
+    return pl.pallas_call(
+        _make_cone_kernel(cfg, rt, block, P, P2, q),
+        grid=(R // rt,),
+        in_specs=[_rows(rt, 3), _rows(rt, 3), _rows(rt, 1), _pinned(t0_row),
+                  _pinned(t1_row)] + [_pinned(a) for a in w],
+        out_specs=_rows(rt, 9),
+        out_shape=jax.ShapeDtypeStruct((R, 9), jnp.float32),
+        compiler_params=_compiler_params(vmem_limit_bytes),
+        interpret=interpret,
+        name="plcore_two_pass_cone",
+    )(rays_o, rays_d, radii, t0_row, t1_row, *w)

@@ -329,15 +329,36 @@ def two_pass_vmem_bytes(cfg: NerfConfig, rt: int, block: int,
     double-buffered per-ray blocks (o, d, mask in; the (rt, 9) record
     out), and one block's two passes — coarse and fine values both
     live — with the resample's (n_fine, n_coarse - 1) one-hots and the
-    rank merge's (n, n_coarse + n_fine) ones per ray."""
+    rank merge's (n, n_coarse + n_fine) ones per ray. A cone config:
+    the same per-ray blocks and ``_cone_vmem_bytes``."""
     Nc, Nf = cfg.n_coarse, cfg.n_fine
-    Nt = Nc + Nf
     io = 2 * rt * 4 * _row_bytes(9)
+    if cfg.cone:
+        return _cone_vmem_bytes(cfg, block, quantized) + io
+    Nt = Nc + Nf
     resample = block * (3 * Nf * _row_bytes(Nc - 1)
                         + 2 * Nt * _row_bytes(Nt))
     return (2 * kernel_weight_vmem_bytes(cfg, quantized) + io
             + block * (Nc + Nt) * _act_row_bytes(cfg) + resample
             + _net_scratch_bytes(cfg, Nc) + _net_scratch_bytes(cfg, Nt))
+
+
+def _cone_vmem_bytes(cfg: NerfConfig, block: int, quantized: bool) -> int:
+    """The cone two-pass kernel's share of ``two_pass_vmem_bytes`` beside
+    its per-ray blocks (o, d, radius in; the record out): the one
+    network both levels read; the two (1, n) interval rows;
+    one block's level at a time (the levels are trips of one loop), each
+    sample row carrying its frustum Gaussian and IPE columns besides the
+    NeRF pass's values; and the resample's (n, n) masks and selections
+    per ray. At MIPNERF: 5.9 MiB, where the compiler for a v5e asks
+    4.5-5.0 MiB."""
+    n = cfg.n_coarse
+    cone_cols = 8 * _row_bytes(3)
+    return (kernel_weight_vmem_bytes(cfg, quantized)
+            + 2 * _tile_bytes((1, n))
+            + block * n * (_act_row_bytes(cfg) + cone_cols)
+            + block * 4 * _rup(n, 8) * _row_bytes(n)
+            + _net_scratch_bytes(cfg, n))
 
 
 def _budget(cfg: NerfConfig, vmem_budget_bytes: Optional[int]) -> int:
@@ -437,7 +458,7 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
                           block: Optional[int] = None,
                           vmem_budget_bytes: Optional[int] = None,
                           interpret: Optional[bool] = None,
-                          alive=None) -> dict:
+                          alive=None, radii=None) -> dict:
     """The complete coarse -> importance -> fine render as ONE pallas_call
     per ray tile (deterministic/inference sampling; coarse weights never
     leave VMEM). ``packed``: {"coarse", "fine"} stack_plcore_weights
@@ -453,6 +474,10 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
     chip the VMEM model's tile and ``pick_ray_block``). Returns
     {rgb, rgb_coarse, acc, acc_coarse, depth}, each trimmed to R rays;
     white background is the caller's composite.
+
+    A cone config (Mip-NeRF) runs ``cone_two_pass_call`` instead, on
+    ``radii`` ((R, 1) per-ray cone radius, required) and the one network
+    under ``packed["coarse"]``; it takes no ERT or alive mask.
     """
     _DISPATCHES.inc()
     it = _interpret(cfg, interpret)
@@ -475,9 +500,24 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
             # padded rows enter dead: their blocks skip the fine pass
             alive = jnp.concatenate(
                 [alive, jnp.zeros((padn,), alive.dtype)])
+        if radii is not None:
+            radii = jnp.concatenate([radii, radii[-1:].repeat(padn, 0)])
+    vmem = None if it else two_pass_vmem_bytes(cfg, rt, block, quantized)
+    if cfg.cone:
+        if ert_eps > 0.0 or alive is not None or radii is None:
+            raise ValueError("the cone kernel takes the rays' radii and no "
+                             "ERT or alive mask")
+        t0_row, t1_row = sampling.cone_intervals(cfg.near, cfg.far,
+                                                 cfg.n_coarse)
+        out = _fp.cone_two_pass_call(
+            cfg, packed["coarse"], rays_o, rays_d,
+            radii.reshape(-1, 1).astype(jnp.float32), t0_row, t1_row,
+            rt=rt, interpret=it, block=block, vmem_limit_bytes=vmem)
+        return {"rgb": out[:R, 0:3], "rgb_coarse": out[:R, 3:6],
+                "acc": out[:R, 6], "acc_coarse": out[:R, 7],
+                "depth": out[:R, 8]}
     # deterministic coarse samples are ray-independent: ship ONE row
     t_row = sampling.stratified(cfg.near, cfg.far, cfg.n_coarse, (1,), None)
-    vmem = None if it else two_pass_vmem_bytes(cfg, rt, block, quantized)
     rgb, rgb_c, acc, acc_c, depth = _fp.two_pass_plcore_call(
         cfg, packed["coarse"], packed["fine"], rays_o, rays_d, t_row,
         rt=rt, ert_eps=float(ert_eps), interpret=it, block=block,

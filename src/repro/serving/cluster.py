@@ -257,6 +257,9 @@ class ClusterScheduler(TileScheduler):
         self._home_cells: Dict[Tuple[str, int], int] = {}  # re-keyed/host
         self._placed_host: Optional[Host] = None
 
+    def _cone_refusals(self) -> List[str]:
+        return ["ClusterEngine"] + super()._cone_refusals()
+
     # ------------------------------------------------------- placement ----
     def _place(self, scene: str, exclude=()) -> Optional[Host]:
         """Best host for one tile of ``scene``: healthy outranks suspect

@@ -166,7 +166,7 @@ class _Active:
     are handed out bucket-by-bucket so tiles stay (scene, budget)-pure,
     while ``next_ray`` keeps counting TOTAL handed-out rays so
     ``remaining`` and the admission math are bucket-agnostic."""
-    __slots__ = ("req", "rid", "seq", "rays_o", "rays_d", "fb",
+    __slots__ = ("req", "rid", "seq", "rays_o", "rays_d", "radii", "fb",
                  "next_ray", "n_done", "n_rays", "submit_s",
                  "service_start_s", "deadline_abs", "terminal",
                  "degraded", "retries", "fallbacks",
@@ -179,6 +179,7 @@ class _Active:
         ro, rd = R.camera_rays(c2w, req.hw, req.hw, 0.9 * req.hw)
         self.rays_o = np.asarray(ro, np.float32).reshape(-1, 3)
         self.rays_d = np.asarray(rd, np.float32).reshape(-1, 3)
+        self.radii = None            # (n, 1) pixel radii: cone scenes only
         self.n_rays = self.rays_o.shape[0]
         # NaN framebuffer: a pixel the scatter never wrote — or a padded
         # tail ray leaking into a neighbor — cannot hide as black
@@ -201,6 +202,19 @@ class _Active:
     def remaining(self) -> int:
         return self.n_rays - self.next_ray
 
+    def footprint(self, tracer) -> None:
+        """The per-pixel cone radii a cone (Mip-NeRF) scene renders with:
+        each pixel's distance to its neighbour's unit direction times
+        2 / sqrt(12) (``data.rays.pixel_radii``), as an (n, 1) column."""
+        hw = self.req.hw
+        with tracer.span("request.footprint", cat="request", hw=hw):
+            self.radii = R.pixel_radii(hw, hw, 0.9 * hw).reshape(-1, 1)
+
+
+def _is_cone(pp) -> bool:
+    """Whether a resident scene renders cone rays (Mip-NeRF)."""
+    return bool(getattr(getattr(pp, "cfg", None), "cone", False))
+
 
 @dataclass
 class _Tile:
@@ -220,6 +234,7 @@ class _Tile:
     rays_o: np.ndarray
     rays_d: np.ndarray
     n_real: int                             # non-pad rays
+    radii: Optional[np.ndarray] = None      # (n, 1) cone radii (Mip-NeRF)
     home_cell: Optional[int] = None         # shard-locality routing
     degraded: bool = False                  # coarse-only program
     budget: Optional[int] = None            # adaptive fine-sample budget
@@ -402,6 +417,8 @@ class TileScheduler:
         tr = self.tracer
         with tr.span("request.rays", cat="request", hw=req.hw):
             a = _Active(req, rid, rid, self._clock())
+        if _is_cone(self.cache.peek(req.scene_id)):
+            a.footprint(tr)
         a.dispatches_at_submit = self.stats["dispatches"]
         if tr.enabled and tr.sampled_request(rid):
             a.trace_span = tr.begin("request", cat="request", request=rid,
@@ -512,6 +529,18 @@ class TileScheduler:
                 a.degraded = True
                 self.stats["degraded_requests"] += 1
 
+    def _cone_refusals(self) -> List[str]:
+        """The armed modes that do not render cone (Mip-NeRF) scenes: a
+        cone scene's first tile raises a ValueError naming the first."""
+        modes = []
+        if self.adaptive is not None:
+            modes.append("adaptive sampling")
+        if self.degrade_on_overload:
+            modes.append("coarse_only degradation (degrade_on_overload)")
+        if self.executor is not None and self.executor.percell:
+            modes.append("per-cell dispatch")
+        return modes
+
     def _route(self, scene_id: str, pp) -> Optional[int]:
         """Shard-locality routing: the tile's home cell is a mesh device
         owning the maximal share of this scene's trunk layers (owner-map
@@ -603,6 +632,11 @@ class TileScheduler:
         if resolved is None:
             return None
         scene, pp, cands, host_id = resolved
+        cone = _is_cone(pp)
+        refused = self._cone_refusals() if cone else []
+        if refused:
+            raise ValueError(f"{refused[0]} does not render cone "
+                             f"(Mip-NeRF) scenes: scene {scene!r}")
         if scene != self._current_scene:
             self.stats["scene_switches"] += 1
             self._current_scene = scene
@@ -648,10 +682,13 @@ class TileScheduler:
             # render in-kernel at exactly their class's n_fine
             budget = int(ar.budgets[min(bucket, len(ar.budgets) - 1)]
                          if bucket < len(ar.budgets) else ar.budgets[0])
-        spans, chunks_o, chunks_d, n = [], [], [], 0
+        spans, chunks_o, chunks_d, chunks_r, n = [], [], [], [], 0
         for a in scene_cands:
             if a.degraded != degraded:
                 continue
+            if cone and a.radii is None:
+                # the scene was not resident when the request arrived
+                a.footprint(self.tracer)
             if bucket is not None:
                 avail = a.bucket_idx[bucket]
                 cur = a.bucket_next[bucket]
@@ -674,6 +711,8 @@ class TileScheduler:
                 spans.append((a, a.next_ray, take))
                 chunks_o.append(a.rays_o[a.next_ray:a.next_ray + take])
                 chunks_d.append(a.rays_d[a.next_ray:a.next_ray + take])
+                if cone:
+                    chunks_r.append(a.radii[a.next_ray:a.next_ray + take])
             a.next_ray += take
             n += take
             if n == self.tile_rays:
@@ -691,6 +730,8 @@ class TileScheduler:
         if pad:                       # tail tile: repeat the last real ray
             chunks_o.append(np.repeat(chunks_o[-1][-1:], pad, axis=0))
             chunks_d.append(np.repeat(chunks_d[-1][-1:], pad, axis=0))
+            if cone:
+                chunks_r.append(np.repeat(chunks_r[-1][-1:], pad, axis=0))
             self.stats["padded_rays"] += pad
         tid = self._tile_seq
         self._tile_seq += 1
@@ -700,7 +741,8 @@ class TileScheduler:
                      budget=budget,
                      dead_bucket=(bucket is not None
                                   and bucket >= len(ar.budgets)),
-                     host_id=host_id, tid=tid)
+                     host_id=host_id, tid=tid,
+                     radii=np.concatenate(chunks_r) if cone else None)
         return tile
 
 
@@ -805,12 +847,15 @@ class TileExecutor:
                      if fault is not None and fault["kind"] == "straggle"
                      else 0.0)
             return rgb, cost, extra
+        footprint = {}
         with tr.span("tile.commit", cat="tile", tile=tile.tid):
             o = tile.pp.commit(tile.rays_o)
             d = tile.pp.commit(tile.rays_d)
+            if tile.radii is not None:
+                footprint["radii"] = tile.pp.commit(tile.radii)
         rgb, cost = tile.pp.dispatch_tile(
             o, d, home_cell=tile.home_cell, coarse_only=tile.degraded,
-            percell=self.percell,
+            percell=self.percell, **footprint,
             tracer=tr if tr.enabled else None,
             trace_attrs={"tile": tile.tid, "host": tile.host_id,
                          "scene": tile.scene_id} if tr.enabled else None)
@@ -883,9 +928,11 @@ class TileExecutor:
                 a.fallbacks += 1
         o = tile.pp.commit(tile.rays_o)
         d = tile.pp.commit(tile.rays_d)
+        footprint = ({} if tile.radii is None
+                     else {"radii": tile.pp.commit(tile.radii)})
         arr = np.asarray(
             tile.pp.render_tile(o, d, coarse_only=True) if tile.degraded
-            else tile.pp.render_tile_oracle(o, d))
+            else tile.pp.render_tile_oracle(o, d, **footprint))
         return arr, tile.pp.tile_gather_cost(tile.home_cell)
 
     def _account(self, tile: _Tile, cost: dict) -> None:

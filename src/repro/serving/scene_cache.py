@@ -263,6 +263,12 @@ class SceneCache:
         scheduler reads this per HOST to decide quarantine."""
         return list(self._failed)
 
+    def peek(self, scene_id: str) -> Optional[PackedPlcore]:
+        """The scene's resident weights, or None when it is not resident,
+        without counting a hit or moving it in the LRU order."""
+        ent = self._entries.get(scene_id)
+        return None if ent is None else ent[0]
+
     def get(self, scene_id: str) -> PackedPlcore:
         """Fetch a scene, loading (and possibly evicting) on miss. The
         returned instance is resident until LRU eviction pushes it out;

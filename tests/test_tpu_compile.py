@@ -1,7 +1,9 @@
 """The served PLCore kernels compile for a TPU v5e at the published widths.
 
 Each case lowers a kernel entry point at ``CONFIG`` (8x256 trunk, skip at
-4, 128-wide colour branch, L=10/4, 64 + 128 samples) with
+4, 128-wide colour branch, L=10/4, 64 + 128 samples), or for the cone
+kernel at ``MIPNERF`` (one shared 8x256 network, IPE L=16, 128 + 128
+intervals), with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology and
 compiles it with the TPU compiler installed here — no chip needed. That
 is what Mosaic refuses and interpret mode accepts: rank-1 blocks, ops
@@ -21,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.nerf_icarus import CONFIG
+from repro.configs.nerf_icarus import CONFIG, MIPNERF
 from repro.core import rmcm
 from repro.core.plcore import plcore_decls
 from repro.kernels import ops as kops
@@ -61,15 +63,16 @@ def _abstract(tree, sharding):
         tree)
 
 
-def _packed(quantized: bool, sharding):
-    """Shapes of both networks' packed layouts at CONFIG (no weights are
-    materialized: the pack is traced abstractly)."""
+def _packed(quantized: bool, sharding, cfg=CONFIG):
+    """Shapes of the networks' packed layouts (both at CONFIG, the one
+    shared network at MIPNERF; no weights are materialized: the pack is
+    traced abstractly)."""
     def build():
-        params = init_params(plcore_decls(CONFIG), jax.random.PRNGKey(0))
+        params = init_params(plcore_decls(cfg), jax.random.PRNGKey(0))
         return {net: kops.stack_plcore_weights(
-                    CONFIG, params[net],
+                    cfg, params[net],
                     rmcm.quantize_tree(params[net]) if quantized else None)
-                for net in ("coarse", "fine")}
+                for net in params}
     return _abstract(jax.eval_shape(build), sharding)
 
 
@@ -89,6 +92,17 @@ def _two_pass(quantized: bool, ert: bool):
                 CONFIG, pk, o, d, interpret=False)
         return fn, args
     return case
+
+
+def _cone_two_pass(sharding):
+    """Mip-NeRF's cone two-pass kernel: IPE, the mask-form resample, one
+    pinned network read by both passes, the (rt, 1) radius column."""
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+    fn = lambda pk, o, d, r: kops.fused_render_two_pass(
+        MIPNERF, pk, o, d, radii=r, interpret=False)
+    return fn, [_packed(False, sharding, MIPNERF), arr(N_RAYS, 3),
+                arr(N_RAYS, 3), arr(N_RAYS, 1)]
 
 
 def _one_pass(n_samples: int, ert: bool):
@@ -114,6 +128,7 @@ CASES = {
     "two_pass_f32": _two_pass(quantized=False, ert=False),
     "two_pass_rmcm": _two_pass(quantized=True, ert=False),
     "two_pass_alive_ert": _two_pass(quantized=False, ert=True),
+    "two_pass_cone_mipnerf": _cone_two_pass,
     "one_pass_coarse": _one_pass(CONFIG.n_coarse, ert=False),
     "one_pass_fine_alive": _one_pass(CONFIG.n_samples, ert=True),
 }
