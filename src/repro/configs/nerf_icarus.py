@@ -63,15 +63,18 @@ class NerfConfig:
     rmcm_enabled: bool = True
     # render batching — PLCore analogue: rays per fused-kernel tile
     rays_per_tile: int = 128    # paper batch-computing: 128 samples weight-stationary
-    # fused-kernel scoped-VMEM budget: the limit handed to the compiler
-    # (16 MiB is its default scoped limit on TPU v5e, of 128 MiB of VMEM).
-    # The one-kernel two-pass path pins BOTH networks' gathered weight
-    # stacks as the working set every grid step (see
-    # kernels.ops.two_pass_vmem_bytes) plus one ray block's scratch; the
-    # ray tile rt is sized so the per-ray in/out blocks fit the rest.
+    # fused-kernel scoped-VMEM budget: the ray tile rt is the largest
+    # whose VMEM model (kernels.ops.two_pass_vmem_bytes) fits it, and the
+    # compiler is handed that model's figure as its scoped limit. Half of
+    # a TPU v5e's 128 MiB of VMEM (its default scoped limit is 16 MiB),
+    # the rest left to what the model does not count. The one-kernel
+    # two-pass path pins BOTH networks' gathered weight stacks as the
+    # working set every grid step plus one ray block's scratch (26.5 MiB
+    # modelled at CONFIG's block of 4 rays, above the 16 MiB default);
+    # the per-ray in/out blocks take the rest.
     # Mesh-sharding the weights shrinks the HBM-resident footprint, not
     # this working set.
-    kernel_vmem_budget_mb: float = 16.0
+    kernel_vmem_budget_mb: float = 64.0
     # how the Pallas kernels run: None compiles them through Mosaic when
     # JAX's first device is a TPU and interprets them elsewhere; False
     # always compiles (a run that must be on the chip then fails off it

@@ -30,14 +30,22 @@ and VMEM proportional to rt (the one-pass kernel at CONFIG: 49 s at 8 rays
 per step, out of scoped VMEM at 16). Each kernel therefore walks its ray
 tile in a ``fori_loop`` over blocks of ``block`` rays, with the per-sample
 work of one block in registers and VMEM scratch: the compiled body, and
-its VMEM, depend on ``block``, not on ``rt``. Off-TPU the same kernel runs
-under the Pallas interpreter with one block per tile.
+its VMEM, depend on ``block``, not on ``rt``. The block is also what each
+MXU weight load feeds: a trunk layer streams block * N rows, and every
+per-ray contraction against a constant matrix (the prefix-sum triangles,
+the row sums, the colour branch's direction half) block rows. The
+one-pass kernel steps by ``ops.pick_ray_block`` (one ray at CONFIG: its
+(rt, N) sample blocks are too wide for a dynamic load of 2 or 4 rows);
+the two-pass kernels, whose per-ray blocks are at most 9 lanes wide, by
+``ops.pick_two_pass_block`` (4 rays at CONFIG and at MIPNERF: a row
+budget over the widest pass). Off-TPU the same kernel runs under the
+Pallas interpreter with one block per tile.
 
 Per-ray early termination (Cicero, arXiv 2404.11852) inside the two-pass
 kernel: after the coarse VRU, rays with transmittance < ert_eps keep the
 coarse color/acc/depth, and a block whose rays are all dead skips the
 importance resample and the fine pass (a ``lax.cond``). Blocks are a few
-rays on the chip, so the skip is close to per-ray there.
+rays on the chip (4 at CONFIG), so the skip is close to per-ray there.
 
 HBM traffic per ray (f32 words), N = n_coarse + n_fine samples:
 

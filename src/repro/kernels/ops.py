@@ -96,6 +96,14 @@ def dispatch_count() -> int:
     return int(_DISPATCHES.value)
 
 
+# Rays per inner-loop step of the last two-pass program built (set at
+# trace time, like the counters above): which block the served kernel
+# steps by.
+_RAY_BLOCK = _obs_registry().gauge(
+    "plcore_kernel_ray_block",
+    "rays per inner-loop step of the last two-pass kernel traced")
+
+
 def stack_plcore_weights(cfg: NerfConfig, params: dict,
                          quant: Optional[dict] = None) -> dict:
     """Kernel weight layout: trunk stacked (L, P, W) with per-layer row
@@ -283,16 +291,26 @@ def _net_scratch_bytes(cfg: NerfConfig, n_samples: int) -> int:
             + _rup(n_samples, 8) * _row_bytes(n_samples))
 
 
-# Sample rows one inner-loop step of a kernel works on: the compiled body
-# (and its VMEM scratch) grows with it, the MXU's row utilization too.
+# Sample rows one inner-loop step of the one-pass kernel works on: the
+# compiled body (and its VMEM scratch) grows with it, the MXU's row
+# utilization too.
 _BLOCK_SAMPLE_ROWS = 512
+# The same budget for the two-pass kernels: a block's rays times the rows
+# of its widest pass (NeRF's fine pass over n_coarse + n_fine samples, a
+# cone level's n_coarse intervals). On a v5e a 512-ray tile took 8.76 /
+# 8.27 / 8.17 ms at NeRF blocks of 1 / 2 / 4 rays (192 / 384 / 768
+# rows), and 7.78 / 6.93 / 7.32 ms at cone blocks of 1 / 4 / 8 (128 /
+# 512 / 1024 rows): 768 keeps the best of each.
+_TWO_PASS_BLOCK_ROWS = 768
 
 
 def pick_ray_block(n_samples: int) -> int:
-    """Rays per inner-loop step on the chip: the most whose samples fit
-    ``_BLOCK_SAMPLE_ROWS`` rows — a power of two from 8 up, else ONE ray:
-    Mosaic loads a block of 2 or 4 rows at a dynamic offset only from
-    arrays at most 128 lanes wide (the (rt, N) sample blocks are wider)."""
+    """Rays per inner-loop step of the ONE-PASS kernel on the chip: the
+    most whose samples fit ``_BLOCK_SAMPLE_ROWS`` rows — a power of two
+    from 8 up, else ONE ray. Its (rt, N) t and delta blocks are wider
+    than 128 lanes, and Mosaic loads a block of 2 or 4 rows at a dynamic
+    offset only from arrays at most 128 lanes wide. The two-pass kernels
+    have no such array and take ``pick_two_pass_block``."""
     g = _BLOCK_SAMPLE_ROWS // max(1, n_samples)
     if g < 8:
         return 1
@@ -303,12 +321,29 @@ def pick_ray_block(n_samples: int) -> int:
 
 
 def _ray_block(rt: int, want: int) -> int:
-    """The block the kernel's loop steps by: ``want`` (a power of two)
-    halved until it divides rt, and 1 below 8 (see ``pick_ray_block``)."""
+    """The block the one-pass kernel's loop steps by: ``want`` (a power of
+    two) halved until it divides rt, and 1 below 8 (its wide (rt, N)
+    blocks, see ``pick_ray_block``)."""
     g = min(want, rt)
     while g > 1 and rt % g:
         g //= 2
     return g if g >= 8 or g == rt else 1
+
+
+def pick_two_pass_block(cfg: NerfConfig, rt: int) -> int:
+    """Rays per inner-loop step of the two-pass kernels on the chip: the
+    largest power of two G dividing rt with G times the rows of the
+    widest pass within ``_TWO_PASS_BLOCK_ROWS`` (1 if one ray's pass is
+    wider). Every trunk weight load and every per-ray contraction against
+    a constant matrix (prefix-sum triangles, row sums, the colour
+    branch's direction half) then streams G rays' rows. Their per-ray
+    blocks are at most 9 lanes wide (o, d, radius or mask in, the record
+    out), so blocks of 2 and 4 load at a dynamic offset."""
+    rows = cfg.n_coarse if cfg.cone else cfg.n_coarse + cfg.n_fine
+    g = 1
+    while rt % (2 * g) == 0 and 2 * g * rows <= _TWO_PASS_BLOCK_ROWS:
+        g *= 2
+    return g
 
 
 def fused_vmem_bytes(cfg: NerfConfig, n_samples: int, rt: int, block: int,
@@ -329,8 +364,17 @@ def two_pass_vmem_bytes(cfg: NerfConfig, rt: int, block: int,
     double-buffered per-ray blocks (o, d, mask in; the (rt, 9) record
     out), and one block's two passes — coarse and fine values both
     live — with the resample's (n_fine, n_coarse - 1) one-hots and the
-    rank merge's (n, n_coarse + n_fine) ones per ray. A cone config:
-    the same per-ray blocks and ``_cone_vmem_bytes``."""
+    rank merge's (n, n_coarse + n_fine) ones per ray. Each sample row
+    also carries four trunk-width and three narrow values beside
+    ``_act_row_bytes``, for what that count leaves out (Mosaic's copies
+    for the body's reshapes and broadcasts; with ERT or the alive mask
+    the fine pass sits behind a ``lax.cond`` and shares no buffer with
+    the coarse one): compiled for a v5e at CONFIG with both on, the
+    widest variant, the kernel asks 10.9 / 14.6 / 22.5 MiB at blocks of
+    1 / 2 / 4 rays, 3.7-4.0 MiB a ray, where this model gives 14.3 /
+    18.4 / 26.5 (without the extra values 13.0 / 15.7 / 21.0, too few
+    from 4 rays on). A cone config: the same per-ray blocks and
+    ``_cone_vmem_bytes``."""
     Nc, Nf = cfg.n_coarse, cfg.n_fine
     io = 2 * rt * 4 * _row_bytes(9)
     if cfg.cone:
@@ -338,8 +382,10 @@ def two_pass_vmem_bytes(cfg: NerfConfig, rt: int, block: int,
     Nt = Nc + Nf
     resample = block * (3 * Nf * _row_bytes(Nc - 1)
                         + 2 * Nt * _row_bytes(Nt))
+    row = (_act_row_bytes(cfg) + 4 * _row_bytes(cfg.trunk_width)
+           + 3 * _row_bytes(3))
     return (2 * kernel_weight_vmem_bytes(cfg, quantized) + io
-            + block * (Nc + Nt) * _act_row_bytes(cfg) + resample
+            + block * (Nc + Nt) * row + resample
             + _net_scratch_bytes(cfg, Nc) + _net_scratch_bytes(cfg, Nt))
 
 
@@ -350,8 +396,9 @@ def _cone_vmem_bytes(cfg: NerfConfig, block: int, quantized: bool) -> int:
     one block's level at a time (the levels are trips of one loop), each
     sample row carrying its frustum Gaussian and IPE columns besides the
     NeRF pass's values; and the resample's (n, n) masks and selections
-    per ray. At MIPNERF: 5.9 MiB, where the compiler for a v5e asks
-    4.5-5.0 MiB."""
+    per ray. At MIPNERF and one ray a block: 5.9 MiB, where the compiler
+    for a v5e asks 4.5-5.0 MiB; at 4 rays, with the per-ray blocks of a
+    512-ray tile, 13.3 MiB where it asks 9.3."""
     n = cfg.n_coarse
     cone_cols = 8 * _row_bytes(3)
     return (kernel_weight_vmem_bytes(cfg, quantized)
@@ -447,10 +494,9 @@ def pick_ray_tile_two_pass(cfg: NerfConfig,
     ``plcore_resident_weight_bytes``). Powers of two only, so any pow2
     ray batch is tiled without padding."""
     budget = _budget(cfg, vmem_budget_bytes)
-    block = pick_ray_block(cfg.n_coarse + cfg.n_fine)
     return _largest_tile(
-        lambda rt: two_pass_vmem_bytes(cfg, rt, _ray_block(rt, block),
-                                       quantized) <= budget, 512)
+        lambda rt: two_pass_vmem_bytes(
+            cfg, rt, pick_two_pass_block(cfg, rt), quantized) <= budget, 512)
 
 
 def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
@@ -471,7 +517,7 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
     and skip their fine pass. ``rt``/``block``: ray tile per grid step
     and rays per inner-loop step (defaults: off the chip the whole batch,
     up to 2048 rays, as one block — the interpreter has no VMEM; on the
-    chip the VMEM model's tile and ``pick_ray_block``). Returns
+    chip the VMEM model's tile and ``pick_two_pass_block``). Returns
     {rgb, rgb_coarse, acc, acc_coarse, depth}, each trimmed to R rays;
     white background is the caller's composite.
 
@@ -489,8 +535,9 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
               pick_ray_tile_two_pass(cfg, vmem_budget_bytes, quantized))
     rt = min(rt, _rup(R, 8))
     if block is None:
-        block = rt if it else pick_ray_block(cfg.n_coarse + cfg.n_fine)
-    block = _ray_block(rt, block)
+        block = rt if it else pick_two_pass_block(cfg, rt)
+    block = min(block, rt)
+    _RAY_BLOCK.set(block)
     Rp = _rup(R, rt)
     if Rp != R:
         padn = Rp - R

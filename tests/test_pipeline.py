@@ -180,7 +180,7 @@ def test_vmem_budget_scales_ray_tile():
     cfg = tiny()
     small = kops.pick_ray_tile(cfg, cfg.n_samples,
                                vmem_budget_bytes=1 << 20)
-    big = kops.pick_ray_tile(cfg, cfg.n_samples)          # cfg default 16 MB
+    big = kops.pick_ray_tile(cfg, cfg.n_samples)          # cfg default 64 MB
     assert small <= big
     assert big <= 128
     # budget flows from the config knob

@@ -9,8 +9,10 @@ compiles it with the TPU compiler installed here — no chip needed. That
 is what Mosaic refuses and interpret mode accepts: rank-1 blocks, ops
 without a Mosaic lowering, unaligned dynamic slices, and scoped VMEM
 beyond the limit the kernel is given (the VMEM model's own estimate, so
-a model that under-counts fails here). Nothing runs, so no result is
-checked; the CPU parity tests hold the same kernels to the reference.
+a model that under-counts fails here), at the ray block the two-pass
+kernels step by on the chip (``pick_two_pass_block``). Nothing runs, so
+no result is checked; the CPU parity tests hold the same kernels to the
+reference.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -139,6 +141,12 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, args = CASES[name](one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if name.startswith("two_pass"):
+        # built at the two-pass rule's block (4 rays at CONFIG and at
+        # MIPNERF), with the VMEM model's figure at that block as limit
+        cfg = MIPNERF if "cone" in name else CONFIG
+        rt = kops.pick_ray_tile_two_pass(cfg, quantized="rmcm" in name)
+        assert kops._RAY_BLOCK.value == kops.pick_two_pass_block(cfg, rt)
 
 
 def test_vmem_model_counts_the_packed_layout():

@@ -255,9 +255,10 @@ def test_two_pass_ray_tile_accounts_for_both_nets():
 
 
 def test_ray_block_divides_tile():
-    """The kernels' inner loop steps by a block that divides the tile and
-    is one ray or a multiple of 8 (Mosaic loads 2 or 4 rows at a dynamic
-    offset only from arrays at most 128 lanes wide)."""
+    """The one-pass kernel's inner loop steps by a block that divides the
+    tile and is one ray or a multiple of 8 (Mosaic loads 2 or 4 rows at a
+    dynamic offset only from arrays at most 128 lanes wide, and its
+    (rt, N) sample blocks are wider)."""
     assert kops.pick_ray_block(192) == 1          # CONFIG: 64 + 128
     assert kops.pick_ray_block(64) == 8
     assert kops.pick_ray_block(32) == 16          # tiny(): 16 + 16
@@ -270,3 +271,72 @@ def test_ray_block_divides_tile():
         for want in (1, 8, 16, 64):
             g = kops._ray_block(rt, want)
             assert rt % g == 0 and (g == 1 or g % 8 == 0)
+
+
+def test_two_pass_block_rule():
+    """The two-pass kernels' block: the largest power of two dividing rt
+    whose rays' widest pass fits the row budget, from shapes alone (no
+    block of 2 or 4 is ruled out: their per-ray blocks are narrow), and
+    the published widths keep 512-ray tiles under the default budget."""
+    from dataclasses import replace
+    from repro.configs.nerf_icarus import CONFIG, MIPNERF
+    assert kops.pick_two_pass_block(CONFIG, 512) == 4       # 4 x 192 rows
+    assert kops.pick_two_pass_block(MIPNERF, 512) == 4      # 4 x 128 rows
+    assert kops.pick_two_pass_block(tiny(), 512) == 16      # 16 x 32 rows
+    assert kops.pick_ray_block(192) == 1                    # one-pass kept
+    # the rule reads shapes, never the VMEM budget
+    assert kops.pick_two_pass_block(
+        replace(CONFIG, kernel_vmem_budget_mb=1.0), 512) == 4
+    for cfg in (CONFIG, MIPNERF, tiny()):
+        for rt in (8, 24, 120, 512):
+            g = kops.pick_two_pass_block(cfg, rt)
+            assert rt % g == 0 and g & (g - 1) == 0
+    assert kops.pick_two_pass_block(tiny(), 24) == 8
+    for cfg in (CONFIG, MIPNERF):
+        for quantized in (False, True):
+            assert kops.pick_ray_tile_two_pass(cfg,
+                                               quantized=quantized) == 512
+
+
+@pytest.fixture(scope="module")
+def cone_setup():
+    from repro.configs.nerf_icarus import tiny_mip
+    cfg = tiny_mip()
+    params = init_params(plcore_decls(cfg), jax.random.PRNGKey(0), "float32")
+    c2w = R.pose_spherical(30.0, -20.0, 4.0)
+    ro, rd = R.camera_rays(c2w, 16, 16, 14.4)
+    radii = jnp.asarray(R.pixel_radii(16, 16, 14.4).reshape(-1, 1))
+    return cfg, params, ro, rd, radii
+
+
+# the coarse pass differs by f32 rounding alone; the fine samples move
+# with the coarse weights' last bits through the importance resample
+BLOCK_TOL = {"rgb_coarse": 1e-6, "acc_coarse": 1e-6, "rgb": 1e-4,
+             "acc": 1e-4, "depth": 1e-3}
+
+
+@pytest.mark.parametrize("block", [2, 4])
+@pytest.mark.parametrize("kind", ["nerf", "cone"])
+def test_two_pass_blocks_match_one_block(kind, block, setup, cone_setup):
+    """Stepping a 16-ray tile in blocks of 2 or 4 rays, as the chip does
+    at the published widths, renders what the tile as one block does:
+    each ray's rows meet the same contractions, only the rows per
+    contraction change."""
+    if kind == "nerf":
+        cfg, params, ro, rd = setup
+        radii, nets = None, ("coarse", "fine")
+    else:
+        cfg, params, ro, rd, radii = cone_setup
+        radii, nets = radii[:32], ("coarse",)
+    o, d = ro.reshape(-1, 3)[:32], rd.reshape(-1, 3)[:32]
+    packed = {n: kops.stack_plcore_weights(cfg, params[n], None)
+              for n in nets}
+    whole = kops.fused_render_two_pass(cfg, packed, o, d, rt=16, block=16,
+                                       radii=radii)
+    got = kops.fused_render_two_pass(cfg, packed, o, d, rt=16, block=block,
+                                     radii=radii)
+    assert kops._RAY_BLOCK.value == block
+    for key, tol in BLOCK_TOL.items():
+        np.testing.assert_allclose(np.asarray(got[key]),
+                                   np.asarray(whole[key]), rtol=0,
+                                   atol=tol, err_msg=key)
