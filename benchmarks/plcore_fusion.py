@@ -11,13 +11,9 @@ Three reports:
      loop vs. the single-dispatch lax.map pipeline (+ERT) vs. the kernel
      paths — two-dispatch coarse/fine, the one-kernel two-pass chain
      (``two_pass_fused``, ``two_pass_fused_ert`` with per-ray-block
-     skips) and the mesh-sharded-weight variant
-     (``two_pass_fused_sharded``: trunk stacks layer-partitioned over
-     the local device mesh, per-layer all-gather in the program; the
-     ``sharding`` dict records per-device resident MB vs replicated) —
-     full-image wall time at tiny scale. benchmarks/run.py persists this
-     one as BENCH_plcore.json (latest + append-only ``history``) so the
-     perf trajectory is trackable across PRs.
+     skips) — full-image wall time at tiny scale. benchmarks/run.py
+     persists this one as BENCH_plcore.json (latest + append-only
+     ``history``) so the perf trajectory is trackable across PRs.
 """
 from __future__ import annotations
 
@@ -109,18 +105,9 @@ def bench_pipeline(hw: int = None, rays_per_batch: int = 1024,
     n_rays = hw * hw
     n_samples = n_rays * (cfg.n_coarse + cfg.n_coarse + cfg.n_fine)
 
-    from repro.kernels import ops as kops
-    from repro.runtime import sharding as rsh
-    from repro.serving.scene_cache import plcore_nbytes
-
     # kernel engines: weights packed once at load, outside the timed loop
     eng_2d = PackedPlcore(cfg, params, use_kernel=True)
     eng_tp = PackedPlcore(cfg, params, use_kernel=True, fuse_two_pass=True)
-    # mesh-sharded residency over the local devices (a 1-device CI box
-    # degrades to replicated: the variant then times the gather no-ops)
-    mesh = rsh.plcore_mesh()
-    eng_sh = PackedPlcore(cfg, params, use_kernel=True, fuse_two_pass=True,
-                          shard_mesh=mesh)
 
     # adaptive (ASDR) variant on the canonical mixed empty-space scene:
     # same param draw with the sigma-head bias shifted -0.5, which carves
@@ -154,24 +141,11 @@ def bench_pipeline(hw: int = None, rays_per_batch: int = 1024,
             ro, rd, rays_per_batch=rays_per_batch),
         "two_pass_fused_ert": lambda: eng_tp.render_image(
             ro, rd, rays_per_batch=rays_per_batch, ert_eps=ert_eps),
-        "two_pass_fused_sharded": lambda: eng_sh.render_image(
-            ro, rd, rays_per_batch=rays_per_batch),
         "two_pass_fused_adaptive": lambda: eng_ad.render_image(
             ro, rd, rays_per_tile=rays_per_batch),
     }
-    n_shards = rsh.plcore_shard_count(mesh, cfg.trunk_layers)
     out = {"hw": hw, "rays": n_rays, "samples": n_samples,
            "rays_per_batch": rays_per_batch, "ert_eps": ert_eps,
-           "sharding": {
-               "devices": int(mesh.size), "weight_shards": n_shards,
-               "resident_mb_per_device": round(
-                   plcore_nbytes(eng_sh) / (1 << 20), 4),
-               "resident_mb_replicated": round(
-                   plcore_nbytes(eng_tp) / (1 << 20), 4),
-               "resident_model_mb_per_device": round(
-                   2 * kops.plcore_resident_weight_bytes(cfg, n_shards)
-                   / (1 << 20), 4),
-           },
            "variants": {}}
     # Interleaved rounds + MIN wall time per variant: this container's
     # cores are shared, so contention bursts poison means and medians;
@@ -206,8 +180,6 @@ def bench_pipeline(hw: int = None, rays_per_batch: int = 1024,
         v["seed_loop"]["wall_s"] / v["two_pass_fused"]["wall_s"], 2)
     out["speedup_two_pass_ert_vs_seed"] = round(
         v["seed_loop"]["wall_s"] / v["two_pass_fused_ert"]["wall_s"], 2)
-    out["speedup_two_pass_sharded_vs_seed"] = round(
-        v["seed_loop"]["wall_s"] / v["two_pass_fused_sharded"]["wall_s"], 2)
     out["speedup_adaptive_vs_two_pass"] = round(
         v["two_pass_fused"]["wall_s"]
         / v["two_pass_fused_adaptive"]["wall_s"], 2)

@@ -5,15 +5,12 @@
 
 Prints ``name,us_per_call,derived`` CSV lines. The ``fusion`` suite
 persists its serving-pipeline comparison (seed tile loop vs single
-dispatch vs kernel paths vs the mesh-sharded-weight variant: wall_s /
-rays_per_s / samples_per_s, plus the ``sharding`` residency dict), and
-the ``serving`` suite its multi-tenant engine numbers (req/s, p50/p95/
-p99 latency split into queueing vs service, dispatch savings, cache hit
-rate, the depth>=2 pipelined-executor pass, and a sharded-resident pass
-with routed-vs-unrouted gather accounting — under the ``serving`` key),
-into ``BENCH_plcore.json`` at the
-repo root: the top-level fields are
-the LATEST run, and the append-only ``history`` list (git SHA, date,
+dispatch vs kernel paths: wall_s / rays_per_s / samples_per_s), and the
+``serving`` suite its multi-tenant engine numbers (req/s, p50/p95/p99
+latency split into queueing vs service, dispatch savings, cache hit
+rate, the depth>=2 pipelined-executor pass — under the ``serving``
+key), into ``BENCH_plcore.json`` at the repo root: the top-level fields
+are the LATEST run, and the append-only ``history`` list (git SHA, date,
 plus whichever suites ran) records every canonical-scale run so the
 cross-PR perf trajectory survives re-runs instead of being overwritten.
 """

@@ -9,29 +9,15 @@ through ``PackedPlcore.render_image`` as the sequential baseline, so
 the engine's scheduling win (not just the kernel's) is what the number
 isolates.
 
-Two more interleaved passes cover the scheduler/executor split:
+One more interleaved pass covers the scheduler/executor split:
 
 * ``pipeline``: the SAME trace at ``pipeline_depth >= 2`` (env
   ``BENCH_SERVING_DEPTH``, default 2) next to the depth=1 numbers — the
   double-buffered executor's req/s + latency vs the synchronous loop,
   persisted per PR so the async-dispatch trajectory is tracked like the
   kernel one.
-* ``sharding``: the trace through a cache whose residents are
-  mesh-sharded (``PackedPlcore(..., shard_mesh=...)`` — trunk stacks
-  layer-partitioned over the local devices), unrouted AND
-  ``route_by_shard``: per-device resident MB per scene (the
-  capacity-scaling quantity the SceneCache budgets against) plus the
-  engine's owner-map gather accounting (``plcore_gather_count`` /
-  ``_bytes``) — the cross-device weight-traffic quantity routing
-  shrinks.
-* ``percell``: the routed trace again with ``percell_dispatch=True`` —
-  each tile runs a program compiled for its home cell's devices only,
-  remote layers staged into the cell ONCE per (scene, cell) instead of
-  gathered per dispatch. Reports the per-cell dispatch/concurrency
-  split, the one-time stage cost next to the per-dispatch gather cost
-  it replaces, and req/s vs the SPMD routed engine on the same trace.
 
-A fourth pass covers the fault-tolerance layer:
+A second pass covers the fault-tolerance layer:
 
 * ``robustness``: the SAME trace under the canonical seeded chaos plan
   (``FaultConfig.chaos(seed=0)`` — injected dispatch errors, corrupted
@@ -41,7 +27,7 @@ A fourth pass covers the fault-tolerance layer:
   redispatches). Deterministic in the seed, so the persisted history
   shows the recovery surface shifting across PRs, not noise.
 
-A fifth pass covers the multi-host fabric:
+A third pass covers the multi-host fabric:
 
 * ``multihost``: the SAME trace through a 2-host ``ClusterEngine``
   (per-host SceneCache + TileExecutor behind the global scheduler) with
@@ -68,11 +54,9 @@ from repro.configs.nerf_icarus import tiny
 from repro.core.pipeline import PackedPlcore
 from repro.core.plcore import plcore_decls
 from repro.models.params import init_params
-from repro.runtime import sharding as rsh
 from repro.serving import FaultConfig, FaultPlan, RenderEngine, SceneCache
 from repro.serving import loadgen
 from repro.serving.cluster import ClusterEngine, HostEvent, split_devices
-from repro.serving.scene_cache import plcore_nbytes
 
 
 def _warm(cache, scene_ids, hw_mix, tile_rays):
@@ -112,24 +96,11 @@ def run() -> dict:
     from repro.data import rays as R
     _warm(cache, scene_ids, hw_mix, tile_rays)
 
-    # sharded-resident pass setup: same trace, cache residents layer-
-    # partitioned over the local device mesh (1-device CI box: replicated
-    # fallback, the run then prices the gather no-ops + per-device
-    # accounting)
-    from repro.kernels import ops as kops
-    mesh = rsh.plcore_mesh()
-    n_shards = rsh.plcore_shard_count(mesh, cfg.trunk_layers)
-    cache_sh = SceneCache(
-        lambda sid: PackedPlcore(cfg, param_sets[sid], shard_mesh=mesh),
-        capacity_mb=256.0)
-    _warm(cache_sh, scene_ids, hw_mix, tile_rays)
-
     # interleaved rounds + best (min-wall) per pass — the fusion suite's
     # rationale: on a shared CI box, back-to-back passes record
     # contention bursts as signal; interleaving + min compares the
     # engine variants and the sequential baseline on equal footing
-    reps, reps_pl, reps_sh, reps_sh_rt, seq_walls = [], [], [], [], []
-    reps_pc = []
+    reps, reps_pl, seq_walls = [], [], []
     for _ in range(2):
         engine = RenderEngine(cache, tile_rays=tile_rays, pipeline_depth=1)
         reps.append(loadgen.run_trace(engine, trace, mode="closed",
@@ -148,33 +119,8 @@ def run() -> dict:
                                  pipeline_depth=depth)
         reps_pl.append(loadgen.run_trace(engine_pl, trace, mode="closed",
                                          concurrency=4))
-        engine_sh = RenderEngine(cache_sh, tile_rays=tile_rays,
-                                 pipeline_depth=1)
-        reps_sh.append(loadgen.run_trace(engine_sh, trace, mode="closed",
-                                         concurrency=4))
-        # sharded + owner-map routing (and the pipelined executor):
-        # gather accounting is deterministic, timing rides the rounds
-        engine_sh_rt = RenderEngine(cache_sh, tile_rays=tile_rays,
-                                    pipeline_depth=depth,
-                                    route_by_shard=True)
-        reps_sh_rt.append(loadgen.run_trace(engine_sh_rt, trace,
-                                            mode="closed", concurrency=4))
-        # per-cell dispatch: same routed trace, each tile compiled for
-        # its home cell only. Stage counters are per-engine but the
-        # (scene, cell) views cache on the resident PackedPlcore, so the
-        # FIRST round's engine pays (and reports) the one-time staging
-        engine_pc = RenderEngine(cache_sh, tile_rays=tile_rays,
-                                 pipeline_depth=depth,
-                                 route_by_shard=True,
-                                 percell_dispatch=True)
-        reps_pc.append((loadgen.run_trace(engine_pc, trace, mode="closed",
-                                          concurrency=4), engine_pc))
     rep = min(reps, key=lambda r: r["wall_s"])
     rep_pl = min(reps_pl, key=lambda r: r["wall_s"])
-    rep_sh = min(reps_sh, key=lambda r: r["wall_s"])
-    rep_sh_rt = min(reps_sh_rt, key=lambda r: r["wall_s"])
-    rep_pc = min((r for r, _ in reps_pc), key=lambda r: r["wall_s"])
-    pc_report = reps_pc[0][1].percell_report() or {}
     seq_wall = min(seq_walls)
 
     # robustness pass: same trace, canonical chaos plan, COLD wrapped
@@ -321,59 +267,6 @@ def run() -> dict:
             "speedup_vs_depth1": round(rep["wall_s"] / rep_pl["wall_s"], 2)
             if rep_pl["wall_s"] else None,
         },
-        "sharding": {
-            "devices": int(mesh.size),
-            "weight_shards": n_shards,
-            "req_per_s": rep_sh["req_per_s"],
-            # owner-map routing: modeled remote-layer gathers per trace,
-            # unrouted worst case vs home-cell-routed (engine stats)
-            "gather_layers_unrouted":
-                rep_sh["engine"]["plcore_gather_count"],
-            "gather_layers_routed":
-                rep_sh_rt["engine"]["plcore_gather_count"],
-            "gather_mb_unrouted": round(
-                rep_sh["engine"]["plcore_gather_bytes"] / (1 << 20), 3),
-            "gather_mb_routed": round(
-                rep_sh_rt["engine"]["plcore_gather_bytes"] / (1 << 20), 3),
-            "req_per_s_routed": rep_sh_rt["req_per_s"],
-            # measured as deployed: sharded residents hold raw heads +
-            # the layer-sharded trunk stacks, the replicated baseline
-            # raw params only — a layout difference (128-row stack
-            # padding) on top of the sharding one
-            "resident_mb_per_scene": round(
-                plcore_nbytes(cache_sh.get(scene_ids[0])) / (1 << 20), 4),
-            "resident_mb_per_scene_replicated": round(
-                plcore_nbytes(cache.get(scene_ids[0])) / (1 << 20), 4),
-            # analytic, layout-matched pair: the SAME packed layout at
-            # n_shards vs 1 — isolates what sharding alone buys
-            "resident_model_mb_per_scene": round(
-                2 * kops.plcore_resident_weight_bytes(cfg, n_shards)
-                / (1 << 20), 4),
-            "resident_model_mb_replicated": round(
-                2 * kops.plcore_resident_weight_bytes(cfg, 1)
-                / (1 << 20), 4),
-        },
-        # per-cell dispatch vs the SPMD routed engine on the same trace:
-        # per-cell concurrency split + the once-per-(scene, cell) stage
-        # cost next to the per-dispatch gather cost it replaces
-        "percell": {
-            "req_per_s": rep_pc["req_per_s"],
-            "req_per_s_spmd_routed": rep_sh_rt["req_per_s"],
-            "cells": pc_report.get("cells", {}),
-            "cells_active": pc_report.get("cells_active", 0),
-            "percell_tiles": pc_report.get("percell_tiles", 0),
-            "stage_events": pc_report.get("stage_events", 0),
-            "stage_layers": pc_report.get("stage_layers", 0),
-            "stage_mb": round(pc_report.get("stage_bytes", 0) / (1 << 20),
-                              3),
-            # per-dispatch remote-layer traffic under percell (cells
-            # execute from staged local copies — must be 0) vs what the
-            # SPMD routed engine gathers every dispatch
-            "gather_layers_per_dispatch":
-                rep_pc["engine"]["plcore_gather_count"],
-            "gather_layers_spmd_routed":
-                rep_sh_rt["engine"]["plcore_gather_count"],
-        },
         # the fault-tolerance surface under the canonical chaos plan:
         # goodput + status counts + the recovery-ladder accounting
         # (RenderEngine.robustness schema, see docs/benchmarks.md)
@@ -473,25 +366,14 @@ def run() -> dict:
     emit("serving/req_per_s", 0.0, f"req_per_s={out['req_per_s']}")
     emit("serving/pipelined_req_per_s", 0.0,
          f"depth{depth}_req_per_s={out['pipeline']['req_per_s']}")
-    emit("serving/sharded_req_per_s", 0.0,
-         f"req_per_s={out['sharding']['req_per_s']}")
     emit("serving/latency_p50_ms", out["latency_ms"]["p50"],
          f"p99={out['latency_ms']['p99']}")
     emit("serving/queueing_p50_ms", out["queueing_ms"]["p50"],
          f"service_p50={out['service_ms']['p50']}")
     emit("serving/dispatch_savings", 0.0,
          f"{out['dispatches']}_vs_{out['dispatch_baseline']}")
-    emit("serving/gather_layers", 0.0,
-         f"routed_{out['sharding']['gather_layers_routed']}"
-         f"_vs_unrouted_{out['sharding']['gather_layers_unrouted']}")
     emit("serving/speedup_vs_sequential", 0.0,
          f"x{out['speedup_engine_vs_sequential']}")
-    pc = out["percell"]
-    emit("serving/percell_req_per_s", 0.0,
-         f"req_per_s={pc['req_per_s']}_cells={pc['cells_active']}"
-         f"_stage_layers={pc['stage_layers']}"
-         f"_gathers={pc['gather_layers_per_dispatch']}"
-         f"_vs_spmd_{pc['gather_layers_spmd_routed']}")
     rb = out["robustness"]
     emit("serving/chaos_goodput", 0.0,
          f"goodput={rb['goodput']}_retries={rb['tile_retries']}"

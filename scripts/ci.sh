@@ -51,33 +51,6 @@ python -m repro.launch.serve --mode engine --scenes 3 --requests 9 \
     --hw-mix 12,16 --tile-rays 128 --loop closed --seed 0 \
     --pipeline-depth 3 --check
 
-echo "== routed sharded engine smoke (8 fake CPU devices, depth 2) =="
-# mesh-sharded weight residency + shard-owner tile routing + pipelined
-# executor: 8 fake host devices, trunk stacks 4-way layer-sharded (tiny
-# cfg has 4 trunk layers); --check asserts the split engaged
-# (weight_shards > 1), depth-2 bit-identity vs depth 1, and that routing
-# strictly reduced the engine's plcore_gather_count vs an unrouted rerun
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m repro.launch.serve --mode engine --scenes 3 --requests 9 \
-    --hw-mix 12,16 --tile-rays 128 --loop closed --seed 0 \
-    --shard-weights --shard-devices 4 --route-by-shard \
-    --pipeline-depth 2 --check
-
-echo "== per-cell dispatch smoke (8 fake CPU devices, 4 cells, depth 2) =="
-# per-device tile execution: each routed tile runs a program compiled
-# for its home cell only, remote trunk layers staged into the cell once
-# per (scene, cell). --shard-devices 4 spreads the 3 scenes' home cells
-# over >= 2 distinct cells (crc32 % 4 -> [0, 2, 0]; a 2-cell mesh maps
-# them all to cell 0 and the concurrency gate below would be vacuous).
-# --check asserts >= 1 per-cell tile ran, >= 1 staging was paid, the
-# framebuffers are BIT-IDENTICAL to a mesh-wide SPMD rerun, and >= 2
-# cells each reached max_in_flight >= 1 (genuine cross-cell concurrency)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m repro.launch.serve --mode engine --scenes 3 --requests 9 \
-    --hw-mix 12,16 --tile-rays 128 --loop closed --seed 0 \
-    --shard-weights --shard-devices 4 --route-by-shard \
-    --percell-dispatch --pipeline-depth 2 --check
-
 echo "== chaos smoke (seeded fault injection through the engine) =="
 # fixed-seed chaos plan (injected dispatch errors, corrupted tiles,
 # loader failures, stragglers) over the deterministic closed-loop trace;
@@ -90,17 +63,18 @@ python -m repro.launch.serve --mode engine --scenes 3 --requests 9 \
     --inject-faults --fault-seed 0 --check
 
 echo "== 2-host cluster chaos smoke (8 fake devices split 4+4, host kill) =="
-# multi-host fabric: two per-host executors + caches over 4-device
-# sub-meshes behind the global scheduler; host 1 (the residency-affinity
-# winner for this seed) is killed at global dispatch 6 with depth-2
-# pipelining, so in-flight tiles MUST fail over. --check fails the run
-# unless every submit reached exactly one terminal status, goodput
-# >= 0.75, >= 1 host kill fired, >= 1 tile was redispatched cross-host,
-# and every ok request is BIT-IDENTICAL to a clean single-host rerun
+# multi-host fabric: two per-host executors + caches, each a replica on
+# its own device group's first device, behind the global scheduler; host
+# 1 (the residency-affinity winner for this seed) is killed at global
+# dispatch 6 with depth-2 pipelining, so in-flight tiles MUST fail over.
+# --check fails the run unless every submit reached exactly one terminal
+# status, goodput >= 0.75, >= 1 host kill fired, >= 1 tile was
+# redispatched cross-host, and every ok request is BIT-IDENTICAL to a
+# clean single-host rerun
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m repro.launch.serve --mode engine --scenes 3 --requests 10 \
     --hw-mix 12,16 --tile-rays 128 --loop closed --seed 0 \
-    --hosts 2 --shard-weights --shard-devices 4 --host-kill "1:@6" \
+    --hosts 2 --host-kill "1:@6" \
     --pipeline-depth 2 --check
 
 echo "== observability chaos smoke (trace + metrics export, span-chain gate) =="

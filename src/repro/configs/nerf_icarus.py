@@ -68,12 +68,10 @@ class NerfConfig:
     # compiler is handed that model's figure as its scoped limit. Half of
     # a TPU v5e's 128 MiB of VMEM (its default scoped limit is 16 MiB),
     # the rest left to what the model does not count. The one-kernel
-    # two-pass path pins BOTH networks' gathered weight stacks as the
-    # working set every grid step plus one ray block's scratch (26.5 MiB
-    # modelled at CONFIG's block of 4 rays, above the 16 MiB default);
-    # the per-ray in/out blocks take the rest.
-    # Mesh-sharding the weights shrinks the HBM-resident footprint, not
-    # this working set.
+    # two-pass path pins BOTH networks' weight stacks as the working set
+    # every grid step plus one ray block's scratch (26.5 MiB modelled at
+    # CONFIG's block of 4 rays, above the 16 MiB default); the per-ray
+    # in/out blocks take the rest.
     kernel_vmem_budget_mb: float = 64.0
     # how the Pallas kernels run: None compiles them through Mosaic when
     # JAX's first device is a TPU and interprets them elsewhere; False

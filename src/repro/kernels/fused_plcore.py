@@ -59,15 +59,10 @@ HBM traffic per ray (f32 words), N = n_coarse + n_fine samples:
                             pinned (1, Nc) row       + acc, acc_c, depth (3)
 
 VMEM (``ops.two_pass_vmem_bytes``): BOTH networks' weight stacks stay
-resident every grid step as the GATHERED working set (single-buffered:
-their block never moves), plus the double-buffered per-ray in/out blocks
-and one ray block's scratch. The same model sizes rt and is handed to the
-compiler as its scoped-VMEM limit. Both entry points take GATHERED
-(replicated) weight layouts: with mesh-sharded residency
-(runtime.sharding) the pipeline all-gathers each trunk layer
-just-in-time inside the same jitted program before the kernel launches —
-sharding shrinks the per-device HBM-resident footprint
-(``ops.plcore_resident_weight_bytes``), never this working set.
+resident every grid step as the working set (single-buffered: their
+block never moves), plus the double-buffered per-ray in/out blocks and
+one ray block's scratch. The same model sizes rt and is handed to the
+compiler as its scoped-VMEM limit.
 """
 from __future__ import annotations
 

@@ -17,7 +17,6 @@ from repro.configs.nerf_icarus import NerfConfig
 from repro.core import rmcm
 from repro.kernels import fused_plcore as _fp
 from repro.kernels import rmcm_matmul as _rm
-from repro.kernels.rmcm_matmul import _unpack_signs
 
 
 def interpret_default() -> bool:
@@ -160,69 +159,7 @@ def stack_plcore_weights(cfg: NerfConfig, params: dict,
     return out
 
 
-def trunk_rows(cfg: NerfConfig, i: int) -> int:
-    """True (un-padded) input-row count of trunk layer i in the stacked
-    layout: layer 0 reads the positional encoding, skip layers [h | PE],
-    everything else the hidden width."""
-    if i == 0:
-        return cfg.pos_enc_dim
-    if i in cfg.skip_at:
-        return cfg.trunk_width + cfg.pos_enc_dim
-    return cfg.trunk_width
-
-
-def unstack_trunk_params(cfg: NerfConfig, packed: dict):
-    """Inverse of ``stack_plcore_weights`` for the trunk: a (gathered)
-    packed layout -> ``(trunk_params, trunk_quant | None)`` holding the
-    EXACT arrays that were stacked — row-padding and sign bit-packing are
-    both lossless, so reconstruction is bit-identical to the originals.
-
-    This is how the XLA (non-kernel) render path consumes mesh-sharded
-    weights: the trunk stacks are the only resident copy; after the
-    per-layer gather (runtime.sharding.gather_plcore_packed) this
-    rebuilds the per-layer param/quant dicts ``nerf_mlp_apply`` expects.
-    For the f32 layout ``trunk_quant`` is None and each layer carries
-    {"w", "b"}; for the RMCM layout the raw f32 trunk weights were never
-    stacked, so layers carry {"b"} only and ``trunk_quant`` holds the
-    mag/sign/scale dicts (the MONB matmuls read those, not "w")."""
-    L = cfg.trunk_layers
-    P = _rup(cfg.trunk_width + cfg.pos_enc_dim, 128)
-    quantized = "trunk_mag" in packed
-    params_t: dict = {}
-    quant_t: Optional[dict] = {} if quantized else None
-    for i in range(L):
-        rows = trunk_rows(cfg, i)
-        b = packed["trunk_b"][i]
-        if quantized:
-            sign = _unpack_signs(packed["trunk_sgn"][i], P)[:rows]
-            quant_t[f"l{i}"] = {"w": {
-                "mag": packed["trunk_mag"][i][:rows],
-                "sign": sign.astype(bool),
-                "scale": packed["trunk_scl"][i]}}
-            params_t[f"l{i}"] = {"b": b}
-        else:
-            params_t[f"l{i}"] = {"w": packed["trunk_w"][i][:rows], "b": b}
-    return params_t, quant_t
-
-
 # ------------------------------------------------------------ fused render --
-def plcore_resident_weight_bytes(cfg: NerfConfig, n_shards: int = 1) -> int:
-    """Per-device HBM bytes of one network's f32 packed layout when the
-    trunk stacks are layer-sharded ``n_shards`` ways (heads stay
-    replicated — every mesh cell reads them every pass). n_shards=1 is
-    exactly the f32 packed layout's bytes: the replicated residency. This
-    is the quantity the serving SceneCache budgets against — resident
-    bytes scale ~1/n_shards with the mesh while the VMEM working set
-    (gathered just-in-time, ``kernel_weight_vmem_bytes``) stays a
-    constant."""
-    W, C, L = cfg.trunk_width, cfg.color_width, cfg.trunk_layers
-    P = _rup(W + cfg.pos_enc_dim, 128)
-    P2 = _rup(W + cfg.dir_enc_dim, 128)
-    trunk = L * P * W + L * W                           # sharded over layers
-    heads = W * W + P2 * C + W * 1 + C * 3 + W + C + 1 + 3
-    return 4 * (trunk // max(1, int(n_shards)) + heads)
-
-
 # VMEM model shared by the tile pickers and the compiler's scoped-VMEM
 # limit (the kernels are compiled with ``vmem_limit_bytes`` = this model,
 # so a model that under-counts fails to compile — tests/test_tpu_compile
@@ -487,12 +424,8 @@ def pick_ray_tile_two_pass(cfg: NerfConfig,
                            quantized: bool = False) -> int:
     """rt for the single-dispatch two-pass kernel: the largest power of
     two (at most 512) whose ``two_pass_vmem_bytes`` fits the budget.
-    BOTH networks' weight stacks occupy VMEM every grid step as the
-    GATHERED working set (with mesh-sharded weights the per-layer
-    all-gather re-materializes full layers before the kernel launches, so
-    the VMEM term does not shrink; only the HBM-resident footprint does,
-    ``plcore_resident_weight_bytes``). Powers of two only, so any pow2
-    ray batch is tiled without padding."""
+    BOTH networks' weight stacks occupy VMEM every grid step. Powers of
+    two only, so any pow2 ray batch is tiled without padding."""
     budget = _budget(cfg, vmem_budget_bytes)
     return _largest_tile(
         lambda rt: two_pass_vmem_bytes(
@@ -508,10 +441,7 @@ def fused_render_two_pass(cfg: NerfConfig, packed: dict, rays_o, rays_d, *,
     """The complete coarse -> importance -> fine render as ONE pallas_call
     per ray tile (deterministic/inference sampling; coarse weights never
     leave VMEM). ``packed``: {"coarse", "fine"} stack_plcore_weights
-    layouts, GATHERED (replicated) — mesh-sharded callers materialize the
-    trunk layers first via runtime.sharding.gather_plcore_packed (the
-    pipeline does this inside the same jitted program, so the gathers
-    overlap the preceding compute). ``ert_eps`` > 0 enables per-ray
+    layouts. ``ert_eps`` > 0 enables per-ray
     early termination inside the kernel. ``alive``: optional (R,) float
     mask — rows with 0 (adaptive trunk-memo hits) enter the kernel dead
     and skip their fine pass. ``rt``/``block``: ray tile per grid step

@@ -19,15 +19,8 @@ Two services:
   transmittance after the coarse pass is < EPS skip the fine-pass MLP;
   under ``--fuse-two-pass`` the kernel skips the fine pass of every ray
   block whose rays all terminated);
-  ``--shard-weights`` shards the packed trunk weight stacks layer-wise
-  over the local device mesh (``--shard-devices`` caps how many devices
-  the mesh uses; the mesh size must divide the trunk layer count for
-  the split to engage — otherwise residency silently stays replicated)
-  — per-device resident weight bytes shrink ~1/n_shards while
-  render programs all-gather each layer just-in-time, bit-identical to
-  the replicated path;
   ``--vmem-budget-mb`` sizes the fused kernel's VMEM budget — under
-  ``--fuse-two-pass`` BOTH networks' gathered weight stacks stay pinned
+  ``--fuse-two-pass`` BOTH networks' weight stacks stay pinned
   as the working set and the per-ray blocks get the remainder;
   ``--tiled`` falls back to the seed per-tile host loop (the benchmark
   baseline — see benchmarks/plcore_fusion.py for the measured gap).
@@ -48,26 +41,16 @@ Two services:
   ``--pipeline-depth N`` gives the executor N in-flight tile slots
   (default: the engine's, 2 on one host — double-buffered async
   dispatch, the host work of tile k-1 overlaps device compute of tile
-  k; 1 under ``--hosts > 1``; depth 1 is the synchronous baseline);
-  ``--route-by-shard`` (with ``--shard-weights``) routes each scene's
-  tiles to the mesh cell owning most of its trunk layers so the modeled
-  per-dispatch weight gathers shrink with locality. ``--check`` exits
-  nonzero unless every request completed, the cache hit rate is > 0,
-  coalescing issued no more dispatches than the per-request baseline,
-  — under ``--shard-weights`` — the layer split actually engaged
-  (weight_shards > 1, catching silent replicated fallback), — with
-  ``--pipeline-depth >= 2`` — the framebuffers are bit-identical to a
-  depth=1 rerun of the same trace, and — with ``--route-by-shard``
-  (which requires ``--shard-weights``) — the unrouted rerun's images
-  match too. The counter gates (pipelining actually held >= 2 tiles in
-  flight; routing strictly reduced plcore_gather_count vs unrouted) are
+  k; 1 under ``--hosts > 1``; depth 1 is the synchronous baseline).
+  ``--check`` exits nonzero unless every request completed, the cache
+  hit rate is > 0, coalescing issued no more dispatches than the
+  per-request baseline, and — with ``--pipeline-depth >= 2`` — the
+  framebuffers are bit-identical to a depth=1 rerun of the same trace.
+  The counter gate (pipelining actually held >= 2 tiles in flight) is
   additionally enforced under ``--loop closed``, where the engine walk
-  is clockless-deterministic. ``--kernel``,
-  ``--fuse-two-pass``, ``--rmcm``, ``--ert``, ``--vmem-budget-mb`` and
-  ``--shard-weights``/``--shard-devices`` apply to the engine's render
-  path exactly as in ``--mode nerf`` — with sharding the cache stores
-  every resident scene's trunk stacks partitioned over the mesh, so
-  ``--cache-mb`` (a per-device budget) holds ~n_shards x more scenes.
+  is clockless-deterministic. ``--kernel``, ``--fuse-two-pass``,
+  ``--rmcm``, ``--ert`` and ``--vmem-budget-mb`` apply to the engine's
+  render path exactly as in ``--mode nerf``.
 
   Fault tolerance (the robustness surface): ``--deadline-ms`` stamps
   every trace request with an SLO deadline (arms admission control +
@@ -86,9 +69,9 @@ Two services:
   the same trace — recovery reconstructs exact pixels or the gate fails.
 
   Multi-host: ``--hosts N`` serves through the ``ClusterEngine`` fabric
-  — N per-host workers (isolated SceneCache + TileExecutor, each over
-  its own sub-mesh when ``--shard-weights`` splits the process devices
-  into per-host groups) behind one global scheduler with heartbeat
+  — N per-host workers (isolated SceneCache + TileExecutor, each a
+  replica on its own group of the process's devices) behind one global
+  scheduler with heartbeat
   health states, cross-host tile failover, per-host scene quarantine
   and aggregate SLO admission. ``--host-kill H:T`` kills host H at
   trace time T seconds — or, deterministically, at global dispatch
@@ -160,15 +143,6 @@ def nerf_energy_uj_per_sample(cfg, fused: bool) -> float:
     return (flops * PJ_PER_FLOP + bytes_per_sample * PJ_PER_BYTE) * 1e-6
 
 
-def _shard_mesh_from_args(args):
-    """``--shard-weights`` -> the canonical 1-D PLCore mesh over the
-    first ``--shard-devices`` local devices (all by default)."""
-    if not args.shard_weights:
-        return None
-    from repro.runtime import sharding as rsh
-    return rsh.plcore_mesh(args.shard_devices)
-
-
 def serve_nerf(args) -> dict:
     from dataclasses import replace
 
@@ -197,10 +171,6 @@ def serve_nerf(args) -> dict:
         raise SystemExit("--fuse-two-pass runs the whole chain in one "
                          "Pallas kernel; it requires --kernel and the "
                          "single-dispatch pipeline (drop --tiled)")
-    shard_mesh = _shard_mesh_from_args(args)
-    if shard_mesh is not None and args.tiled:
-        raise SystemExit("--shard-weights needs the single-dispatch "
-                         "pipeline's gather-aware programs; drop --tiled")
 
     # load-time work: RMCM quantization + kernel weight packing run ONCE
     # here; every render below reuses the packed layout
@@ -208,8 +178,7 @@ def serve_nerf(args) -> dict:
     if not args.tiled:
         engine = PackedPlcore(cfg, params, quant=quant,
                               use_kernel=args.kernel,
-                              fuse_two_pass=args.fuse_two_pass,
-                              shard_mesh=shard_mesh)
+                              fuse_two_pass=args.fuse_two_pass)
     packs_at_load = kops.pack_count()
 
     scene = R.SCENES[args.scene]()
@@ -246,14 +215,6 @@ def serve_nerf(args) -> dict:
         "ert_eps": cfg.ert_eps,
         "weight_packs_since_load": kops.pack_count() - packs_at_load,
     }
-    if shard_mesh is not None:
-        from repro.runtime import sharding as rsh
-        from repro.serving.scene_cache import plcore_nbytes
-        stats["shard_devices"] = int(shard_mesh.size)
-        stats["weight_shards"] = rsh.plcore_shard_count(shard_mesh,
-                                                        cfg.trunk_layers)
-        stats["resident_mb_per_device"] = round(
-            plcore_nbytes(engine) / (1 << 20), 3)
     print(json.dumps(stats, indent=2))
     return stats
 
@@ -285,12 +246,11 @@ def _parse_host_events(args):
 def make_scene_loader(cfg, scene_ids, *, seed: int = 0,
                       scene_bias: float = 0.0, rmcm_weights: bool = False,
                       use_kernel: bool = False, fuse_two_pass: bool = False,
-                      mesh=None, device=None):
+                      device=None):
     """The engine's scene loader: scene id -> ``PackedPlcore`` with one
     synthetic model per id (a distinct param draw from ``seed`` + the
-    id's index stands in for a distinct trained checkpoint). ``mesh``
-    shards each scene's trunk stacks over a (sub-)mesh; ``device`` puts
-    a replicated scene on one device (a replica's own chip)."""
+    id's index stands in for a distinct trained checkpoint). ``device``
+    puts the scene on one device (a replica's own chip)."""
     scene_ids = list(scene_ids)
 
     def load_scene(scene_id: str) -> PackedPlcore:
@@ -309,17 +269,14 @@ def make_scene_loader(cfg, scene_ids, *, seed: int = 0,
             quant = {"coarse": rmcm.quantize_tree(params["coarse"]),
                      "fine": rmcm.quantize_tree(params["fine"])}
         return PackedPlcore(cfg, params, quant=quant, use_kernel=use_kernel,
-                            fuse_two_pass=fuse_two_pass, shard_mesh=mesh,
-                            device=device)
+                            fuse_two_pass=fuse_two_pass, device=device)
     return load_scene
 
 
-def host_devices(device_groups, shard_mesh) -> list:
-    """The device each host's replicated scenes live on: its group's
-    first device. None for every host when weights are mesh-sharded (the
-    per-host sub-meshes place them) or for a single host (JAX's default
-    device)."""
-    if shard_mesh is not None or len(device_groups) < 2:
+def host_devices(device_groups) -> list:
+    """The device each host's scenes live on: its group's first device.
+    None for a single host (JAX's default device)."""
+    if len(device_groups) < 2:
         return [None] * len(device_groups)
     return [g[0] for g in device_groups]
 
@@ -342,32 +299,21 @@ def serve_engine(args) -> dict:
         cfg = replace(cfg, kernel_vmem_budget_mb=args.vmem_budget_mb)
     if args.fuse_two_pass and not args.kernel:
         raise SystemExit("--fuse-two-pass requires --kernel")
-    if args.route_by_shard and not args.shard_weights:
-        raise SystemExit("--route-by-shard routes tiles by sharded-weight "
-                         "ownership; it requires --shard-weights")
-    if args.percell_dispatch and not args.route_by_shard:
-        raise SystemExit("--percell-dispatch executes tiles on their "
-                         "routed home cell; it requires --route-by-shard")
     budget_classes = None
     if args.adaptive_sampling:
-        # ASDR rides the replicated fused-kernel single-cell single-host
-        # path: the probe/memo need the raw replicated trunk params, and
-        # the bit-identity gates need one engine's deterministic memo walk
+        # ASDR rides the fused-kernel single-host path: the bit-identity
+        # gates need one engine's deterministic memo walk
         if not (args.kernel and args.fuse_two_pass):
             raise SystemExit("--adaptive-sampling rides the fused "
                              "two-pass kernel's dead-row skip; it "
                              "requires --kernel --fuse-two-pass")
-        for flag, name in ((args.shard_weights, "--shard-weights"),
-                           (args.route_by_shard, "--route-by-shard"),
-                           (args.percell_dispatch, "--percell-dispatch"),
-                           (args.degrade_on_overload,
+        for flag, name in ((args.degrade_on_overload,
                             "--degrade-on-overload"),
                            (args.inject_faults, "--inject-faults"),
                            (args.hosts > 1, "--hosts > 1")):
             if flag:
-                raise SystemExit(f"--adaptive-sampling is a replicated "
-                                 f"single-host single-cell feature — "
-                                 f"incompatible with {name}")
+                raise SystemExit(f"--adaptive-sampling is a single-host "
+                                 f"feature — incompatible with {name}")
         if args.budget_classes != "auto":
             budget_classes = tuple(
                 int(b) for b in args.budget_classes.split(","))
@@ -381,31 +327,23 @@ def serve_engine(args) -> dict:
     if host_events and args.hosts < 2:
         raise SystemExit("--host-kill/--host-slow need --hosts >= 2 "
                          "(a single-host engine has no pool)")
-    shard_mesh = _shard_mesh_from_args(args)
 
-    # per-host sub-meshes: the process's devices split into contiguous
-    # groups (the xla_force_host_platform_device_count CI idiom), each
-    # host's weight residency sharded over its OWN group only
+    # the process's devices split into contiguous groups (the
+    # xla_force_host_platform_device_count CI idiom); each host is a
+    # replica on its group's device: its scenes and tiles live there, not
+    # all on the default device
     device_groups = split_devices(args.hosts)
-    if shard_mesh is not None and args.hosts > 1:
-        from repro.runtime import sharding as rsh
-        host_meshes = [rsh.plcore_mesh(args.shard_devices, devices=g)
-                       for g in device_groups]
-    else:
-        host_meshes = [shard_mesh] * args.hosts
-    # without sharding each host is a replica on its own device: its
-    # scenes and tiles live there, not all on the default device
-    devices = host_devices(device_groups, shard_mesh)
+    devices = host_devices(device_groups)
 
     scene_ids = [f"scene{i}" for i in range(args.scenes)]
 
-    def make_loader(mesh, device=None):
+    def make_loader(device=None):
         return make_scene_loader(
             cfg, scene_ids, seed=args.seed, scene_bias=args.scene_bias,
             rmcm_weights=args.rmcm, use_kernel=args.kernel,
-            fuse_two_pass=args.fuse_two_pass, mesh=mesh, device=device)
+            fuse_two_pass=args.fuse_two_pass, device=device)
 
-    load_scene = make_loader(shard_mesh)
+    load_scene = make_loader()
     plan = (FaultPlan(FaultConfig.cluster_chaos(args.fault_seed)
                       if args.hosts > 1
                       else FaultConfig.chaos(args.fault_seed))
@@ -422,18 +360,15 @@ def serve_engine(args) -> dict:
         from repro.obs import SpanTracer
         tracer = SpanTracer(sample_every=args.trace_sample)
 
-    def make_engine(depth, routed, *, chaos=False, use_cache=None,
-                    percell=False, adaptive=None):
+    def make_engine(depth, *, chaos=False, use_cache=None, adaptive=None):
         # reference reruns are always CLEAN and SINGLE-HOST: no fault
         # plan (reusing the primary plan would continue its RNG streams,
         # not replay them), a fresh cache with the unwrapped loader, no
-        # host pool — and always SPMD (percell=False), the bit-identity
-        # anchor every multi-host/faulted/per-cell run is compared
-        # against
+        # host pool — the bit-identity anchor every multi-host/faulted
+        # run is compared against
         if adaptive is None:
             adaptive = args.adaptive_sampling
         kw = dict(tile_rays=args.tile_rays, pipeline_depth=depth,
-                  route_by_shard=routed, percell_dispatch=percell,
                   max_queue=args.max_queue,
                   degrade_on_overload=args.degrade_on_overload,
                   faults=plan if chaos else None,
@@ -447,12 +382,11 @@ def serve_engine(args) -> dict:
                       budget_classes=budget_classes,
                       memo_mb=args.memo_mb)
         if chaos and args.hosts > 1:
-            caches = [SceneCache(plan.wrap_loader(make_loader(m, dev))
-                                 if plan else make_loader(m, dev),
+            caches = [SceneCache(plan.wrap_loader(make_loader(dev))
+                                 if plan else make_loader(dev),
                                  capacity_mb=args.cache_mb)
-                      for m, dev in zip(host_meshes, devices)]
-            return ClusterEngine(caches, meshes=host_meshes,
-                                 device_groups=device_groups, **kw)
+                      for dev in devices]
+            return ClusterEngine(caches, device_groups=device_groups, **kw)
         if use_cache is None:
             use_cache = SceneCache(
                 plan.wrap_loader(load_scene)
@@ -460,8 +394,7 @@ def serve_engine(args) -> dict:
                 capacity_mb=args.cache_mb)
         return RenderEngine(use_cache, **kw)
 
-    engine = make_engine(args.pipeline_depth, args.route_by_shard,
-                         chaos=True, percell=args.percell_dispatch)
+    engine = make_engine(args.pipeline_depth, chaos=True)
     deadline_choices = ((None,) if args.deadline_ms is None
                         else (args.deadline_ms / 1e3,))
     trace = loadgen.poisson_trace(
@@ -499,8 +432,6 @@ def serve_engine(args) -> dict:
              "fuse_two_pass": bool(args.fuse_two_pass),
              "ert_eps": cfg.ert_eps,
              "pipeline_depth": args.pipeline_depth,
-             "route_by_shard": bool(args.route_by_shard),
-             "percell_dispatch": bool(args.percell_dispatch),
              "inject_faults": bool(args.inject_faults),
              "hosts": args.hosts,
              "host_events": [f"{e.kind}:{e.host}" for e in host_events],
@@ -510,13 +441,6 @@ def serve_engine(args) -> dict:
     if args.metrics_out:
         stats.setdefault("observability", {})["metrics_out"] = \
             str(args.metrics_out)
-    if shard_mesh is not None:
-        from repro.runtime import sharding as rsh
-        stats["shard_devices"] = int(shard_mesh.size)
-        stats["weight_shards"] = rsh.plcore_shard_count(shard_mesh,
-                                                        cfg.trunk_layers)
-    if args.percell_dispatch:
-        stats["percell"] = engine.percell_report()
     if args.adaptive_sampling:
         stats["adaptive_sampling"] = True
         stats["sampling"] = engine.sampling_report()
@@ -547,24 +471,15 @@ def serve_engine(args) -> dict:
                 raise SystemExit(
                     "engine check: trace integrity FAILED:\n  "
                     + "\n  ".join(trace_integrity["errors"]))
-        if shard_mesh is not None and stats["weight_shards"] <= 1:
-            # --shard-weights degrading to replicated must not pass the
-            # CI gate green: it means the mesh size does not divide the
-            # trunk layer count (or the fake-device flag stopped working)
-            raise SystemExit(
-                f"engine check: --shard-weights fell back to replicated "
-                f"(weight_shards={stats['weight_shards']} on "
-                f"{stats['shard_devices']} devices; the mesh size must "
-                f"divide trunk_layers={cfg.trunk_layers})")
         # gates below rerun the trace on a reference engine and compare
         # framebuffers bit-for-bit (rids align: every run submits in
-        # trace order; per-ray independence makes images depth- and
-        # routing-invariant even when the tile partition differs).
+        # trace order; per-ray independence makes images depth-invariant
+        # even when the tile partition differs).
         # Only requests that ended ``ok`` in BOTH runs are compared —
         # a degraded/partial/rejected image is policy-dependent, not a
         # determinism anchor
-        def rerun_and_compare(depth, routed, label):
-            ref = make_engine(depth, routed)
+        def rerun_and_compare(depth, label):
+            ref = make_engine(depth)
             loadgen.run_trace(ref, trace, mode=args.loop,
                               concurrency=args.concurrency)
             n_cmp = 0
@@ -595,8 +510,7 @@ def serve_engine(args) -> dict:
                                  f"{rb['goodput']} < 0.75")
             # recovery must reconstruct exact pixels: every request that
             # ended ok under faults is bit-identical to a clean rerun
-            rerun_and_compare(args.pipeline_depth, args.route_by_shard,
-                              "clean (no-fault)")
+            rerun_and_compare(args.pipeline_depth, "clean (no-fault)")
 
         if host_events:
             # multi-host gates: the run survived its scheduled host
@@ -611,8 +525,7 @@ def serve_engine(args) -> dict:
             if not args.inject_faults:
                 # (with --inject-faults the identical comparison already
                 # ran above — make_engine refs are single-host either way)
-                rerun_and_compare(args.pipeline_depth, args.route_by_shard,
-                                  "clean single-host")
+                rerun_and_compare(args.pipeline_depth, "clean single-host")
             kills = [e for e in host_events if e.kind == "kill"]
             if kills and cl["host_kills"] < 1:
                 raise SystemExit("engine check: --host-kill armed but no "
@@ -625,8 +538,8 @@ def serve_engine(args) -> dict:
                     "redispatched across hosts (cross_host_redispatches "
                     "= 0) — failover did not engage")
 
-        # the occupancy and gather-count gates compare counters across
-        # runs, which is only deterministic in the clockless closed loop
+        # the occupancy gate compares counters across runs, which is
+        # only deterministic in the clockless closed loop
         # (open-loop arrival timing changes the tile partition run to
         # run); the bit-identity comparisons hold in either mode
         deterministic = args.loop == "closed"
@@ -636,45 +549,7 @@ def serve_engine(args) -> dict:
                                  f"{args.pipeline_depth} never had 2 "
                                  "tiles in flight — async dispatch "
                                  "pipelining did not engage")
-            rerun_and_compare(1, args.route_by_shard,
-                              "synchronous depth=1")
-        if args.route_by_shard and shard_mesh is not None:
-            # routing gate: owner-map tile routing must strictly shrink
-            # the modeled cross-device gather traffic vs the same trace
-            # unrouted (every tile's home cell owns >= 1 trunk layer)
-            unrouted = rerun_and_compare(args.pipeline_depth, False,
-                                         "unrouted")
-            routed_g = stats["engine"]["plcore_gather_count"]
-            unrouted_g = unrouted.stats["plcore_gather_count"]
-            if deterministic and not routed_g < unrouted_g:
-                raise SystemExit(
-                    f"engine check: --route-by-shard did not reduce "
-                    f"plcore_gather_count (routed {routed_g} vs unrouted "
-                    f"{unrouted_g})")
-        if args.percell_dispatch:
-            # per-cell gates: the per-cell programs actually executed
-            # tiles, their pixels are bit-identical to the SPMD routed
-            # path on the same trace, and (closed loop, >= 2 scenes on a
-            # >= 2-cell mesh) at least two cells held tiles in flight —
-            # the multi-scene concurrency the refactor exists for
-            pc = stats.get("percell")
-            if not pc or pc["percell_tiles"] < 1:
-                raise SystemExit("engine check: --percell-dispatch armed "
-                                 "but no tile executed through a "
-                                 "per-cell program")
-            if pc["stage_events"] < 1:
-                raise SystemExit("engine check: per-cell dispatch ran but "
-                                 "no (scene, cell) staging was accounted")
-            rerun_and_compare(args.pipeline_depth, True, "SPMD (mesh-wide)")
-            n_cells = int(shard_mesh.size) if shard_mesh is not None else 1
-            if deterministic and args.scenes >= 2 and n_cells >= 2:
-                engaged = [c for c, v in pc["cells"].items()
-                           if v["max_in_flight"] >= 1]
-                if len(engaged) < 2:
-                    raise SystemExit(
-                        f"engine check: --percell-dispatch with "
-                        f"{args.scenes} scenes on {n_cells} cells engaged "
-                        f"only cells {engaged} — no cross-cell concurrency")
+            rerun_and_compare(1, "synchronous depth=1")
         if args.adaptive_sampling:
             # adaptive gates: every tile went through the adaptive path,
             # the trunk memo actually served hits, every budget class was
@@ -701,11 +576,10 @@ def serve_engine(args) -> dict:
                     f"{sorted(exercised, key=int)} of {n_classes} "
                     f"exercised — the calibration edges starve classes "
                     f"(is --scene-bias set for a mixed scene?)")
-            off1 = make_engine(args.pipeline_depth, args.route_by_shard,
-                               adaptive=False)
+            off1 = make_engine(args.pipeline_depth, adaptive=False)
             loadgen.run_trace(off1, trace, mode=args.loop,
                               concurrency=args.concurrency)
-            off2 = make_engine(1, args.route_by_shard, adaptive=False)
+            off2 = make_engine(1, adaptive=False)
             loadgen.run_trace(off2, trace, mode=args.loop,
                               concurrency=args.concurrency)
             n_cmp = 0
@@ -795,19 +669,8 @@ def build_parser():
     ap.add_argument("--tiled", action="store_true",
                     help="seed per-tile host loop instead of the "
                          "single-dispatch pipeline")
-    ap.add_argument("--shard-weights", action="store_true",
-                    help="shard the packed trunk weight stacks layer-wise "
-                         "over the local device mesh; render programs "
-                         "all-gather each layer just-in-time "
-                         "(bit-identical, ~1/n_shards resident bytes per "
-                         "device)")
-    ap.add_argument("--shard-devices", type=int, default=None,
-                    help="cap how many local devices the weight-sharding "
-                         "mesh uses (default: all; the mesh size must "
-                         "divide the trunk layer count for the split to "
-                         "engage)")
     ap.add_argument("--vmem-budget-mb", type=float, default=None,
-                    help="fused-kernel VMEM budget: the gathered weight "
+                    help="fused-kernel VMEM budget: the weight "
                          "working set (both networks under "
                          "--fuse-two-pass) stays pinned and the "
                          "activation slab gets the remainder")
@@ -833,30 +696,14 @@ def build_parser():
                          "scatter with device compute via jax async "
                          "dispatch, 1 = synchronous dispatch->block->"
                          "scatter (the bit-identity baseline)")
-    ap.add_argument("--route-by-shard", action="store_true",
-                    help="owner-map tile routing (with --shard-weights): "
-                         "pin each scene's tiles to a mesh cell owning "
-                         "the most of its trunk layers, so the modeled "
-                         "per-dispatch weight gathers shrink with "
-                         "locality (engine stats plcore_gather_count/"
-                         "_bytes)")
-    ap.add_argument("--percell-dispatch", action="store_true",
-                    help="per-cell tile execution (with --route-by-shard): "
-                         "each routed tile runs through a program compiled "
-                         "for its home cell's device only, against weights "
-                         "staged onto that cell once per (scene, cell) — "
-                         "dispatches are gather-free and the executor's "
-                         "in-flight budget is counted per cell, so "
-                         "different cells execute different scenes' tiles "
-                         "concurrently (bit-identical to the SPMD path)")
     ap.add_argument("--adaptive-sampling", action="store_true",
                     help="ASDR: per-scene density calibration probe at "
                          "scene load, per-ray fine-sample budget classes "
                          "(tiles coalesce (scene, budget)-pure), and a "
                          "cross-ray trunk memo whose fully-empty resident "
                          "rays enter the fused kernel as dead rows "
-                         "(requires --kernel --fuse-two-pass; replicated "
-                         "single-host single-cell only)")
+                         "(requires --kernel --fuse-two-pass; "
+                         "single-host only)")
     ap.add_argument("--budget-classes", default="auto", metavar="N,N,N",
                     help="comma list of ascending fine-sample budgets for "
                          "the adaptive classes (default 'auto': derived "
@@ -897,11 +744,11 @@ def build_parser():
     ap.add_argument("--hosts", type=int, default=1,
                     help="serve through the multi-host ClusterEngine "
                          "fabric: N per-host workers (isolated "
-                         "SceneCache + TileExecutor, each over its own "
-                         "device-group sub-mesh under --shard-weights) "
-                         "behind one global scheduler with heartbeats, "
-                         "cross-host failover, per-host scene quarantine "
-                         "and aggregate SLO admission")
+                         "SceneCache + TileExecutor, each a replica on "
+                         "its own device group) behind one global "
+                         "scheduler with heartbeats, cross-host failover, "
+                         "per-host scene quarantine and aggregate SLO "
+                         "admission")
     ap.add_argument("--host-kill", action="append", default=[],
                     metavar="HOST:AT",
                     help="kill host HOST at AT seconds from start, or at "

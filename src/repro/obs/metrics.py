@@ -33,10 +33,9 @@ registry (and its exporters) see every mutation. A counter can no
 longer be read before initialization or silently missed by the cluster
 aggregation: both engines initialize from the same schema tuples.
 
-Module-global trace counters (``kernels.ops`` pack/dispatch,
-``runtime.sharding`` gathers) back onto ``global_registry()`` — one
-process-wide registry importable from anywhere without cycles (this
-module imports nothing from ``repro``).
+Module-global trace counters (``kernels.ops`` pack/dispatch) back onto
+``global_registry()`` — one process-wide registry importable from
+anywhere without cycles (this module imports nothing from ``repro``).
 """
 from __future__ import annotations
 
@@ -48,7 +47,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricFamily", "MetricsRegistry",
     "StatsView", "log_buckets", "global_registry", "engine_stats_view",
     "extend_stats_view", "ENGINE_STATS_SCHEMA", "CLUSTER_STATS_SCHEMA",
-    "PERCELL_STATS_SCHEMA", "SAMPLING_STATS_SCHEMA", "EngineMetrics",
+    "SAMPLING_STATS_SCHEMA", "EngineMetrics",
     "TIME_BUCKETS", "DEPTH_BUCKETS",
 ]
 
@@ -230,7 +229,7 @@ _GLOBAL: Optional[MetricsRegistry] = None
 
 def global_registry() -> MetricsRegistry:
     """The process-wide registry backing module-level trace counters
-    (kernel packs/dispatches, sharding gathers). Per-engine counters
+    (kernel packs/dispatches). Per-engine counters
     live in per-engine registries; exporters merge both."""
     global _GLOBAL
     if _GLOBAL is None:
@@ -253,10 +252,6 @@ ENGINE_STATS_SCHEMA = (
     ("requests_completed", "counter", 0,
      "requests in ANY terminal status"),
     ("status_counts", "status", None, "terminal status -> count"),
-    ("plcore_gather_count", "counter", 0,
-     "owner-map remote layer fetches"),
-    ("plcore_gather_bytes", "counter", 0, "... and their bytes"),
-    ("routed_tiles", "counter", 0, "tiles with a home cell assigned"),
     ("max_in_flight", "gauge", 0, "peak executor slot occupancy"),
     ("dispatch_errors", "counter", 0, "dispatch attempts that raised"),
     ("corrupt_tiles", "counter", 0, "drains with non-finite real rays"),
@@ -296,24 +291,9 @@ CLUSTER_STATS_SCHEMA = (
      "summed requeue -> redispatch latency"),
 )
 
-# Per-cell dispatch extension (PR 9): bound via ``extend_stats_view``
-# ONLY when an engine runs with ``percell_dispatch``, so the default
-# serialized stats/report stay byte-identical for every existing run.
-PERCELL_STATS_SCHEMA = (
-    ("percell_tiles", "counter", 0,
-     "tiles executed through a per-cell program"),
-    ("percell_stage_events", "counter", 0,
-     "(scene, cell) one-time weight stagings performed"),
-    ("percell_stage_layers", "counter", 0,
-     "remote trunk layers paid by those stagings"),
-    ("percell_stage_bytes", "counter", 0, "... and their bytes"),
-    ("percell_cells_active", "gauge", 0,
-     "distinct cells that have executed a tile"),
-)
-
 # Adaptive-sampling extension (PR 10): bound via ``extend_stats_view``
-# ONLY when an engine runs with ``adaptive_sampling`` — same
-# byte-compat rationale as PERCELL_STATS_SCHEMA. The gauge key
+# ONLY when an engine runs with ``adaptive_sampling``, so the default
+# serialized stats stay byte-identical. The gauge key
 # ``dead_ray_fraction`` exports as ``engine_dead_ray_fraction``.
 SAMPLING_STATS_SCHEMA = (
     ("adaptive_tiles", "counter", 0,
@@ -445,17 +425,6 @@ class EngineMetrics:
         self.host_state = registry.gauge(
             f"{prefix}_host_state",
             "host lifecycle (0 healthy / 1 suspect / 2 draining / 3 dead)")
-        # labeled per-cell families (percell_dispatch runs): the 2-cell ×
-        # 2-scene concurrency gate reads max_in_flight per cell
-        self.cell_dispatches = registry.counter(
-            f"{prefix}_cell_dispatches_total",
-            "per-cell tiles dispatched through per-cell programs")
-        self.cell_in_flight = registry.gauge(
-            f"{prefix}_cell_in_flight_tiles",
-            "occupied executor slots per home cell")
-        self.cell_max_in_flight = registry.gauge(
-            f"{prefix}_cell_max_in_flight_tiles",
-            "peak executor slot occupancy per home cell")
         # labeled per-budget-class families (adaptive-sampling runs):
         # the budget histogram behind stats["sampling"], exported as
         # {budget_class=...} children through the same Prometheus path

@@ -15,7 +15,6 @@ from dataclasses import dataclass, field, replace
 from typing import Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -170,254 +169,6 @@ def attn_needs_batch_reshard(n_heads: int) -> bool:
     if mesh is None or mesh.shape.get("model", 1) <= 1:
         return False
     return n_heads % mesh.shape["model"] != 0
-
-
-# ------------------------------------------------ PLCore weight sharding --
-# ICARUS keeps whole-model weights resident per PLCore; replicated over a
-# mesh that residency is the binding constraint (weight bytes, not FLOPs —
-# FlexNeRFer/Cicero's memory-traffic argument). The packed trunk stacks
-# (kernels.ops.stack_plcore_weights lays every trunk tensor out as
-# (L, ...) with the layer axis leading) shard LAYER-WISE over the
-# ("pod","data") axes; render programs re-materialize each layer with a
-# per-layer all-gather that XLA's latency-hiding scheduler can overlap
-# with the previous layer's matmul. Sharding is placement only — values
-# never change — so the sharded path renders bit-identical pixels
-# (tests/test_sharded_weights.py holds image, kernel and engine modes to
-# exact equality against the replicated path).
-
-PLCORE_SHARD_AXES: Tuple[str, ...] = ("pod", "data")
-
-
-def plcore_mesh(n_devices: Optional[int] = None,
-                devices: Optional[list] = None) -> Mesh:
-    """1-D ("data",) mesh over the first ``n_devices`` local devices
-    (default: all), or over an explicit ``devices`` group — the
-    multi-host serving fabric hands each host its own contiguous slice
-    of the process's devices so every host's mesh is disjoint. The
-    trunk stacks shard over whichever of ("pod","data") the mesh
-    carries; an axis whose size does not divide the layer count
-    degrades to replicated (``plcore_stack_spec``), so this is always
-    safe to build — a 1-device mesh just replicates."""
-    devs = list(devices) if devices is not None else jax.devices()
-    n = len(devs) if n_devices is None else max(1, min(int(n_devices),
-                                                       len(devs)))
-    return Mesh(np.array(devs[:n]), ("data",))
-
-
-def plcore_stack_spec(mesh: Mesh, n_layers: int) -> P:
-    """PartitionSpec for one (L, ...) layer stack: axis 0 split over the
-    ("pod","data") axes present in the mesh, dropping (replicating) any
-    axis whose accumulated size does not divide L — the same graceful
-    degradation as ``Rules.resolve``."""
-    axes = []
-    for a in PLCORE_SHARD_AXES:
-        if a in mesh.shape:
-            size = int(np.prod([mesh.shape[x] for x in axes + [a]]))
-            if size > 0 and n_layers % size == 0:
-                axes.append(a)
-    if not axes:
-        return P()
-    return P(tuple(axes) if len(axes) > 1 else axes[0])
-
-
-def plcore_shard_count(mesh: Mesh, n_layers: int) -> int:
-    """How many ways ``plcore_stack_spec`` actually splits the layer axis
-    (1 = replicated fallback)."""
-    spec = plcore_stack_spec(mesh, n_layers)
-    if len(spec) == 0 or spec[0] is None:
-        return 1
-    axes = spec[0] if isinstance(spec[0], tuple) else (spec[0],)
-    return int(np.prod([mesh.shape[a] for a in axes]))
-
-
-def _is_stacked(key: str) -> bool:
-    """Keys of the packed layout whose leading axis is the trunk layer
-    stack (trunk_w / trunk_b and the RMCM trunk_mag/sgn/scl)."""
-    return key.startswith("trunk")
-
-
-def shard_plcore_packed(packed: dict, mesh: Mesh) -> dict:
-    """device_put one network's ``stack_plcore_weights`` layout: trunk
-    stacks layer-sharded over the mesh, heads replicated (they are small
-    and every mesh cell reads them every pass)."""
-    out = {}
-    for k, a in packed.items():
-        spec = plcore_stack_spec(mesh, a.shape[0]) if _is_stacked(k) else P()
-        out[k] = jax.device_put(a, NamedSharding(mesh, spec))
-    return out
-
-
-# ------------------------------------------------- PLCore owner map -------
-# ICARUS §5 scales by putting a ray dispatcher in front of many PLCores;
-# which cell a tile lands on decides which weight layers it reads locally
-# vs fetches across the interconnect. The owner map is the dispatcher's
-# view of the layer-sharded residency above: for every mesh cell (device),
-# which trunk layers its HBM holds. The serving scheduler scores candidate
-# tiles/scenes by owner overlap (route-by-shard) and the per-dispatch
-# gather accounting prices only the layers the home cell must fetch
-# REMOTELY — so modeled cross-device weight traffic shrinks with locality,
-# not just residency. (The SPMD emulation still computes mesh-wide and
-# replicates every layer — placement only, pixels bit-identical; the
-# owner map is the traffic model a hardware dispatcher would minimize.)
-
-
-def plcore_owner_table(mesh: Mesh, n_layers: int) -> np.ndarray:
-    """(n_devices, n_layers) bool ownership matrix: entry [c, l] is True
-    when mesh cell ``c`` (flat ``mesh.devices`` order) holds layer ``l``
-    of a ``plcore_stack_spec``-sharded trunk stack in local HBM. A
-    replicated (non-dividing) fallback owns everything everywhere."""
-    spec = plcore_stack_spec(mesh, n_layers)
-    sh = NamedSharding(mesh, spec)
-    devs = list(mesh.devices.flat)
-    pos = {d: i for i, d in enumerate(devs)}
-    table = np.zeros((len(devs), n_layers), bool)
-    for dev, idx in sh.devices_indices_map((n_layers,)).items():
-        table[pos[dev], idx[0]] = True
-    return table
-
-
-def plcore_locality_scores(mesh: Mesh, n_layers: int) -> np.ndarray:
-    """Per-cell routing score: how many trunk layers each mesh cell owns
-    locally. The scheduler routes a tile to an argmax cell — every layer
-    that cell owns is one all-gather the dispatch does not pay."""
-    return plcore_owner_table(mesh, n_layers).sum(axis=1)
-
-
-def plcore_home_cell(mesh: Mesh, n_layers: int, salt: str = "") -> int:
-    """Pick the home cell for one scene's tiles: a cell owning the
-    maximal number of that scene's trunk layers. Ties (the equal-shard
-    common case) break by a stable hash of ``salt`` (scene id), so
-    concurrent scenes spread over the owning cells deterministically —
-    same trace, same routing, every run."""
-    import zlib
-    scores = plcore_locality_scores(mesh, n_layers)
-    ties = np.flatnonzero(scores == scores.max())
-    return int(ties[zlib.crc32(salt.encode()) % len(ties)])
-
-
-def plcore_owned_layer_mask(mesh: Mesh, n_layers: int,
-                            cell: Optional[int] = None) -> np.ndarray:
-    """(n_layers,) bool: layers resident in cell ``cell``'s local HBM
-    (``None`` — no routing decision — owns nothing: every layer is a
-    remote fetch, the unrouted worst case the gather accounting prices)."""
-    if cell is None:
-        return np.zeros(n_layers, bool)
-    return plcore_owner_table(mesh, n_layers)[int(cell)]
-
-
-# Per-layer gather counter — kernels.ops.pack_count trace-time semantics:
-# ticks once per layer per stacked array when a render program TRACES;
-# cached program re-runs tick nothing. Tests pin the just-in-time gather
-# structure (L independent collectives, not one monolithic all-gather)
-# through this counter. Gather BYTES tick alongside with the replicated
-# per-layer bytes — the modeled gathered-layer traffic. Both live in the
-# process-global metrics registry (exporter-visible); accessors unchanged.
-from repro.obs.metrics import global_registry as _obs_registry
-
-_GATHERS = _obs_registry().counter(
-    "plcore_layer_gathers_total",
-    "per-layer all-gather collectives traced")
-_GATHER_BYTES = _obs_registry().counter(
-    "plcore_layer_gather_bytes_total",
-    "modeled replicated bytes of traced layer gathers", unit="bytes")
-
-
-def plcore_gather_count() -> int:
-    return int(_GATHERS.value)
-
-
-def plcore_gather_bytes() -> int:
-    return int(_GATHER_BYTES.value)
-
-
-def gather_plcore_stack(stack, mesh: Mesh):
-    """(L, ...) layer-sharded stack -> replicated, one all-gather PER
-    LAYER: each layer is sliced out and constrained to replicated
-    individually, so XLA sees L independent collectives it can schedule
-    just-in-time — layer i's gather overlaps the layer i-1 matmul —
-    instead of one monolithic all-gather blocking the whole trunk."""
-    repl = NamedSharding(mesh, P())
-    per_layer = int(np.prod(stack.shape[1:])) * stack.dtype.itemsize
-    layers = []
-    for i in range(stack.shape[0]):
-        _GATHERS.inc()
-        _GATHER_BYTES.inc(per_layer)
-        layers.append(jax.lax.with_sharding_constraint(stack[i], repl))
-    return jnp.stack(layers)
-
-
-def gather_plcore_packed(packed: dict, mesh: Mesh) -> dict:
-    """Materialize one network's sharded packed layout for compute:
-    trunk stacks gathered layer-by-layer, replicated heads passed
-    through. Values are bit-identical to the replicated layout."""
-    return {k: gather_plcore_stack(a, mesh) if _is_stacked(k) else a
-            for k, a in packed.items()}
-
-
-# ---------------------------------------------- PLCore per-cell staging --
-# The owner map above is the *traffic model*; per-cell execution (PR 9)
-# makes it the *dataflow*: a routed tile's program compiles for its home
-# cell's device ONLY, reading a staged full-weight copy from that cell's
-# HBM — zero in-program collectives, the ICARUS "nothing goes off-chip"
-# economy at mesh scale. Staging pays the remote layers ONCE per
-# (scene, cell) — the same layers tile_gather_cost prices per dispatch on
-# the SPMD path — and every subsequent dispatch on that cell is local.
-# device_put is placement only, so per-cell pixels stay bit-identical to
-# the SPMD path (tests/test_parity_matrix.py + the 8-fake-device leg pin
-# this).
-
-_STAGES = _obs_registry().counter(
-    "plcore_cell_stage_layers_total",
-    "remote trunk layers staged into a home cell (once per scene+cell)")
-_STAGE_BYTES = _obs_registry().counter(
-    "plcore_cell_stage_bytes_total",
-    "modeled bytes of trunk layers staged into home cells", unit="bytes")
-
-
-def plcore_stage_count() -> int:
-    return int(_STAGES.value)
-
-
-def plcore_stage_bytes() -> int:
-    return int(_STAGE_BYTES.value)
-
-
-def plcore_cell_mesh(mesh: Mesh, cell: int) -> Mesh:
-    """1-device ("data",) sub-mesh over mesh cell ``cell`` (flat
-    ``mesh.devices`` order) — the compile target for that cell's tile
-    programs. A 1-device mesh replicates everything, so all the packed/
-    spec helpers above compose unchanged."""
-    devs = list(mesh.devices.flat)
-    return Mesh(np.array([devs[int(cell)]]), ("data",))
-
-
-def stage_plcore_packed_to_cell(packed: dict, mesh: Mesh, cell: int) -> dict:
-    """Materialize one network's (possibly layer-sharded) packed layout
-    fully resident on cell ``cell``: every array device_put onto the
-    cell's device. For trunk stacks this is the one-time cross-device
-    fetch of the layers the cell does not own — accounted through the
-    ``plcore_cell_stage_*`` counters with the owner map's remote-layer
-    pricing (owned layers are local reads, not traffic). Values are
-    bit-identical to the source layout; only placement changes."""
-    dev = list(mesh.devices.flat)[int(cell)]
-    n_layers_any = None
-    for k, a in packed.items():
-        if _is_stacked(k):
-            n_layers_any = int(a.shape[0])
-            break
-    remote = None
-    if n_layers_any is not None:
-        owned = plcore_owned_layer_mask(mesh, n_layers_any, cell)
-        remote = ~owned
-    out = {}
-    for k, a in packed.items():
-        if _is_stacked(k) and remote is not None:
-            per_layer = int(np.prod(a.shape[1:])) * a.dtype.itemsize
-            n_remote = int(remote[: a.shape[0]].sum())
-            _STAGES.inc(n_remote)
-            _STAGE_BYTES.inc(n_remote * per_layer)
-        out[k] = jax.device_put(a, dev)
-    return out
 
 
 def pspecs(decls, mesh: Mesh, rules: Rules):

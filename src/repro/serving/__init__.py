@@ -8,9 +8,9 @@ three explicit layers (see ``engine``'s module docstring for the
 dataflow):
 
 * ``engine``       — ``TileScheduler`` (queue, priority/sticky policy,
-                     cross-request ray coalescing, shard-locality tile
-                     routing) -> ``TileExecutor`` (double-buffered
-                     async-dispatch slots over jax async dispatch) ->
+                     cross-request ray coalescing) -> ``TileExecutor``
+                     (double-buffered async-dispatch slots over jax
+                     async dispatch) ->
                      ``CompletionSink`` (out-of-order framebuffer
                      scatter), behind the ``RenderEngine`` façade.
 * ``scene_cache``  — LRU of resident ``PackedPlcore`` weight sets so one
@@ -29,8 +29,9 @@ dataflow):
                      loader backoff, straggler redispatch, SLO admission
                      + expiry.
 * ``cluster``      — the multi-host fabric: a ``HostPool`` of isolated
-                     per-host cache+executor workers (each over its own
-                     sub-mesh) behind one global ``ClusterScheduler``;
+                     per-host cache+executor workers (each a replica on
+                     its own device) behind one global
+                     ``ClusterScheduler``;
                      heartbeat health states, cross-host tile failover,
                      per-host scene quarantine with recovery probes,
                      aggregate SLO admission, graceful drain/rejoin.

@@ -4,10 +4,10 @@ ICARUS scales rendering by replicating self-contained PLCores, each
 owning its pipeline end to end (§5); Cicero's corollary is that when
 state is replicable, work is cheaply redirectable. The serving analog:
 a pool of **hosts**, each an isolated ``TileExecutor`` + ``SceneCache``
-over its own sub-mesh (faked in CI by partitioning
+over its own device group (faked in CI by partitioning
 ``xla_force_host_platform_device_count`` devices into per-host groups),
 fronted by ONE global ``ClusterScheduler`` whose placement decision
-folds scene-cache residency and shard locality into the same score.
+folds scene-cache residency and host health into the same score.
 ``ClusterEngine`` keeps the ``RenderEngine`` facade — submit / step /
 drain / take are unchanged, and ``hosts=1`` degenerates to the
 single-host engine every existing test pins.
@@ -26,7 +26,7 @@ Every PR-6 single-host robustness policy gets its cross-host version:
 * **Cross-host failover.** A tile that fails on host A (dispatch raise
   or corrupt drain) is first redispatched synchronously to a DIFFERENT
   healthy host via the executor's ``redispatch_hook`` — bit-exact,
-  because every host gathers the same packed weights — and only when no
+  because every host holds the same packed weights — and only when no
   other host can serve does the PR-6 local retry -> oracle ladder run,
   as the LAST rung. A killed host's in-flight tiles are re-queued and
   re-placed (their rays were already handed out, so re-queueing tiles —
@@ -71,8 +71,8 @@ HOST_STATES = ("healthy", "suspect", "draining", "dead")
 def split_devices(n_hosts: int, devices: Optional[list] = None) -> List[list]:
     """Partition this process's devices into contiguous per-host groups
     — the CI idiom: ``xla_force_host_platform_device_count=8`` fake CPU
-    devices split 4+4 across two emulated hosts, each group backing its
-    own sub-mesh. With fewer devices than hosts every host shares the
+    devices split 4+4 across two emulated hosts, each group backing one
+    replica. With fewer devices than hosts every host shares the
     full list (the degenerate laptop mode: isolation is still exercised
     at the cache/executor layer, just not the device layer)."""
     if n_hosts < 1:
@@ -104,7 +104,7 @@ class HostEvent:
 
 class Host:
     """One pool member: an isolated SceneCache + TileExecutor (over its
-    own sub-mesh) plus the health state the cluster tracks for it."""
+    own device group) plus the health state the cluster tracks for it."""
 
     def __init__(self, host_id: int, cache: SceneCache,
                  executor: "_HostExecutor", mesh=None, devices=None):
@@ -192,15 +192,15 @@ class _HostExecutor(TileExecutor):
     host: Optional[Host] = None
 
     def _attempt(self, tile: _Tile, allow_straggle: bool = True):
-        rgb, cost, extra = super()._attempt(tile, allow_straggle)
+        rgb, extra = super()._attempt(tile, allow_straggle)
         h = self.host
         if h is not None and allow_straggle:
             extra += h.slow_extra_s + h.pending_extra_s
             h.pending_extra_s = 0.0
-        return rgb, cost, extra
+        return rgb, extra
 
-    def _account(self, tile: _Tile, cost: dict) -> None:
-        super()._account(tile, cost)
+    def _account(self, tile: _Tile) -> None:
+        super()._account(tile)
         if self.host is not None:
             self.host.dispatches += 1
             self.host.beat(self._clock())
@@ -241,7 +241,7 @@ class ClusterScheduler(TileScheduler):
       dead once every placeable host has it quarantined.
     * a re-queue lane for tiles abandoned by a killed host, drained
       ahead of fresh coalescing and re-placed (new host, new resident
-      weights, new home cell) without touching request cursors.
+      weights) without touching request cursors.
     """
 
     def __init__(self, pool: HostPool, *, quarantine_probe_tiles: int = 8,
@@ -254,7 +254,6 @@ class ClusterScheduler(TileScheduler):
         self._quarantine: Dict[Tuple[int, str], int] = {}
         self._affinity: Dict[str, int] = {}      # scene -> preferred host
         self._requeue: deque = deque()           # tiles from killed hosts
-        self._home_cells: Dict[Tuple[str, int], int] = {}  # re-keyed/host
         self._placed_host: Optional[Host] = None
 
     def _cone_refusals(self) -> List[str]:
@@ -286,24 +285,6 @@ class ClusterScheduler(TileScheduler):
             if best_key is None or key > best_key:
                 best, best_key = h, key
         return best
-
-    def route_for(self, scene: str, pp, host: Host) -> Optional[int]:
-        """Shard-locality routing, per host: home cells live on a HOST's
-        mesh, so the cache key is (scene, host) — the same scene routes
-        independently on every host's sub-mesh."""
-        if not self.route_by_shard or getattr(pp, "shard_mesh", None) is None:
-            return None
-        key = (scene, host.id)
-        home = self._home_cells.get(key)
-        if home is None:
-            from repro.runtime import sharding as rsh
-            home = rsh.plcore_home_cell(pp.shard_mesh, pp.cfg.trunk_layers,
-                                        salt=scene)
-            self._home_cells[key] = home
-        return home
-
-    def _route(self, scene_id: str, pp) -> Optional[int]:
-        return self.route_for(scene_id, pp, self._placed_host)
 
     # ------------------------------------------------------- admission ----
     def _estimated_queueing_s(self) -> Optional[float]:
@@ -462,7 +443,6 @@ class ClusterScheduler(TileScheduler):
             self._on_scene_loaded(host, tile.scene_id)
             tile.pp = pp
             tile.host_id = host.id
-            tile.home_cell = self.route_for(tile.scene_id, pp, host)
             return tile
         return None
 
@@ -478,8 +458,8 @@ class ClusterScheduler(TileScheduler):
 class ClusterEngine(RenderEngine):
     """The multi-host serving fabric behind the single-host facade.
 
-    ``caches`` is one SceneCache per host (each typically built over its
-    own sub-mesh — ``split_devices`` partitions the process's devices);
+    ``caches`` is one SceneCache per host (each typically loading onto
+    its own device — ``split_devices`` partitions the process's devices);
     everything else matches ``RenderEngine``. submit/take/pending/
     completed/robustness are inherited; step/drain re-route through the
     pool. ``schedule_host_events`` arms deterministic kill / slow /
@@ -495,8 +475,6 @@ class ClusterEngine(RenderEngine):
                  quarantine_probe_tiles: int = 8,
                  tile_rays: int = 512, max_sticky_tiles: int = 64,
                  clock=time.perf_counter, pipeline_depth: int = 1,
-                 route_by_shard: bool = False,
-                 percell_dispatch: bool = False,
                  max_queue: Optional[int] = None,
                  aging_tiles: Optional[int] = None,
                  degrade_on_overload: bool = False,
@@ -517,8 +495,7 @@ class ClusterEngine(RenderEngine):
         super().__init__(
             caches[0], tile_rays=tile_rays,
             max_sticky_tiles=max_sticky_tiles, clock=clock,
-            pipeline_depth=pipeline_depth, route_by_shard=route_by_shard,
-            percell_dispatch=percell_dispatch,
+            pipeline_depth=pipeline_depth,
             max_queue=max_queue, aging_tiles=aging_tiles,
             degrade_on_overload=degrade_on_overload,
             degrade_queue_tiles=degrade_queue_tiles,
@@ -550,7 +527,7 @@ class ClusterEngine(RenderEngine):
                 max_tile_retries=max_tile_retries,
                 retry_backoff_s=retry_backoff_s,
                 check_finite=check_finite, clock=clock,
-                tracer=self.tracer, percell=percell_dispatch)
+                tracer=self.tracer)
             host = Host(i, cache, ex, mesh=mesh_list[i], devices=groups[i])
             ex.host = host
             ex.redispatch_hook = (lambda tile, h=host:
@@ -561,8 +538,7 @@ class ClusterEngine(RenderEngine):
         self.scheduler = ClusterScheduler(
             self.pool, quarantine_probe_tiles=quarantine_probe_tiles,
             cache=caches[0], tile_rays=tile_rays,
-            max_sticky_tiles=max_sticky_tiles,
-            route_by_shard=route_by_shard, stats=self.stats, clock=clock,
+            max_sticky_tiles=max_sticky_tiles, stats=self.stats, clock=clock,
             max_queue=max_queue, aging_tiles=aging_tiles,
             degrade_on_overload=degrade_on_overload,
             degrade_queue_tiles=degrade_queue_tiles,
@@ -738,11 +714,10 @@ class ClusterEngine(RenderEngine):
             if fault is not None and fault["kind"] == "dispatch_error":
                 host.tile_failures += 1
                 return None
-        home = sched.route_for(tile.scene_id, pp, host)
         try:
-            rgb, cost = pp.dispatch_tile(
+            rgb, _ = pp.dispatch_tile(
                 pp.commit(tile.rays_o), pp.commit(tile.rays_d),
-                home_cell=home, coarse_only=tile.degraded)
+                coarse_only=tile.degraded)
             arr = np.asarray(rgb)
         except Exception:
             host.tile_failures += 1
@@ -761,7 +736,7 @@ class ClusterEngine(RenderEngine):
                           scene=tile.scene_id, from_host=failed_host.id,
                           host=host.id)
         tile.prev_host = host.id
-        return arr, cost
+        return arr
 
     # ------------------------------------------------------------- loop ----
     def _dispatch_on(self, host: Host, tile: _Tile, now: float) -> None:

@@ -12,10 +12,7 @@ lever has one home:
   NaN instead of silently reading as black), picks the next scene by
   (priority, FIFO) with sticky-scene grouping, coalesces one fixed-shape
   tile of ``tile_rays`` rays across that scene's pending requests (pad
-  only the tail), and — with ``route_by_shard`` — routes the tile to a
-  *home cell*: the mesh device owning the most of that scene's trunk
-  layers (``runtime.sharding`` owner-map API), so the modeled
-  cross-device weight gathers shrink with locality, not just residency.
+  only the tail).
 * ``TileExecutor`` — the dispatch layer. Keeps up to ``pipeline_depth``
   tiles in flight: ``PackedPlcore.dispatch_tile`` returns an UN-BLOCKED
   device array (jax async dispatch), so the executor dispatches tile k+1
@@ -29,8 +26,7 @@ lever has one home:
   bit-identity anchor CI pins). The
   executor pins each tile's scene in the ``SceneCache`` for the life of
   the slot, so eviction can never drop weights under an in-flight
-  dispatch, and accounts every dispatch's owner-map gather cost into
-  ``stats`` (``plcore_gather_count`` / ``plcore_gather_bytes``).
+  dispatch.
 * ``CompletionSink`` — the output layer. Materializes a drained tile's
   pixels, scatters them to each contributing request's framebuffer and
   completes requests OUT OF ORDER as their last ray lands — semantics
@@ -39,11 +35,8 @@ lever has one home:
 ``RenderEngine`` is the façade wiring the three together behind the same
 ``submit``/``step``/``drain``/``take`` surface as before. Because every
 per-ray op depends only on its own ray, the per-request images are
-bit-identical across pipeline depths and routing choices even when the
-tile partition differs — only throughput and the traffic accounting
-move. Mesh-sharded weight residency still plugs in underneath via the
-``SceneCache`` loader; routing only adds a scheduler-side placement
-decision on top of it.
+bit-identical across pipeline depths even when the tile partition
+differs — only throughput moves.
 
 Fault tolerance
 ---------------
@@ -235,7 +228,6 @@ class _Tile:
     rays_d: np.ndarray
     n_real: int                             # non-pad rays
     radii: Optional[np.ndarray] = None      # (n, 1) cone radii (Mip-NeRF)
-    home_cell: Optional[int] = None         # shard-locality routing
     degraded: bool = False                  # coarse-only program
     budget: Optional[int] = None            # adaptive fine-sample budget
     dead_bucket: bool = False               # rays all hinted-dead: memo
@@ -329,16 +321,16 @@ class AdaptiveSampling:
 class TileScheduler:
     """Layer 1 — policy. Queue, admission control, priority/sticky-scene
     scene pick (with optional deterministic priority aging), overload
-    degradation marking, deadline expiry, tile coalescing, and
-    shard-locality routing. Produces ``_Tile``s; never touches the
-    device. Scene-loader failures are absorbed here: a scene whose
-    ``SceneCache.get`` raises is skipped for the current tile (other
-    scenes keep rendering) and its queued requests are terminated once
-    the cache reports ``max_load_failures`` consecutive real failures."""
+    degradation marking, deadline expiry and tile coalescing. Produces
+    ``_Tile``s; never touches the device. Scene-loader failures are
+    absorbed here: a scene whose ``SceneCache.get`` raises is skipped for
+    the current tile (other scenes keep rendering) and its queued
+    requests are terminated once the cache reports ``max_load_failures``
+    consecutive real failures."""
 
     def __init__(self, cache: SceneCache, *, tile_rays: int,
-                 max_sticky_tiles: int, route_by_shard: bool,
-                 stats: dict, clock, max_queue: Optional[int] = None,
+                 max_sticky_tiles: int, stats: dict, clock,
+                 max_queue: Optional[int] = None,
                  aging_tiles: Optional[int] = None,
                  degrade_on_overload: bool = False,
                  degrade_queue_tiles: int = 8,
@@ -359,7 +351,6 @@ class TileScheduler:
         # residency amortizes, but an early request for another scene
         # can't be starved forever by a stream of same-priority arrivals
         self.max_sticky_tiles = int(max_sticky_tiles)
-        self.route_by_shard = bool(route_by_shard)
         self.stats = stats
         self._clock = clock
         self.max_queue = max_queue
@@ -374,7 +365,6 @@ class TileScheduler:
         self._tile_seq = 0           # deterministic per-engine tile ids
         self._current_scene: Optional[str] = None
         self._sticky_run = 0         # consecutive tiles for current scene
-        self._home_cells: Dict[str, int] = {}   # scene -> routed cell
         self._deadlines_armed = False
         self.completion: Optional["CompletionSink"] = None   # wired by engine
         self.executor: Optional["TileExecutor"] = None       # wired by engine
@@ -537,26 +527,7 @@ class TileScheduler:
             modes.append("adaptive sampling")
         if self.degrade_on_overload:
             modes.append("coarse_only degradation (degrade_on_overload)")
-        if self.executor is not None and self.executor.percell:
-            modes.append("per-cell dispatch")
         return modes
-
-    def _route(self, scene_id: str, pp) -> Optional[int]:
-        """Shard-locality routing: the tile's home cell is a mesh device
-        owning the maximal share of this scene's trunk layers (owner-map
-        API); scenes spread deterministically over tied owners. Every
-        layer the home cell owns is a remote gather this scene's
-        dispatches don't pay. ``None`` (unrouted) when routing is off or
-        the resident isn't mesh-sharded."""
-        if not self.route_by_shard or getattr(pp, "shard_mesh", None) is None:
-            return None
-        home = self._home_cells.get(scene_id)
-        if home is None:
-            from repro.runtime import sharding as rsh
-            home = rsh.plcore_home_cell(pp.shard_mesh, pp.cfg.trunk_layers,
-                                        salt=scene_id)
-            self._home_cells[scene_id] = home
-        return home
 
     def _note_load_failure(self, scene: str, err: SceneLoadError) -> None:
         """Account one failed ``cache.get`` and, once the cache reports
@@ -736,8 +707,7 @@ class TileScheduler:
         tid = self._tile_seq
         self._tile_seq += 1
         tile = _Tile(scene, pp, spans, np.concatenate(chunks_o),
-                     np.concatenate(chunks_d), n,
-                     home_cell=self._route(scene, pp), degraded=degraded,
+                     np.concatenate(chunks_d), n, degraded=degraded,
                      budget=budget,
                      dead_bucket=(bucket is not None
                                   and bucket >= len(ar.budgets)),
@@ -775,7 +745,6 @@ class TileExecutor:
                  max_retry_backoff_s: float = 0.05,
                  check_finite: bool = True, clock=time.perf_counter,
                  sleep=time.sleep, redispatch_hook=None, tracer=None,
-                 percell: bool = False,
                  adaptive: "Optional[AdaptiveSampling]" = None):
         if depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
@@ -786,17 +755,10 @@ class TileExecutor:
         self.depth = int(depth)
         self.faults = faults
         self.straggler = straggler
-        # per-cell dispatch (PR 9): routed tiles execute through programs
-        # compiled for their home cell only, and the in-flight budget is
-        # counted PER CELL — each cell gets its own ``depth`` slots, so
-        # two cells genuinely hold different scenes' tiles concurrently
-        # instead of the whole mesh serializing over one slot ring
-        self.percell = bool(percell)
         # adaptive sampling (PR 10): budget-stamped tiles route through
         # the scene's AdaptiveRenderer (budgeted n_fine + memo-dead rays)
         # instead of the static full-budget dispatch
         self.adaptive = adaptive
-        self.cell_stats: Dict[Optional[int], dict] = {}
         # cluster failover: tried BEFORE the local retry ladder — a tile
         # that failed here is first offered to a DIFFERENT host; only
         # when the hook declines (returns None) does the local
@@ -809,10 +771,10 @@ class TileExecutor:
         self._clock = clock
         self._sleep = sleep             # injectable alongside the clock
         self._slots: deque = deque()    # (tile, rgb, t0, extra_s, span)
-        # per stream, when its last drained tile was materialized: at
-        # depth >= 2 the next tile was enqueued behind it and only starts
-        # then, so its service is measured from there, not from dispatch
-        self._last_drain: Dict[Optional[int], float] = {}
+        # when the last drained tile was materialized: at depth >= 2 the
+        # next tile was enqueued behind it and only starts then, so its
+        # service is measured from there, not from dispatch
+        self._last_drain: Optional[float] = None
 
     @property
     def in_flight(self) -> int:
@@ -821,18 +783,20 @@ class TileExecutor:
     # ------------------------------------------------------- internals ----
     def _attempt(self, tile: _Tile, allow_straggle: bool = True):
         """ONE dispatch attempt through the fault plan. Returns
-        ``(device_rgb, gather_cost, injected_extra_latency_s)``; raises
-        on an (injected or real) dispatch failure."""
+        ``(device_rgb, injected_extra_latency_s)``; raises on an
+        (injected or real) dispatch failure."""
         fault = (self.faults.draw_dispatch(allow_straggle=allow_straggle)
                  if self.faults is not None else None)
         if fault is not None and fault["kind"] == "dispatch_error":
             raise InjectedDispatchError(
                 f"injected dispatch failure (tile scene={tile.scene_id})")
         tr = self.tracer
+        extra = (fault["extra_s"]
+                 if fault is not None and fault["kind"] == "straggle"
+                 else 0.0)
         if tile.budget is not None and self.adaptive is not None:
             # adaptive path: budget-stamped tile renders at its class's
-            # n_fine with memo-dead rays masked out of the fused kernel;
-            # gather cost matches the static path (same packed weights)
+            # n_fine with memo-dead rays masked out of the fused kernel
             ar = self.adaptive.renderer(tile.scene_id, tile.pp)
             rgb, info = ar.render_tile(tile.rays_o, tile.rays_d,
                                        budget=tile.budget,
@@ -842,27 +806,19 @@ class TileExecutor:
                 tr.event("tile.adaptive", cat="tile", tile=tile.tid,
                          host=tile.host_id, budget_class=tile.budget,
                          dead=info["dead"], full_dead=info["full_dead"])
-            cost = tile.pp.tile_gather_cost(tile.home_cell)
-            extra = (fault["extra_s"]
-                     if fault is not None and fault["kind"] == "straggle"
-                     else 0.0)
-            return rgb, cost, extra
+            return rgb, extra
         footprint = {}
         with tr.span("tile.commit", cat="tile", tile=tile.tid):
             o = tile.pp.commit(tile.rays_o)
             d = tile.pp.commit(tile.rays_d)
             if tile.radii is not None:
                 footprint["radii"] = tile.pp.commit(tile.radii)
-        rgb, cost = tile.pp.dispatch_tile(
-            o, d, home_cell=tile.home_cell, coarse_only=tile.degraded,
-            percell=self.percell, **footprint,
+        rgb, _ = tile.pp.dispatch_tile(
+            o, d, coarse_only=tile.degraded, **footprint,
             tracer=tr if tr.enabled else None,
             trace_attrs={"tile": tile.tid, "host": tile.host_id,
                          "scene": tile.scene_id} if tr.enabled else None)
-        extra = (fault["extra_s"]
-                 if fault is not None and fault["kind"] == "straggle"
-                 else 0.0)
-        return rgb, cost, extra
+        return rgb, extra
 
     def _is_finite(self, arr: np.ndarray, tile: _Tile) -> bool:
         """Real (non-pad) rays must be finite. Checked whenever
@@ -883,9 +839,9 @@ class TileExecutor:
         fresh dispatches (each a new fault-plan event, so transient
         faults clear; capped exponential backoff between attempts), then
         the bit-exact oracle program — which the fault plan never
-        touches. Returns ``(finite rgb ndarray, gather_cost)``; retry
-        attempts are accounted per tile and per touched request, the
-        oracle rung as ``oracle_fallbacks``."""
+        touches. Returns the finite rgb ndarray; retry attempts are
+        accounted per tile and per touched request, the oracle rung as
+        ``oracle_fallbacks``."""
         st = self.stats
         tr = self.tracer
         if self.redispatch_hook is not None:
@@ -907,7 +863,7 @@ class TileExecutor:
                 self._sleep(min(self.retry_backoff_s * (2 ** attempt),
                                 self.max_retry_backoff_s))
             try:
-                rgb, cost, _ = self._attempt(tile, allow_straggle=False)
+                rgb, _ = self._attempt(tile, allow_straggle=False)
             except Exception:
                 st["dispatch_errors"] += 1
                 continue
@@ -917,7 +873,7 @@ class TileExecutor:
                 if bad is not None:
                     arr = bad
             if self._is_finite(arr, tile):
-                return arr, cost
+                return arr
             st["corrupt_tiles"] += 1
         st["oracle_fallbacks"] += 1
         if tr.enabled:
@@ -930,72 +886,16 @@ class TileExecutor:
         d = tile.pp.commit(tile.rays_d)
         footprint = ({} if tile.radii is None
                      else {"radii": tile.pp.commit(tile.radii)})
-        arr = np.asarray(
+        return np.asarray(
             tile.pp.render_tile(o, d, coarse_only=True) if tile.degraded
             else tile.pp.render_tile_oracle(o, d, **footprint))
-        return arr, tile.pp.tile_gather_cost(tile.home_cell)
 
-    def _account(self, tile: _Tile, cost: dict) -> None:
+    def _account(self, tile: _Tile) -> None:
         st = self.stats
         st["dispatches"] += 1
         st["rays_rendered"] += tile.n_real
-        st["plcore_gather_count"] += cost["layers"]
-        st["plcore_gather_bytes"] += cost["bytes"]
-        if tile.home_cell is not None:
-            st["routed_tiles"] += 1
         if tile.degraded:
             st["degraded_tiles"] += 1
-        if "cell" in cost and "percell_tiles" in st:
-            # a per-cell execution: the dispatch itself is gather-free;
-            # stage_* is nonzero only on the dispatch that staged the
-            # (scene, cell) weights — the one-time residency transfer
-            st["percell_tiles"] += 1
-            if cost.get("stage_layers"):
-                st["percell_stage_events"] += 1
-                st["percell_stage_layers"] += cost["stage_layers"]
-                st["percell_stage_bytes"] += cost["stage_bytes"]
-
-    # --------------------------------------------------- per-cell slots ----
-    def _cell_of(self, tile: _Tile) -> Optional[int]:
-        """The in-flight stream a tile occupies: its home cell under
-        per-cell dispatch, else the single global (None) stream."""
-        return tile.home_cell if self.percell else None
-
-    def _cell_in_flight(self, cell: Optional[int]) -> int:
-        return sum(1 for s in self._slots if self._cell_of(s[0]) == cell)
-
-    def _note_cell_dispatch(self, tile: _Tile) -> None:
-        """Per-cell occupancy bookkeeping at dispatch time — the 2-cell
-        concurrency gate reads ``cell_stats[cell]["max_in_flight"]``."""
-        if not self.percell:
-            return
-        cell = self._cell_of(tile)
-        n = self._cell_in_flight(cell)
-        cs = self.cell_stats.setdefault(
-            cell, {"dispatches": 0, "max_in_flight": 0})
-        cs["dispatches"] += 1
-        cs["max_in_flight"] = max(cs["max_in_flight"], n)
-        st = self.stats
-        if "percell_cells_active" in st:
-            st["percell_cells_active"] = len(self.cell_stats)
-        m = getattr(self.stats, "m", None)
-        if m is not None:
-            label = "none" if cell is None else cell
-            m.cell_dispatches.labels(cell=label).inc()
-            m.cell_in_flight.labels(cell=label).set(n)
-            m.cell_max_in_flight.labels(cell=label).set(cs["max_in_flight"])
-
-    def drain_cell_one(self, cell: Optional[int]) -> bool:
-        """Materialize the OLDEST in-flight tile of ONE cell stream (may
-        sit mid-ring: other cells' younger tiles stay in flight — that
-        independence is the per-cell concurrency win). Same recovery /
-        scatter / unpin path as ``drain_one``."""
-        for i, s in enumerate(self._slots):
-            if self._cell_of(s[0]) == cell:
-                del self._slots[i]
-                self._finish_slot(*s)
-                return True
-        return False
 
     def _update_service_ewma(self, dt: float) -> None:
         prev = self.stats.get("tile_service_s_ewma")
@@ -1008,37 +908,34 @@ class TileExecutor:
     # ----------------------------------------------------------- public ----
     def dispatch(self, tile: _Tile) -> None:
         """Issue one tile (non-blocking), pin its scene for the life of
-        the slot, account its gather traffic, then drain down to
-        ``depth - 1`` so at most ``depth`` programs are ever enqueued.
-        A dispatch-time failure is resolved SYNCHRONOUSLY through the
-        retry ladder (it never occupies a slot) — this method does not
-        raise for handled fault classes."""
-        self.cache.pin(tile.scene_id, cell=self._cell_of(tile))
+        the slot, account it, then drain down to ``depth - 1`` so at most
+        ``depth`` programs are ever enqueued. A dispatch-time failure is
+        resolved SYNCHRONOUSLY through the retry ladder (it never occupies
+        a slot) — this method does not raise for handled fault classes."""
+        self.cache.pin(tile.scene_id)
         tr = self.tracer
         if tr.enabled:
             tr.event("tile.dispatch", cat="tile", tile=tile.tid,
                      scene=tile.scene_id, host=tile.host_id,
-                     slot=len(self._slots), degraded=tile.degraded,
-                     home_cell=tile.home_cell)
+                     slot=len(self._slots), degraded=tile.degraded)
         try:
-            rgb, cost, extra = self._attempt(tile)
+            rgb, extra = self._attempt(tile)
         except Exception as e:
             self.stats["dispatch_errors"] += 1
             if tr.enabled:
                 tr.event("tile.dispatch_error", cat="tile", tile=tile.tid,
                          host=tile.host_id, error=str(e)[:120])
-            arr, cost = self._resolve_sync(tile)
-            self._account(tile, cost)
+            arr = self._resolve_sync(tile)
+            self._account(tile)
             self.completion.scatter(tile, arr)
-            self.cache.unpin(tile.scene_id, cell=self._cell_of(tile))
+            self.cache.unpin(tile.scene_id)
             return
         sp = (tr.begin("tile.device_compute", cat="tile", tile=tile.tid,
                        host=tile.host_id, slot=len(self._slots))
               if tr.enabled else None)
         overlapped = bool(self._slots)
         self._slots.append((tile, rgb, self._clock(), extra, sp))
-        self._account(tile, cost)
-        self._note_cell_dispatch(tile)
+        self._account(tile)
         self.stats["max_in_flight"] = max(self.stats["max_in_flight"],
                                           len(self._slots))
         m = getattr(self.stats, "m", None)
@@ -1046,15 +943,8 @@ class TileExecutor:
             m.in_flight_tiles.set(len(self._slots))
             if overlapped:
                 m.overlapped_dispatches.inc()
-        if self.percell:
-            # the depth budget is PER CELL: this tile's stream drains
-            # when ITS cell is full, other cells' tiles stay in flight
-            cell = self._cell_of(tile)
-            while self._cell_in_flight(cell) >= self.depth:
-                self.drain_cell_one(cell)
-        else:
-            while len(self._slots) >= self.depth:
-                self.drain_one()
+        while len(self._slots) >= self.depth:
+            self.drain_one()
 
     def drain_one(self) -> bool:
         """Materialize the OLDEST in-flight tile (the only host sync in
@@ -1066,17 +956,15 @@ class TileExecutor:
         return True
 
     def _finish_slot(self, tile, rgb, t0, extra, sp) -> None:
-        """The drain body shared by ``drain_one`` (oldest overall) and
-        ``drain_cell_one`` (oldest of one cell stream): materialize,
-        recover if corrupt/straggled, scatter, unpin. Traced, the
-        materialization is split into the wait for the device and the
-        copy to the host. The tile's service runs from the later of its
-        dispatch and its stream's previous drain: a tile enqueued behind
-        another waits for it on the device, and that wait is the older
-        tile's service, not this one's."""
+        """``drain_one``'s body: materialize, recover if
+        corrupt/straggled, scatter, unpin. Traced, the materialization is
+        split into the wait for the device and the copy to the host. The
+        tile's service runs from the later of its dispatch and the
+        previous drain: a tile enqueued behind another waits for it on
+        the device, and that wait is the older tile's service, not this
+        one's."""
         tr = self.tracer
-        cell = self._cell_of(tile)
-        start = max(t0, self._last_drain.get(cell, t0))
+        start = t0 if self._last_drain is None else max(t0, self._last_drain)
         if tr.enabled:
             with tr.span("tile.wait", cat="tile", tile=tile.tid):
                 jax.block_until_ready(rgb)
@@ -1096,9 +984,9 @@ class TileExecutor:
         if self.straggler is not None:
             # effective in-flight latency includes any injected straggle;
             # past the monitor's deadline the slow result is abandoned
-            # and the tile redispatched fresh (on a multi-cell deployment
-            # this lands on a different cell; here it models cutting the
-            # loss instead of stalling the drain point)
+            # and the tile redispatched fresh (under the cluster, on
+            # another host first; here it models cutting the loss instead
+            # of stalling the drain point)
             verdict = self.straggler.record_step(
                 self._clock() - start + extra)
             if verdict["deadline_exceeded"]:
@@ -1106,7 +994,7 @@ class TileExecutor:
                 if tr.enabled:
                     tr.event("tile.straggler_redispatch", cat="tile",
                              tile=tile.tid, host=tile.host_id)
-                arr, _ = self._resolve_sync(tile)
+                arr = self._resolve_sync(tile)
                 redispatched = True
             elif extra > 0.0:
                 self._sleep(extra)    # the monitor missed it: pay the stall
@@ -1119,15 +1007,15 @@ class TileExecutor:
             if tr.enabled:
                 tr.event("tile.corrupt", cat="tile", tile=tile.tid,
                          host=tile.host_id)
-            arr, _ = self._resolve_sync(tile)
+            arr = self._resolve_sync(tile)
         now = self._clock()
-        self._last_drain[cell] = now
+        self._last_drain = now
         m = getattr(self.stats, "m", None)
         if m is not None:
             m.in_flight_tiles.set(len(self._slots))
         self._update_service_ewma(now - start)
         self.completion.scatter(tile, arr)
-        self.cache.unpin(tile.scene_id, cell=cell)
+        self.cache.unpin(tile.scene_id)
 
     def drain_all(self) -> None:
         while self.drain_one():
@@ -1149,7 +1037,7 @@ class TileExecutor:
             if tr.enabled:
                 tr.event("tile.abandon", cat="tile", tile=tile.tid,
                          host=tile.host_id)
-            self.cache.unpin(tile.scene_id, cell=self._cell_of(tile))
+            self.cache.unpin(tile.scene_id)
             tiles.append(tile)
         return tiles
 
@@ -1278,8 +1166,7 @@ class RenderEngine:
     ``pipeline_depth`` bounds the executor's in-flight slots (default
     ``DEFAULT_PIPELINE_DEPTH`` = 2, which overlaps the host's commit,
     dispatch, copy back and scatter with device compute; 1 =
-    synchronous, the bit-identical baseline); ``route_by_shard`` turns
-    on owner-map tile routing for mesh-sharded residents.
+    synchronous, the bit-identical baseline).
 
     Fault-tolerance knobs (all default to the pre-fault behavior):
     ``max_queue`` bounds the request queue (admission rejects beyond);
@@ -1301,8 +1188,6 @@ class RenderEngine:
     def __init__(self, cache: SceneCache, *, tile_rays: int = 512,
                  max_sticky_tiles: int = 64, clock=time.perf_counter,
                  pipeline_depth: int = DEFAULT_PIPELINE_DEPTH,
-                 route_by_shard: bool = False,
-                 percell_dispatch: bool = False,
                  max_queue: Optional[int] = None,
                  aging_tiles: Optional[int] = None,
                  degrade_on_overload: bool = False,
@@ -1322,27 +1207,13 @@ class RenderEngine:
                  adaptive_grid_res: int = 32,
                  adaptive_probe_hw: int = 8,
                  tracer=None, registry=None):
-        if percell_dispatch and not route_by_shard:
-            raise ValueError("percell_dispatch executes tiles on their "
-                             "routed home cell — pass route_by_shard=True")
-        if adaptive_sampling:
-            # ASDR rides the replicated fused-kernel single-cell path:
-            # sharded residency drops the raw trunk params the probe and
-            # memo need, per-cell/routed dispatch would multiply the
-            # per-budget program cache across cells, and overload
-            # degradation already rewrites the sample budget its own way
-            if route_by_shard or percell_dispatch:
-                raise ValueError("adaptive_sampling is a replicated "
-                                 "single-cell feature — incompatible with "
-                                 "route_by_shard / percell_dispatch")
-            if degrade_on_overload:
-                raise ValueError("adaptive_sampling and "
-                                 "degrade_on_overload both rewrite the "
-                                 "per-ray sample budget — arm one")
+        if adaptive_sampling and degrade_on_overload:
+            raise ValueError("adaptive_sampling and degrade_on_overload "
+                             "both rewrite the per-ray sample budget — "
+                             "arm one")
         self.cache = cache
         self.faults = faults
         self._clock = clock
-        self.percell_dispatch = bool(percell_dispatch)
         # observability: a per-engine registry backs the stats dict (the
         # keys, order and value types come from ENGINE_STATS_SCHEMA —
         # the old literal dict, now registry-derived so a counter can't
@@ -1353,15 +1224,10 @@ class RenderEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = engine_stats_view(self.registry)
         watch_compiles()
-        if percell_dispatch:
-            # extension block, bound ONLY when per-cell dispatch is on so
-            # the default serialized stats stay byte-identical
-            from repro.obs.metrics import (PERCELL_STATS_SCHEMA,
-                                           extend_stats_view)
-            extend_stats_view(self.stats, PERCELL_STATS_SCHEMA)
         self.adaptive: Optional[AdaptiveSampling] = None
         if adaptive_sampling:
-            # sampling extension block — same bind-only-when-armed rule
+            # sampling extension block, bound ONLY when adaptive
+            # sampling is on so the default stats stay byte-identical
             from repro.obs.metrics import (SAMPLING_STATS_SCHEMA,
                                            extend_stats_view)
             extend_stats_view(self.stats, SAMPLING_STATS_SCHEMA)
@@ -1371,7 +1237,7 @@ class RenderEngine:
         cache.tracer = self.tracer
         self.scheduler = TileScheduler(
             cache, tile_rays=tile_rays, max_sticky_tiles=max_sticky_tiles,
-            route_by_shard=route_by_shard, stats=self.stats, clock=clock,
+            stats=self.stats, clock=clock,
             max_queue=max_queue, aging_tiles=aging_tiles,
             degrade_on_overload=degrade_on_overload,
             degrade_queue_tiles=degrade_queue_tiles,
@@ -1398,7 +1264,7 @@ class RenderEngine:
             max_tile_retries=max_tile_retries,
             retry_backoff_s=retry_backoff_s,
             check_finite=check_finite, clock=clock, tracer=self.tracer,
-            percell=percell_dispatch, adaptive=self.adaptive)
+            adaptive=self.adaptive)
         # admission control needs the in-flight count; termination needs
         # the sink — wire the cross-layer references the façade owns
         self.scheduler.completion = self.completion
@@ -1506,27 +1372,6 @@ class RenderEngine:
         if self.faults is not None:
             out["faults_injected"] = self.faults.summary()
         return out
-
-    def percell_report(self) -> Optional[dict]:
-        """Per-cell dispatch summary (``None`` unless the engine runs
-        with ``percell_dispatch``): per-cell dispatch counts and peak
-        in-flight occupancy plus the one-time staging totals — what the
-        bench's ``serving.percell`` block and serve.py's ``--check``
-        concurrency gate persist."""
-        if not self.percell_dispatch:
-            return None
-        st = self.stats
-        cells = {str(c): dict(v)
-                 for c, v in sorted(self.executor.cell_stats.items(),
-                                    key=lambda kv: (kv[0] is None, kv[0]))}
-        return {
-            "cells": cells,
-            "percell_tiles": st["percell_tiles"],
-            "stage_events": st["percell_stage_events"],
-            "stage_layers": st["percell_stage_layers"],
-            "stage_bytes": st["percell_stage_bytes"],
-            "cells_active": st["percell_cells_active"],
-        }
 
     def sampling_report(self) -> Optional[dict]:
         """Adaptive-sampling summary (``None`` unless the engine runs

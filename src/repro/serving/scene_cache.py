@@ -21,14 +21,10 @@ A resident with tiles in flight on the async executor is PINNED
 whose dispatched tiles have not yet drained can never lose its weights
 to a colder scene's load mid-flight. Unpinned entries evict LRU-first as
 before.
-The accounting is PER DEVICE: a replicated array costs its full size on
-every device (so it counts once, as before), but a mesh-sharded resident
-(``PackedPlcore(..., shard_mesh=...)`` — trunk stacks layer-partitioned
-over the ("pod","data") axes) costs each device only its shard, so the
-same ``capacity_mb`` holds ~n_shards x more scenes and cache capacity
-scales with the mesh. Eviction never removes the just-inserted entry, so
-a cache smaller than one scene still serves (it just thrashes, and the
-counters show it).
+The accounting is PER DEVICE: an array costs the bytes the device that
+holds the most of it holds. Eviction never removes the just-inserted
+entry, so a cache smaller than one scene still serves (it just thrashes,
+and the counters show it).
 """
 from __future__ import annotations
 
@@ -54,8 +50,7 @@ class SceneLoadError(RuntimeError):
 
 def device_nbytes(a) -> int:
     """Per-device resident bytes of one array: the largest total any
-    single device holds. Replicated (or single-device) arrays cost their
-    full size; an array sharded k ways costs size/k."""
+    single device holds."""
     try:
         per_dev: dict = {}
         for s in a.addressable_shards:
@@ -71,7 +66,7 @@ def device_nbytes(a) -> int:
 def plcore_nbytes(pp: PackedPlcore) -> int:
     """Per-device resident bytes of one loaded scene: every array hanging
     off the PackedPlcore (raw params + RMCM quant tree + packed kernel
-    layout), sharded arrays counted at their per-device shard size."""
+    layout), each counted at its per-device size."""
     leaves = jax.tree_util.tree_leaves((pp.params, pp.quant, pp.packed))
     return int(sum(device_nbytes(a) for a in leaves))
 
@@ -81,9 +76,7 @@ class SceneCache:
 
     ``loader(scene_id)`` builds a PackedPlcore on miss (the once-per-
     residency pack); ``capacity_mb`` bounds total PER-DEVICE resident
-    bytes, so a loader that builds mesh-sharded residents fits
-    proportionally more scenes in the same budget. Hits, misses and
-    evictions are counted for the serving stats.
+    bytes. Hits, misses and evictions are counted for the serving stats.
 
     A loader that RAISES must leave the cache exactly as it was: no
     partially-constructed entry resident, no stale pin refcount, and the
@@ -113,10 +106,6 @@ class SceneCache:
         # entry; its nbytes is LIVE (trunk memo grows during serving)
         self._aux: Dict[str, object] = {}
         self._pins: Dict[str, int] = {}
-        # per-cell pin accounting (percell dispatch): scene -> cell ->
-        # refcount. A sub-account of _pins, never a second gate — a
-        # scene is evictable iff its TOTAL refcount is zero.
-        self._cell_pins: Dict[str, Dict[int, int]] = {}
         self.fail_backoff = int(fail_backoff)
         self.max_fail_backoff = int(max_fail_backoff)
         # scene -> [consecutive real failures, fail-fast credits left]
@@ -179,51 +168,29 @@ class SceneCache:
         self._evict_over_capacity(keep=scene_id)
         return aux
 
-    def pin(self, scene_id: str, cell: "Optional[int]" = None) -> None:
+    def pin(self, scene_id: str) -> None:
         """Refcount one in-flight use of a resident scene: a pinned entry
         is skipped by eviction until its last ``unpin`` (the executor pins
         at tile dispatch and unpins when the tile's scatter drains, so a
-        resident can never be evicted under an in-flight dispatch).
-        ``cell`` (percell dispatch) additionally attributes the pin to
-        the tile's home cell — ``pinned_cells`` shows which cells hold a
-        scene's tiles in flight; eviction still gates on the total."""
+        resident can never be evicted under an in-flight dispatch)."""
         self._pins[scene_id] = self._pins.get(scene_id, 0) + 1
-        if cell is not None:
-            by_cell = self._cell_pins.setdefault(scene_id, {})
-            by_cell[int(cell)] = by_cell.get(int(cell), 0) + 1
         if self.tracer.enabled:
             self.tracer.event("cache.pin", cat="cache", scene=scene_id,
-                              host=self.trace_host, cell=cell,
+                              host=self.trace_host,
                               refs=self._pins[scene_id])
 
-    def unpin(self, scene_id: str, cell: "Optional[int]" = None) -> None:
+    def unpin(self, scene_id: str) -> None:
         n = self._pins.get(scene_id, 0) - 1
         if n <= 0:
             self._pins.pop(scene_id, None)
         else:
             self._pins[scene_id] = n
-        if cell is not None:
-            by_cell = self._cell_pins.get(scene_id)
-            if by_cell is not None:
-                c = by_cell.get(int(cell), 0) - 1
-                if c <= 0:
-                    by_cell.pop(int(cell), None)
-                else:
-                    by_cell[int(cell)] = c
-                if not by_cell:
-                    self._cell_pins.pop(scene_id, None)
         if self.tracer.enabled:
             self.tracer.event("cache.unpin", cat="cache", scene=scene_id,
-                              host=self.trace_host, cell=cell,
-                              refs=max(0, n))
+                              host=self.trace_host, refs=max(0, n))
 
     def pinned(self, scene_id: str) -> bool:
         return scene_id in self._pins
-
-    def pinned_cells(self, scene_id: str) -> dict:
-        """cell -> in-flight pin refcount for one scene (empty when no
-        per-cell tile is in flight)."""
-        return dict(self._cell_pins.get(scene_id, {}))
 
     def discard(self, scene_id: str) -> bool:
         """Drop one resident entry outside the LRU policy (the cluster's

@@ -244,8 +244,6 @@ def test_adaptive_renderer_requires_fused_kernel(scene_setup):
 
 def test_engine_guards_reject_incompatible_modes(scene_setup):
     cache = _cache(*scene_setup, capacity_mb=64.0)
-    with pytest.raises(ValueError, match="single-cell"):
-        RenderEngine(cache, adaptive_sampling=True, route_by_shard=True)
     with pytest.raises(ValueError, match="degrade_on_overload"):
         RenderEngine(cache, adaptive_sampling=True,
                      degrade_on_overload=True)

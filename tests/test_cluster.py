@@ -388,7 +388,7 @@ from repro.serving import ClusterEngine, RenderRequest, SceneCache, split_device
 devs = jax.devices()
 assert len(devs) == 4, devs
 groups = split_devices(4)
-devices = host_devices(groups, None)
+devices = host_devices(groups)
 assert devices == devs, devices
 
 # every dispatched tile: the devices its rays and pixels sit on, keyed by
@@ -396,10 +396,10 @@ assert devices == devs, devices
 seen = {}
 dispatch = PackedPlcore.dispatch_tile
 def spy(self, o, d, **kw):
-    rgb, cost = dispatch(self, o, d, **kw)
+    rgb, rest = dispatch(self, o, d, **kw)
     seen.setdefault(self.device, set()).update(
         o.devices() | d.devices() | rgb.devices())
-    return rgb, cost
+    return rgb, rest
 PackedPlcore.dispatch_tile = spy
 
 cfg = tiny()

@@ -1,36 +1,31 @@
 """Scheduler/executor/completion engine layers: pipelined async dispatch,
-shard-locality routing, cache pinning, latency split.
+cache pinning, latency split.
 
 The load-bearing claims on top of test_serving.py's synchronous ones:
 (1) any ``pipeline_depth`` renders framebuffers BIT-IDENTICAL to the
 synchronous depth=1 loop (per-ray independence makes tile-partition
-differences invisible) while actually holding ``depth`` tiles in flight;
-(2) a scene with in-flight executor tiles is PINNED in the ``SceneCache``
-— eviction pressure from loading other scenes cannot drop its weights
-until the last slot drains; (3) owner-map routing strictly shrinks the
-engine's per-dispatch gather accounting (``plcore_gather_count/_bytes``)
-vs unrouted on the same trace, with identical pixels; (4) request latency
-splits exactly into queueing delay + service time; (5) an engine built
-without ``pipeline_depth`` pipelines two tiles deep, counts each
-dispatch that overlapped an occupied slot, and reads each tile's service
-from the later of its dispatch and the previous drain, so admission
-control's estimate does not double at depth 2. Subprocess legs
-(the conftest ``fake_devices`` fixture) re-assert (1)+(3) on a REAL
-4-way layer shard over 8 fake CPU devices, and hold per-cell dispatch
-(``percell_dispatch=True``) to the ISSUE acceptance bar there: tiles
-bit-identical to the mesh-wide SPMD engine, staging paid once per
-(scene, cell) with zero per-dispatch gathers, and >= 2 cells genuinely
-concurrent on a 2-scene trace.
+differences invisible) while actually holding ``depth`` tiles in flight
+— for the XLA program and for the fused two-pass programs the chip
+serves (f32, RMCM, and a cone scene); (2) a scene with in-flight executor
+tiles is PINNED in the ``SceneCache`` — eviction pressure from loading
+other scenes cannot drop its weights until the last slot drains; (3)
+request latency splits exactly into queueing delay + service time; (4)
+an engine built without ``pipeline_depth`` pipelines two tiles deep,
+counts each dispatch that overlapped an occupied slot, and reads each
+tile's service from the later of its dispatch and the previous drain, so
+admission control's estimate does not double at depth 2.
 """
 import jax
 import numpy as np
 import pytest
 
-from repro.configs.nerf_icarus import tiny
+from test_mipnerf import _cone_cache
+
+from repro.configs.nerf_icarus import tiny, tiny_mip
+from repro.core import rmcm
 from repro.core.pipeline import PackedPlcore
 from repro.core.plcore import plcore_decls
 from repro.models.params import init_params
-from repro.runtime import sharding as rsh
 from repro.serving import RenderEngine, RenderRequest, SceneCache
 from repro.serving import loadgen
 
@@ -53,6 +48,31 @@ def _engine(cfg, param_sets, **kw):
     return RenderEngine(cache, tile_rays=kw.pop("tile_rays", TILE), **kw)
 
 
+@pytest.fixture(scope="module")
+def residents(setup):
+    """Scene caches by the program their residents run: the XLA path,
+    and the fused two-pass kernel the chip serves, in f32, in RMCM
+    weights, and for a cone (Mip-NeRF) scene at ``tiny_mip()``. Each
+    call builds a fresh cache."""
+    cfg, param_sets = setup
+    quant = {sid: {n: rmcm.quantize_tree(p[n]) for n in ("coarse", "fine")}
+             for sid, p in param_sets.items()}
+    mip = tiny_mip()
+    mip_scene = (mip, init_params(plcore_decls(mip), jax.random.PRNGKey(3)))
+
+    def fused(sid, **kw):
+        return PackedPlcore(cfg, param_sets[sid], use_kernel=True,
+                            fuse_two_pass=True, **kw)
+    return {
+        "xla": lambda: SceneCache(
+            lambda sid: PackedPlcore(cfg, param_sets[sid])),
+        "fused": lambda: SceneCache(fused),
+        "fused_rmcm": lambda: SceneCache(
+            lambda sid: fused(sid, quant=quant[sid])),
+        "cone": lambda: _cone_cache(mip_scene),
+    }
+
+
 MIXED = [RenderRequest("scene0", hw=10, theta=10.0),
          RenderRequest("scene1", hw=12, theta=50.0),
          RenderRequest("scene0", hw=10, theta=90.0),
@@ -62,8 +82,9 @@ MIXED = [RenderRequest("scene0", hw=10, theta=10.0),
 
 
 # ------------------------------------------------ pipelined bit-identity ----
-def _run_mixed(cfg, param_sets, **kw):
-    eng = _engine(cfg, param_sets, **kw)
+def _run_mixed(cfg, param_sets, cache=None, **kw):
+    eng = (_engine(cfg, param_sets, **kw) if cache is None
+           else RenderEngine(cache, tile_rays=TILE, **kw))
     rids = [eng.submit(r) for r in MIXED]
     eng.drain()
     assert eng.in_flight_tiles == 0
@@ -72,27 +93,43 @@ def _run_mixed(cfg, param_sets, **kw):
 
 
 @pytest.fixture(scope="module")
-def sync_mixed(setup):
-    """The synchronous depth=1 engine over ``MIXED``: the anchor."""
-    eng, rids = _run_mixed(*setup, pipeline_depth=1)
-    assert eng.pipeline_depth == 1
-    assert eng.stats["max_in_flight"] == 1
-    return eng, rids
+def sync_mixed(setup, residents):
+    """The synchronous depth=1 engine over ``MIXED``, per residents: the
+    anchor (run once per residents, on first use)."""
+    runs = {}
+
+    def anchor(name):
+        if name not in runs:
+            eng, rids = _run_mixed(*setup, cache=residents[name](),
+                                   pipeline_depth=1)
+            assert eng.pipeline_depth == 1
+            assert eng.stats["max_in_flight"] == 1
+            runs[name] = eng, rids
+        return runs[name]
+    return anchor
 
 
-@pytest.mark.parametrize("kw, depth", [({"pipeline_depth": 2}, 2),
-                                       ({"pipeline_depth": 3}, 3),
-                                       ({}, 2)],
-                         ids=["depth2", "depth3", "default"])
-def test_pipeline_depths_bit_identical(setup, sync_mixed, kw, depth):
+_DEPTHS = [({"pipeline_depth": 2}, 2, "depth2"),
+           ({"pipeline_depth": 3}, 3, "depth3"),
+           ({}, 2, "default")]
+
+
+@pytest.mark.parametrize("name, kw, depth", [
+    pytest.param(name, kw, depth,
+                 id=tag if name == "xla" else f"{name}-{tag}")
+    for name in ("xla", "fused", "fused_rmcm", "cone")
+    for kw, depth, tag in _DEPTHS])
+def test_pipeline_depths_bit_identical(setup, residents, sync_mixed, name,
+                                       kw, depth):
     """Depths 2/3, and an engine built without ``pipeline_depth`` (the
     served default, 2), over the same submitted-upfront trace as the
     synchronous loop: identical scheduler decisions (dispatch/pad counts
     equal), identical images, and the deep engines really pipeline
-    (peak in-flight == depth)."""
+    (peak in-flight == depth) — for the XLA residents and for the fused
+    residents every cell serves."""
     cfg, param_sets = setup
-    base, base_rids = sync_mixed
-    eng, rids = _run_mixed(cfg, param_sets, **kw)
+    base, base_rids = sync_mixed(name)
+    eng, rids = _run_mixed(cfg, param_sets, cache=residents[name](), **kw)
     assert eng.pipeline_depth == depth
     # all requests queued before the first step -> the scheduler walks
     # the same policy path at any depth
@@ -279,28 +316,7 @@ def test_latency_splits_into_queueing_plus_service(setup):
         assert np.isclose(res.queueing_s + res.service_s, res.latency_s)
 
 
-# ---------------------------------------------------- routing accounting ----
-def test_owner_map_replicated_fallback_and_gather_cost(setup):
-    """On a 1-device mesh the stacks replicate: the lone cell owns every
-    layer, so a routed tile's modeled gather cost is 0 while the unrouted
-    worst case prices every trunk layer of both nets."""
-    cfg, param_sets = setup
-    mesh = rsh.plcore_mesh()
-    L = cfg.trunk_layers
-    assert rsh.plcore_owner_table(mesh, L).all()
-    assert rsh.plcore_locality_scores(mesh, L).tolist() == [L]
-    assert not rsh.plcore_owned_layer_mask(mesh, L).any()    # None = unrouted
-    pp = PackedPlcore(cfg, param_sets["scene0"], shard_mesh=mesh)
-    unrouted = pp.tile_gather_cost()
-    assert unrouted["layers"] == 2 * 2 * L        # (w,b) x (coarse,fine)
-    assert unrouted["bytes"] > 0
-    routed = pp.tile_gather_cost(rsh.plcore_home_cell(mesh, L, "scene0"))
-    assert routed == {"layers": 0, "bytes": 0}
-    # unsharded residents gather nothing either way
-    assert PackedPlcore(cfg, param_sets["scene0"]).tile_gather_cost() == \
-        {"layers": 0, "bytes": 0}
-
-
+# ------------------------------------------------------------ dispatch ----
 def test_dispatch_tile_matches_render_tile(setup):
     cfg, param_sets = setup
     pp = PackedPlcore(cfg, param_sets["scene0"])
@@ -309,181 +325,6 @@ def test_dispatch_tile_matches_render_tile(setup):
     o = np.asarray(ro, np.float32).reshape(-1, 3)
     d = np.asarray(rd, np.float32).reshape(-1, 3)
     rgb, cost = pp.dispatch_tile(o.copy(), d.copy())
-    assert cost == {"layers": 0, "bytes": 0}
+    assert cost == {}
     np.testing.assert_array_equal(np.asarray(rgb),
                                   np.asarray(pp.render_tile(o, d)))
-
-
-def test_routed_engine_reduces_gather_accounting(setup):
-    """route_by_shard over sharded residents (replicated fallback on this
-    1-device box: the home cell owns all layers): routed accounting drops
-    to zero, unrouted prices every dispatch, pixels identical."""
-    cfg, param_sets = setup
-    mesh = rsh.plcore_mesh()
-
-    def make(routed):
-        cache = SceneCache(
-            lambda sid: PackedPlcore(cfg, param_sets[sid], shard_mesh=mesh),
-            capacity_mb=256.0)
-        return RenderEngine(cache, tile_rays=TILE, pipeline_depth=2,
-                            route_by_shard=routed)
-    reqs = MIXED[:3]
-    engines = {}
-    for routed in (True, False):
-        eng = make(routed)
-        rids = [eng.submit(r) for r in reqs]
-        eng.drain()
-        engines[routed] = (eng, rids)
-    routed_eng, routed_rids = engines[True]
-    unrouted_eng, unrouted_rids = engines[False]
-    assert routed_eng.stats["routed_tiles"] == routed_eng.stats["dispatches"]
-    assert routed_eng.stats["plcore_gather_count"] == 0
-    assert unrouted_eng.stats["routed_tiles"] == 0
-    assert (unrouted_eng.stats["plcore_gather_count"]
-            == unrouted_eng.stats["dispatches"] * 2 * 2 * cfg.trunk_layers)
-    assert unrouted_eng.stats["plcore_gather_bytes"] > 0
-    for rr, ur in zip(routed_rids, unrouted_rids):
-        np.testing.assert_array_equal(routed_eng.completed[rr].image,
-                                      unrouted_eng.completed[ur].image)
-
-
-# ------------------------------------------------- 8-device subprocess -----
-_SNIPPET = r"""
-import numpy as np
-from dataclasses import replace
-import jax
-from repro.configs.nerf_icarus import tiny
-from repro.core.pipeline import PackedPlcore
-from repro.core.plcore import plcore_decls
-from repro.models.params import init_params
-from repro.runtime import sharding as rsh
-from repro.serving import RenderEngine, RenderRequest, SceneCache
-
-cfg = tiny()
-L = cfg.trunk_layers
-mesh = rsh.plcore_mesh(4)                       # 4-way layer shard (L=4)
-assert rsh.plcore_shard_count(mesh, L) == 4
-table = rsh.plcore_owner_table(mesh, L).astype(int)
-assert table.shape == (4, L) and (table.sum(1) == L // 4).all()
-assert (table.sum(0) == 1).all()                # every layer has ONE owner
-homes = {s: rsh.plcore_home_cell(mesh, L, s)
-         for s in ("s0", "s1", "s2")}
-assert len(set(homes.values())) > 1, homes      # scenes spread over cells
-
-param_sets = {f"s{i}": init_params(plcore_decls(cfg), jax.random.PRNGKey(i),
-                                   "float32") for i in range(3)}
-def make(routed, depth):
-    cache = SceneCache(
-        lambda sid: PackedPlcore(cfg, param_sets[sid], shard_mesh=mesh),
-        capacity_mb=256.0)
-    return RenderEngine(cache, tile_rays=128, pipeline_depth=depth,
-                        route_by_shard=routed)
-
-reqs = [RenderRequest("s0", hw=12), RenderRequest("s1", hw=16),
-        RenderRequest("s0", hw=16), RenderRequest("s2", hw=12)]
-runs = {}
-for name, routed, depth in (("sync", False, 1), ("routed", True, 2),
-                            ("unrouted", False, 2)):
-    eng = make(routed, depth)
-    rids = [eng.submit(r) for r in reqs]
-    eng.drain()
-    assert eng.in_flight_tiles == 0
-    runs[name] = (eng, [eng.completed[rid].image for rid in rids])
-
-# pipelined + routed framebuffers == synchronous unrouted, bit for bit
-for name in ("routed", "unrouted"):
-    for a, b in zip(runs["sync"][1], runs[name][1]):
-        assert np.array_equal(a, b), f"{name} images != synchronous"
-        assert np.isfinite(a).all()
-
-# real-shard accounting: unrouted pays all L layers per stacked array,
-# routing a home cell that owns L/4 of them strictly reduces the count
-eng_r, eng_u = runs["routed"][0], runs["unrouted"][0]
-d = eng_u.stats["dispatches"]
-assert eng_u.stats["plcore_gather_count"] == d * 2 * 2 * L
-assert eng_r.stats["dispatches"] == d
-assert eng_r.stats["plcore_gather_count"] == d * 2 * 2 * (L - L // 4)
-assert eng_r.stats["plcore_gather_bytes"] < eng_u.stats["plcore_gather_bytes"]
-assert eng_r.stats["max_in_flight"] == 2
-print("ALL OK")
-"""
-
-
-@pytest.mark.slow
-def test_routed_pipelined_engine_multidevice(fake_devices):
-    fake_devices(_SNIPPET)
-
-
-# ----------------------------------- 8-device per-cell dispatch leg --------
-_PERCELL_SNIPPET = r"""
-import numpy as np
-from dataclasses import replace
-import jax
-from repro.configs.nerf_icarus import tiny
-from repro.core.pipeline import PackedPlcore
-from repro.core.plcore import plcore_decls
-from repro.models.params import init_params
-from repro.runtime import sharding as rsh
-from repro.serving import RenderEngine, RenderRequest, SceneCache
-
-# 8 trunk layers on a 4-cell mesh: every cell owns 2 layers, so per-cell
-# staging has 6 genuinely REMOTE layers per net to pay for
-cfg = replace(tiny(), trunk_layers=8, skip_at=(4,))
-L = cfg.trunk_layers
-mesh = rsh.plcore_mesh(4)
-assert rsh.plcore_shard_count(mesh, L) == 4
-homes = {s: rsh.plcore_home_cell(mesh, L, s) for s in ("s0", "s1", "s2")}
-assert len(set(homes.values())) >= 2, homes     # >= 2 distinct home cells
-
-param_sets = {f"s{i}": init_params(plcore_decls(cfg), jax.random.PRNGKey(i),
-                                   "float32") for i in range(3)}
-def make(percell):
-    cache = SceneCache(
-        lambda sid: PackedPlcore(cfg, param_sets[sid], shard_mesh=mesh),
-        capacity_mb=256.0)
-    return RenderEngine(cache, tile_rays=128, pipeline_depth=2,
-                        route_by_shard=True, percell_dispatch=percell)
-
-reqs = [RenderRequest("s0", hw=12), RenderRequest("s1", hw=16),
-        RenderRequest("s0", hw=16), RenderRequest("s2", hw=12)]
-runs = {}
-for name, pc in (("spmd", False), ("percell", True)):
-    eng = make(pc)
-    rids = [eng.submit(r) for r in reqs]
-    eng.drain()
-    assert eng.in_flight_tiles == 0
-    runs[name] = (eng, [eng.completed[rid].image for rid in rids])
-
-# acceptance: per-cell framebuffers == mesh-wide SPMD, bit for bit
-for a, b in zip(runs["spmd"][1], runs["percell"][1]):
-    assert np.array_equal(a, b), "percell images != SPMD"
-    assert np.isfinite(a).all()
-print("ok percell bit-identity vs SPMD")
-
-eng_pc, eng_sp = runs["percell"][0], runs["spmd"][0]
-st = eng_pc.stats
-# every dispatch ran through a per-cell program; staging replaced the
-# per-dispatch gathers entirely (SPMD pays them on every dispatch)
-assert st["percell_tiles"] == st["dispatches"] > 0
-assert st["plcore_gather_count"] == 0
-assert eng_sp.stats["plcore_gather_count"] > 0
-# one staging per (scene, cell) — each of the 3 scenes stages into its
-# single home cell exactly once, paying the 6 remote layers per stacked
-# array per net, and never re-pays on later dispatches
-assert st["percell_stage_events"] == 3
-assert st["percell_stage_layers"] == 3 * 2 * 2 * (L - L // 4)
-print("ok staging replaces per-dispatch gathers")
-
-# acceptance: >= 2 cells executed tiles, each genuinely holding a slot
-rep = eng_pc.percell_report()
-assert rep["cells_active"] >= 2, rep
-mif = [c["max_in_flight"] for c in rep["cells"].values()]
-assert sum(1 for m in mif if m >= 1) >= 2, rep
-print("ok cross-cell concurrency")
-print("ALL OK")
-"""
-
-
-@pytest.mark.slow
-def test_percell_dispatch_multidevice(fake_devices):
-    fake_devices(_PERCELL_SNIPPET)

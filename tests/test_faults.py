@@ -174,24 +174,19 @@ class _NaNPlcore:
     def __init__(self, pp):
         self._pp = pp
         self.params, self.quant, self.packed = pp.params, pp.quant, pp.packed
-        self.shard_mesh = None
 
     def commit(self, x):
         return self._pp.commit(x)
 
-    def dispatch_tile(self, o, d, home_cell=None, coarse_only=False):
-        rgb, cost = self._pp.dispatch_tile(o, d, home_cell=home_cell,
-                                           coarse_only=coarse_only)
-        return jnp.full_like(rgb, jnp.nan), cost
+    def dispatch_tile(self, o, d, coarse_only=False):
+        rgb, rest = self._pp.dispatch_tile(o, d, coarse_only=coarse_only)
+        return jnp.full_like(rgb, jnp.nan), rest
 
     def render_tile(self, o, d, coarse_only=False):
         return jnp.full((o.shape[0], 3), jnp.nan, jnp.float32)
 
     def render_tile_oracle(self, o, d):
         return jnp.full((o.shape[0], 3), jnp.nan, jnp.float32)
-
-    def tile_gather_cost(self, home_cell=None):
-        return self._pp.tile_gather_cost(home_cell)
 
 
 def test_check_finite_rejects_nan_framebuffer(setup):

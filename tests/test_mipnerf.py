@@ -256,10 +256,10 @@ def test_shared_network_packs_and_pins_one_weight_set(scene):
 
 
 # ----------------------------------------------------- refused modes ----
-def _cone_cache(scene, **kw):
+def _cone_cache(scene):
     cfg, params = scene
     return SceneCache(lambda sid: PackedPlcore(
-        cfg, params, use_kernel=True, fuse_two_pass=True, **kw))
+        cfg, params, use_kernel=True, fuse_two_pass=True))
 
 
 def _first_tile_raises(engine, match):
@@ -271,8 +271,6 @@ def _first_tile_raises(engine, match):
 @pytest.mark.parametrize("mode, kw", [
     ("adaptive sampling", {"adaptive_sampling": True}),
     (r"coarse_only degradation", {"degrade_on_overload": True}),
-    ("per-cell dispatch", {"route_by_shard": True,
-                           "percell_dispatch": True}),
 ])
 def test_engine_modes_refuse_a_cone_scene(mode, kw, scene):
     _first_tile_raises(RenderEngine(_cone_cache(scene), tile_rays=TILE,
@@ -284,14 +282,6 @@ def test_cluster_engine_refuses_a_cone_scene(scene):
     engine = ClusterEngine([_cone_cache(scene), _cone_cache(scene)],
                            tile_rays=TILE)
     _first_tile_raises(engine, "ClusterEngine")
-
-
-def test_sharded_residency_refuses_a_cone_scene(scene):
-    from repro.runtime import sharding as rsh
-    cfg, params = scene
-    with pytest.raises(ValueError, match="sharded residency"):
-        PackedPlcore(cfg, params, use_kernel=True,
-                     shard_mesh=rsh.plcore_mesh())
 
 
 @pytest.mark.parametrize("mode, kw", [

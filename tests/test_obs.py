@@ -114,8 +114,7 @@ def test_stats_view_byte_identical_to_old_literals():
     old_engine = {
         "dispatches": 0, "dispatch_baseline": 0, "rays_rendered": 0,
         "padded_rays": 0, "scene_switches": 0, "requests_completed": 0,
-        "status_counts": {}, "plcore_gather_count": 0,
-        "plcore_gather_bytes": 0, "routed_tiles": 0, "max_in_flight": 0,
+        "status_counts": {}, "max_in_flight": 0,
         "dispatch_errors": 0, "corrupt_tiles": 0, "tile_retries": 0,
         "oracle_fallbacks": 0, "scene_load_errors": 0,
         "scene_load_fail_fasts": 0, "straggler_redispatches": 0,

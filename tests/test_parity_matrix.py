@@ -2,16 +2,16 @@
 
 Every rendering path the repo grew — seed per-tile loop, one-dispatch
 XLA pipeline, one-pass kernel chain, one-kernel two-pass fusion, ERT,
-RMCM quantization, mesh-sharded weights, the coalescing engine, the
-pipelined executor, per-cell dispatch — renders ONE canonical scene in
-one parameterized module, each against its flag-matched oracle.
+RMCM quantization, device placement, the coalescing engine over the
+served fused residents, the pipelined executor — renders ONE canonical
+scene in one parameterized module, each against its flag-matched oracle.
 
 Two comparison regimes, matching the per-path tests that pinned them:
 
 * ``exact`` — bit-for-bit. Structural dimensions that reuse the same
   compiled tile body (tiling into the single dispatch, packed-weight
-  layout, sharding's placement-only re-gather, engine coalescing,
-  pipelining depth, per-cell staging) must be pixel-invisible.
+  layout, device placement, engine coalescing, pipelining depth) must
+  be pixel-invisible.
 * ``atol`` — fp32 tolerance. Cross-PROGRAM comparisons (kernel vs XLA,
   fused vs two-dispatch) run the same math at different tile shapes, so
   XLA's gemm blocking reorders fp32 sums; the importance resampler
@@ -31,7 +31,6 @@ from repro.core.pipeline import PackedPlcore
 from repro.core.plcore import plcore_decls, render_image_tiled
 from repro.data import rays as R
 from repro.models.params import init_params
-from repro.runtime import sharding as rsh
 from repro.serving import RenderEngine, RenderRequest, SceneCache
 
 HW = 16
@@ -48,8 +47,7 @@ def scene():
     ro, rd = R.camera_rays(R.pose_spherical(45.0, -25.0, 4.0),
                            HW, HW, 0.9 * HW)
     return {"cfg": cfg, "params": params, "quant": quant,
-            "ro": ro, "rd": rd, "mesh": rsh.plcore_mesh(),
-            "_imgs": {}}
+            "ro": ro, "rd": rd, "_imgs": {}}
 
 
 def _img(scene, *, batch=BATCH, ert_eps=None, **pp_kw):
@@ -60,10 +58,7 @@ def _img(scene, *, batch=BATCH, ert_eps=None, **pp_kw):
                         for k, v in pp_kw.items())))
     out = scene["_imgs"].get(key)
     if out is None:
-        kw = dict(pp_kw)
-        if kw.pop("sharded", False):
-            kw["shard_mesh"] = scene["mesh"]
-        pp = PackedPlcore(scene["cfg"], scene["params"], **kw)
+        pp = PackedPlcore(scene["cfg"], scene["params"], **pp_kw)
         out = np.asarray(pp.render_image(scene["ro"], scene["rd"],
                                          rays_per_batch=batch,
                                          ert_eps=ert_eps))
@@ -77,13 +72,12 @@ def _seed_loop(scene, **kw):
                                           rays_per_batch=BATCH, **kw))]
 
 
-def _engine_imgs(scene, *, sharded=False, fused=False, **engine_kw):
+def _engine_imgs(scene, *, fused=False, quant=None, **engine_kw):
     """The engine side of a row: two coalescable same-scene requests plus
     a second resolution, images in submit order."""
     cfg, params = scene["cfg"], scene["params"]
-    mesh = scene["mesh"] if sharded else None
     cache = SceneCache(
-        lambda sid: PackedPlcore(cfg, params, shard_mesh=mesh,
+        lambda sid: PackedPlcore(cfg, params, quant=quant,
                                  use_kernel=fused, fuse_two_pass=fused),
         capacity_mb=64.0)
     eng = RenderEngine(cache, tile_rays=BATCH, **engine_kw)
@@ -99,10 +93,10 @@ def _engine_imgs(scene, *, sharded=False, fused=False, **engine_kw):
     return out
 
 
-def _engine_direct_oracle(scene):
+def _engine_direct_oracle(scene, **pp_kw):
     """Per-request single-dispatch renders at the engine's request poses
     — what the engine's scatter must reproduce bit-for-bit."""
-    pp = PackedPlcore(scene["cfg"], scene["params"])
+    pp = PackedPlcore(scene["cfg"], scene["params"], **pp_kw)
     out = []
     for hw in (HW, 12, HW):
         ro, rd = R.camera_rays(R.pose_spherical(45.0, -25.0, 4.0),
@@ -139,21 +133,26 @@ _MATRIX = {
         lambda s: _img(s, quant=s["quant"], use_kernel=True,
                        fuse_two_pass=True),
         lambda s: _img(s, quant=s["quant"], use_kernel=True), 5e-3),
-    "sharded_xla__replicated_xla": (
-        lambda s: _img(s, sharded=True), lambda s: _img(s), None),
-    "sharded_kernel__replicated_kernel": (
-        lambda s: _img(s, sharded=True, use_kernel=True),
-        lambda s: _img(s, use_kernel=True), None),
+    "device_placed_fused__fused": (
+        lambda s: _img(s, use_kernel=True, fuse_two_pass=True,
+                       device=jax.devices()[0]),
+        lambda s: _img(s, use_kernel=True, fuse_two_pass=True), None),
     "engine_coalesced__direct": (
         lambda s: _engine_imgs(s), _engine_direct_oracle, None),
     "engine_depth3__engine_depth1": (
         lambda s: _engine_imgs(s, pipeline_depth=3),
         lambda s: _engine_imgs(s), None),
-    "percell_engine__spmd_engine": (
-        lambda s: _engine_imgs(s, sharded=True, route_by_shard=True,
-                               percell_dispatch=True),
-        lambda s: _engine_imgs(s, sharded=True, route_by_shard=True),
-        None),
+    # the served program: fused residents behind the engine at its
+    # default pipeline depth, against per-request fused renders
+    "engine_fused__fused_direct": (
+        lambda s: _engine_imgs(s, fused=True),
+        lambda s: _engine_direct_oracle(s, use_kernel=True,
+                                        fuse_two_pass=True), None),
+    "engine_fused_rmcm__fused_rmcm_direct": (
+        lambda s: _engine_imgs(s, fused=True, quant=s["quant"]),
+        lambda s: _engine_direct_oracle(s, quant=s["quant"],
+                                        use_kernel=True,
+                                        fuse_two_pass=True), None),
     # ASDR acceptance: adaptive sampling OFF is not a degraded mode — an
     # engine with the flag explicitly off is the construction-for-
     # construction SAME pipeline as one that never heard of it
@@ -180,11 +179,11 @@ def test_parity(combo, scene):
 
 def test_matrix_breadth():
     """The consolidation contract: >= 8 path combinations in ONE module,
-    and the structural (bit-identity) rows cover sharding, the engine,
-    pipelining and per-cell dispatch."""
+    and the structural (bit-identity) rows cover device placement, the
+    engine over the served fused residents, and pipelining."""
     assert len(_MATRIX) >= 8
     exact = {name for name, (_, _, atol) in _MATRIX.items()
              if atol is None}
-    for needle in ("seed_loop", "sharded", "engine_coalesced",
-                   "engine_depth3", "percell", "adaptive"):
+    for needle in ("seed_loop", "device_placed", "engine_coalesced",
+                   "engine_depth3", "engine_fused", "adaptive"):
         assert any(needle in name for name in exact), needle

@@ -68,7 +68,7 @@ def test_ray_order_permutation_invariance(scene, seed):
 @settings(max_examples=10, deadline=None)
 @given(n_real=st.integers(min_value=1, max_value=N_RAYS - 1),
        tail=arrays(np.float32, (N_RAYS, 3),
-                   elements=st.floats(min_value=0.1, max_value=1.0,
+                   elements=st.floats(min_value=0.125, max_value=1.0,
                                    width=32)))
 def test_tail_pad_cannot_leak_into_real_rays(scene, n_real, tail):
     """Two renders of the same real rays with different garbage tails:
